@@ -36,7 +36,6 @@ documented in SURVEY.md (the reference mount was empty at survey time).
 __version__ = "0.1.0"
 
 try:
-    from apex_tpu import _compat  # noqa: F401  (jax.shard_map shim)
     from apex_tpu import mesh  # noqa: F401
 except ImportError:
     # No working jax (lint-only CI, a tree too broken to import): the

@@ -1,51 +1,13 @@
-"""JAX version compatibility shims.
-
-The repo targets the modern ``jax.shard_map`` entry point (keyword-only,
-``check_vma``); older runtimes ship it as
-``jax.experimental.shard_map.shard_map`` (``check_rep``). Installing the
-adapter at package import keeps every call site on the one modern
-spelling instead of scattering try/except through models, tests, and
-examples. No-op on runtimes that already expose ``jax.shard_map``.
-"""
+"""The one seam onto ``jax.monitoring`` (the compile-event stream)."""
 
 from __future__ import annotations
 
-import jax
-
-#: True when the legacy ``jax.experimental.shard_map`` adapter is in
-#: place. Legacy ``check_rep`` inference is weaker than modern
-#: ``check_vma`` (e.g. it cannot see replication through a
-#: ``jax.grad``-of-psum), so callers that rely on the stronger
-#: inference gate on this flag.
-LEGACY_SHARD_MAP = False
-
-
-def _install_shard_map() -> None:
-    global LEGACY_SHARD_MAP
-    if getattr(jax, "shard_map", None) is not None:
-        return
-    try:
-        from jax.experimental.shard_map import shard_map as _legacy
-    except ImportError:  # pragma: no cover - no known runtime hits this
-        return
-    LEGACY_SHARD_MAP = True
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
-                  **kwargs):
-        kwargs.pop("axis_names", None)  # legacy maps over all mesh axes
-        return _legacy(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=check_vma, **kwargs)
-
-    shard_map.__doc__ = _legacy.__doc__
-    jax.shard_map = shard_map
+from jax import monitoring
 
 
 def register_monitoring_listeners(on_event, on_duration):
     """Subscribe to the runtime's compile-event stream
-    (``jax.monitoring``), returning an unregister callable — or ``None``
-    on legacy runtimes without the module, in which case the caller
-    falls back to polling its tracked functions' jit-cache sizes (the
-    lowering/cache-miss counter the recompile sentinel keeps anyway).
+    (``jax.monitoring``), returning the unregister callable.
 
     ``on_event(name, **kw)`` receives point events (persistent-cache
     hits/misses); ``on_duration(name, seconds, **kw)`` receives duration
@@ -54,59 +16,11 @@ def register_monitoring_listeners(on_event, on_duration):
     (fresh XLA compile OR persistent-cache load) and never on an
     in-memory jit-cache hit.
     """
-    try:
-        from jax import monitoring
-    except ImportError:  # pragma: no cover - legacy runtime
-        return None
-    # require BOTH registration APIs before touching either — a partial
-    # register with no unregister handle would leak for process lifetime
-    if not (hasattr(monitoring, "register_event_listener") and
-            hasattr(monitoring, "register_event_duration_secs_listener")):
-        return None  # pragma: no cover - legacy runtime
-    # unregistration only exists as private helpers, living on the
-    # implementation module (jax._src.monitoring — the public re-export
-    # does NOT carry them on this runtime). Resolve them BEFORE
-    # registering: a runtime where they are gone (they are private, no
-    # stability guarantee) gets the clean cache-polling fallback instead
-    # of listeners that Engine.close() can never release.
-    impl = monitoring
-    if not hasattr(impl, "_unregister_event_listener_by_callback"):
-        try:
-            from jax._src import monitoring as impl  # type: ignore
-        except ImportError:  # pragma: no cover
-            return None
-    unreg_event = getattr(impl, "_unregister_event_listener_by_callback",
-                          None)
-    unreg_duration = getattr(
-        impl, "_unregister_event_duration_listener_by_callback", None)
-    if unreg_event is None or unreg_duration is None:
-        return None  # pragma: no cover - future runtime
-
     monitoring.register_event_listener(on_event)
     monitoring.register_event_duration_secs_listener(on_duration)
 
     def unregister():
-        for fn, cb in ((unreg_event, on_event),
-                       (unreg_duration, on_duration)):
-            try:
-                fn(cb)
-            except ValueError:  # already removed
-                pass
+        monitoring.unregister_event_listener(on_event)
+        monitoring.unregister_event_duration_listener(on_duration)
 
     return unregister
-
-
-def _install_axis_size() -> None:
-    if getattr(jax.lax, "axis_size", None) is not None:
-        return
-
-    def axis_size(axis_name):
-        # psum of a Python literal constant-folds to the (static) axis
-        # size — the documented pre-axis_size spelling of the same query
-        return jax.lax.psum(1, axis_name)
-
-    jax.lax.axis_size = axis_size
-
-
-_install_shard_map()
-_install_axis_size()

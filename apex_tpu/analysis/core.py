@@ -19,8 +19,8 @@ import tokenize
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 #: directories never walked for source files
-_SKIP_DIRS = {"__pycache__", ".git", ".jax_cache", ".scratch",
-              ".pytest_cache", "node_modules"}
+_SKIP_DIRS = {"__pycache__", ".git", ".jax_cache", "chiprun_out",
+              "_checkout", "_parent", ".pytest_cache", "node_modules"}
 
 #: the suppression comment:  "apex: noqa[<rule>]: justification"
 #: after a hash (spelled without one here or it would register itself)

@@ -11,10 +11,13 @@ traffic that scales with the cache horizon just to land one
 composed by :func:`decode_attention`:
 
 - **column write**: the new K/V column lands at each row's own ``pos``
-  via a scalar-prefetch output index map (the block index IS
-  ``pos[b]``) with the cache aliased input→output
-  (``input_output_aliases``), so exactly one ``[h, 1, d]`` block per
-  batch row is written and the rest of the cache is never touched;
+  through a scalar-prefetch index map with the cache aliased
+  input→output (``input_output_aliases``). A cache position is one ROW
+  of a ``(sublane, lane)`` tile and Mosaic moves whole tiles, so each
+  grid step reads the one tile-high window that holds ``pos[b]``
+  (8 rows f32 / 16 bf16 / 32 int8, fp8 — the block index is ``pos[b]
+  // tile``), replaces row ``pos[b] % tile`` and writes the window
+  back; the rest of the cache is never touched;
 - **split-K read**: flash-decode attention — the cache horizon is swept
   in ``block_k`` chunks with a running online-softmax ``(out, lse)``
   merge (the same ``m/l/acc`` update as the training flash kernel),
@@ -31,14 +34,13 @@ oracle test covers with per-dtype tolerances
 
 **Quantized cache layout** (:func:`decode_attention_quantized`): K/V
 stored int8 (or fp8 e4m3) with per-head, per-slot, per-position fp32
-scales. The one-column write quantizes the incoming ``[h, d]`` rows
-IN-KERNEL (symmetric absmax per head — the same deterministic
-round-to-nearest quantizer every other cache-write path calls, see
-:func:`quantize_kv_rows`) and lands one quantized column
-plus one scale column per batch row; the split-K read streams int8
+scales. The incoming ``[h, d]`` rows are quantized by
+:func:`quantize_kv_rows` (symmetric absmax per head — the same
+deterministic round-to-nearest quantizer every other cache-write path
+calls) and land as one quantized column plus one scale column per
+batch row through the same window write; the split-K read streams int8
 chunks from HBM — ~2x less read traffic than bf16, ~4x less than f32 —
-and dequantizes each ``[block_k, d]`` chunk in VMEM before the fp32
-score dot.
+and folds the scales into the fp32 scores and probabilities in VMEM.
 
 Like every kernel in this package it runs interpreted off-TPU, so the
 CPU test backbone exercises identical semantics; the model-level
@@ -66,78 +68,136 @@ _LANES = 128  # stat scratch lane width (matches flash_attention)
 _DEFAULT_BLOCK_K = 256
 
 
-def _fit_block_k(want: int, sk: int) -> int:
-    """Largest chunk ≤ ``want`` that doesn't over-sweep a short horizon
-    by more than a quarter (same policy as flash's ``_fit_block``, with
-    a smaller floor — decode horizons can be tiny)."""
-    b = min(want, round_up(sk, 8))
-    while b > 8 and round_up(sk, b) - sk > sk // 4:
+def _sublane_tile(dtype) -> int:
+    """Rows of one Mosaic tile of ``dtype``: 8 f32 / 16 bf16 / 32 int8,
+    fp8 — narrower dtypes pack more rows per 32-bit sublane."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def _fit_block_k(want: int, sk: int, align: int) -> int:
+    """Split-K chunk for a horizon of ``sk``: the whole horizon as one
+    block when it fits ``want`` (a full-dimension block is always
+    tile-legal and sweeps nothing extra), else the largest halving of
+    ``want`` that doesn't over-sweep by more than a quarter (same
+    policy as flash's ``_fit_block``). ``align`` is the smallest chunk
+    Mosaic accepts: the cache dtype's sublane tile, or 128 when fp32
+    scale rows ride along on the lane dimension."""
+    if sk <= want:
+        return sk
+    b = max(want, align)
+    while b > align and round_up(sk, b) - sk > sk // 4:
         b //= 2
     return b
 
 
 # ---------------------------------------------------------------------------
-# column write: cache[b, :, pos[b], :] = new[b]  (one block per row)
+# column write: plane[row, :, pos, ...] = new[b]  (one window per row)
 # ---------------------------------------------------------------------------
 
-def _write_kernel(pos_ref, kn_ref, vn_ref, ki_ref, vi_ref, ko_ref, vo_ref):
-    del pos_ref, ki_ref, vi_ref  # pos drives the index map; caches are
-    #                              aliased to the outputs, never read here
-    ko_ref[...] = kn_ref[...][:, :, None]
-    vo_ref[...] = vn_ref[...][:, :, None]
+def _write_kernel(*refs, n_scalar, windows, page):
+    """Land one column per cache plane. Each plane's block is the
+    tile-aligned window of ``windows[k]`` positions around ``pos`` (dim
+    2 of the block: sublanes of a ``[.., h, S, d]`` data plane, lanes of
+    a ``[.., h, S]`` scale plane); the incoming block is one position
+    wide and broadcasts over it."""
+    n = len(windows)
+    news = refs[n_scalar:n_scalar + n]
+    olds = refs[n_scalar + n:n_scalar + 2 * n]
+    outs = refs[n_scalar + 2 * n:]
+    pos = refs[0][pl.program_id(0)]
+    if page:
+        pos = lax.rem(pos, page)
+    for new_ref, old_ref, out_ref, w in zip(news, olds, outs, windows):
+        hit = (lax.broadcasted_iota(jnp.int32, out_ref.shape, 2)
+               == lax.rem(pos, w))
+        out_ref[...] = jnp.where(hit, new_ref[...], old_ref[...])
 
 
-def _write_column(k_new, v_new, k_cache, v_cache, pos):
-    """Write ``k_new/v_new [b, h, d]`` into column ``pos[b]`` of the
-    caches ``[b, h, S, d]`` — each grid step touches exactly one
-    ``[h, 1, d]`` output block (the scalar-prefetched ``pos`` IS the
-    block index on the S dim), and ``input_output_aliases`` keeps every
-    other cache byte in place."""
-    b, h, sk, d = k_cache.shape
-    new_spec = pl.BlockSpec((1, h, d), lambda i, pos_ref: (i, 0, 0))
-    col_spec = pl.BlockSpec((1, h, 1, d),
-                            lambda i, pos_ref: (i, 0, pos_ref[i], 0))
+def _write_column_planes(news, planes, pos, table=None):
+    """Write ``news[k] [b, h(, d)]`` into position ``pos[b]`` of
+    ``planes[k]`` — contiguous caches ``[b, h, S(, d)]``, or, with
+    ``table [b, max_pages]``, page pools ``[num_pages, h, P(, d)]``
+    where the cell is ``(table[b, pos // P], pos % P)``. One grid step
+    per batch row; every plane is aliased input→output so only the
+    windows holding the written cells move. ``0 <= pos[b]`` must lie
+    inside the row's horizon, and rows must target distinct windows —
+    except inside a shared garbage/sink page, where the pipelined
+    read-modify-write of one window by two rows keeps only one row's
+    cell (the sink holds garbage by contract)."""
+    b = news[0].shape[0]
+    p_sz = planes[0].shape[2]
+    paged = table is not None
+    mp = table.shape[1] if paged else 0
+    new_specs, plane_specs, windows = [], [], []
+    for plane in planes:
+        h = plane.shape[1]
+        if plane.ndim == 4:
+            w = min(_sublane_tile(plane.dtype), p_sz)
+            tail = (plane.shape[3],)
+        else:
+            w = min(_LANES, p_sz)
+            tail = ()
+        zeros = (0,) * len(tail)
+
+        def where(i, pos_ref, *tbl_ref, w=w, zeros=zeros):
+            if not paged:
+                return (i, 0, lax.div(pos_ref[i], w)) + zeros
+            page = tbl_ref[0][i * mp + lax.div(pos_ref[i], p_sz)]
+            return (page, 0, lax.div(lax.rem(pos_ref[i], p_sz), w)) + zeros
+
+        new_specs.append(pl.BlockSpec(
+            (1, h, 1) + tail,
+            lambda i, *_, zeros=zeros: (i, 0, 0) + zeros))
+        plane_specs.append(pl.BlockSpec((1, h, w) + tail, where))
+        windows.append(w)
+    scalars = [jnp.asarray(pos, jnp.int32)]
+    if paged:
+        scalars.append(jnp.asarray(table, jnp.int32).reshape(-1))
+    n_scalar, n = len(scalars), len(planes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=n_scalar,
         grid=(b,),
-        in_specs=[new_spec, new_spec,
-                  pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=[col_spec, col_spec],
+        in_specs=new_specs + plane_specs,
+        out_specs=plane_specs,
     )
     return pl.pallas_call(
-        _write_kernel,
+        functools.partial(_write_kernel, n_scalar=n_scalar,
+                          windows=tuple(windows),
+                          page=p_sz if paged else 0),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
-        # operand order: (pos, k_new, v_new, k_cache, v_cache)
-        input_output_aliases={3: 0, 4: 1},
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in planes],
+        # operand order: (*scalars, *news, *planes)
+        input_output_aliases={n_scalar + n + k: k for k in range(n)},
         interpret=use_interpret(),
-    )(pos, k_new.astype(k_cache.dtype), v_new.astype(v_cache.dtype),
-      k_cache, v_cache)
+    )(*scalars,
+      *[jnp.expand_dims(new, 2).astype(plane.dtype)
+        for new, plane in zip(news, planes)],
+      *planes)
 
 
-# ---------------------------------------------------------------------------
-# multi-column write: cache[b, :, pos[b] + j, :] = new[b, :, j, :]
-# (the speculative verify forward's cache landing — T = draft k + 1
-# columns per row per wave)
-# ---------------------------------------------------------------------------
-
-def _write_cols_kernel(pos_ref, kn_ref, vn_ref, ki_ref, vi_ref, ko_ref,
-                       vo_ref):
-    del pos_ref, ki_ref, vi_ref  # pos drives the index map; caches are
-    #                              aliased to the outputs, never read here
-    ko_ref[...] = kn_ref[...]    # blocks are (1, h, 1, d) on both sides
-    vo_ref[...] = vn_ref[...]
+def _write_columns_planes(news, planes, pos, table=None):
+    """The T-column write: ``news[k] [b, h, T(, d)]`` land at positions
+    ``pos[b] .. pos[b] + T - 1``, one :func:`_write_column_planes` pass
+    per lane (T is the tiny static draft width; two lanes of one row
+    usually share a window, so they cannot ride one pipelined grid).
+    Lanes past the row's horizon CLAMP onto its last position."""
+    p_sz = planes[0].shape[2]
+    smax = p_sz * table.shape[1] if table is not None else p_sz
+    pos = jnp.asarray(pos, jnp.int32)
+    for j in range(news[0].shape[2]):
+        planes = _write_column_planes(
+            [new[:, :, j] for new in news], planes,
+            jnp.minimum(pos + j, smax - 1), table)
+    return planes
 
 
 def cache_write_columns(k_new, v_new, k_cache, v_cache, pos):
     """Write ``k_new/v_new [b, h, T, d]`` into columns ``pos[b] .. pos[b]
     + T - 1`` of the caches ``[b, h, S, d]`` — the T-column
-    generalisation of the one-column scalar-prefetch write: grid
-    ``(b, T)``, each step landing one ``[h, 1, d]`` block at block index
-    ``pos[b] + j`` with the caches aliased input→output, so only the T
-    touched columns move and the rest of the cache stays in place.
+    generalisation of the one-column window write (the speculative
+    verify forward's cache landing, T = draft k + 1), with the caches
+    aliased input→output so only the touched windows move and the rest
+    of the cache stays in place.
 
     Columns past the horizon are CLAMPED onto ``S - 1``: a row whose
     tail lanes overrun the cache end (a near-budget slot drafting past
@@ -151,31 +211,7 @@ def cache_write_columns(k_new, v_new, k_cache, v_cache, pos):
     holds a real token's K/V once the row is done (frozen done-row
     writes) — the same masked-garbage contract every over-position
     cache entry already lives under."""
-    b, h, sk, d = k_cache.shape
-    t = k_new.shape[2]
-    new_spec = pl.BlockSpec((1, h, 1, d), lambda i, j, pos_ref: (i, 0, j, 0))
-    col_spec = pl.BlockSpec(
-        (1, h, 1, d),
-        lambda i, j, pos_ref: (i, 0, jnp.minimum(pos_ref[i] + j, sk - 1),
-                               0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, t),
-        in_specs=[new_spec, new_spec,
-                  pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=[col_spec, col_spec],
-    )
-    return pl.pallas_call(
-        _write_cols_kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
-        # operand order: (pos, k_new, v_new, k_cache, v_cache)
-        input_output_aliases={3: 0, 4: 1},
-        interpret=use_interpret(),
-    )(jnp.asarray(pos, jnp.int32), k_new.astype(k_cache.dtype),
-      v_new.astype(v_cache.dtype), k_cache, v_cache)
+    return _write_columns_planes([k_new, v_new], [k_cache, v_cache], pos)
 
 
 def cache_write_columns_xla(cache, new, pos):
@@ -212,69 +248,35 @@ def cache_write_columns_xla(cache, new, pos):
     return jnp.where(hit, gathered, cache)
 
 
-def _write_cols_kernel_quant(pos_ref, kn_ref, vn_ref, kqi_ref, ksi_ref,
-                             vqi_ref, vsi_ref, kq_ref, ks_ref, vq_ref,
-                             vs_ref, *, kind):
-    del pos_ref, kqi_ref, ksi_ref, vqi_ref, vsi_ref
-    kq, ks = quantize_kv_rows(kn_ref[:, :, 0], kind)     # (1, h, d)/(1, h)
-    vq, vs = quantize_kv_rows(vn_ref[:, :, 0], kind)
-    kq_ref[...] = kq[:, :, None]
-    ks_ref[...] = ks[:, :, None]
-    vq_ref[...] = vq[:, :, None]
-    vs_ref[...] = vs[:, :, None]
-
-
 def cache_write_columns_quant(k_new, v_new, k_q, k_s, v_q, v_s, pos,
                               kind):
     """:func:`cache_write_columns` over the quantized cache layout:
-    each of the T incoming ``[h, d]`` rows is quantized IN-KERNEL
+    each of the T incoming ``[h, d]`` rows is quantized
     (:func:`quantize_kv_rows` — the one deterministic quantizer) and
     lands one quantized column plus one fp32 scale column at ``pos[b] +
     j`` across all four planes; same clamped over-horizon contract as
     the plain variant."""
-    k_new, _ = widen_f16(k_new)   # Mosaic has no f16; the quantizer
-    v_new, _ = widen_f16(v_new)   # runs fp32 internally anyway
-    b, h, sk, d = k_q.shape
-    t = k_new.shape[2]
-    new_spec = pl.BlockSpec((1, h, 1, d),
-                            lambda i, j, pos_ref: (i, 0, j, 0))
-    col = lambda i, j, pos_ref: (i, 0, jnp.minimum(pos_ref[i] + j,
-                                                   sk - 1), 0)
-    scol = lambda i, j, pos_ref: (i, 0, jnp.minimum(pos_ref[i] + j,
-                                                    sk - 1))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, t),
-        in_specs=[new_spec, new_spec]
-        + [pl.BlockSpec(memory_space=pltpu.ANY)] * 4,
-        out_specs=[pl.BlockSpec((1, h, 1, d), col),
-                   pl.BlockSpec((1, h, 1), scol),
-                   pl.BlockSpec((1, h, 1, d), col),
-                   pl.BlockSpec((1, h, 1), scol)],
-    )
-    return pl.pallas_call(
-        functools.partial(_write_cols_kernel_quant, kind=kind),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k_q.shape, k_q.dtype),
-                   jax.ShapeDtypeStruct(k_s.shape, k_s.dtype),
-                   jax.ShapeDtypeStruct(v_q.shape, v_q.dtype),
-                   jax.ShapeDtypeStruct(v_s.shape, v_s.dtype)],
-        # operand order: (pos, k_new, v_new, k_q, k_s, v_q, v_s)
-        input_output_aliases={3: 0, 4: 1, 5: 2, 6: 3},
-        interpret=use_interpret(),
-    )(jnp.asarray(pos, jnp.int32), k_new, v_new, k_q, k_s, v_q, v_s)
+    return _write_columns_planes(_quantize_pair(k_new, v_new, kind),
+                                 [k_q, k_s, v_q, v_s], pos)
 
 
 # ---------------------------------------------------------------------------
 # split-K read: one query row against its masked cache horizon
 # ---------------------------------------------------------------------------
 
-def _attn_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                 l_ref, *, scale, bk, sk, h):
-    r = pl.program_id(0)        # (batch, head) row
-    j = pl.program_id(1)        # split-K chunk of the horizon
-    nk = pl.num_programs(1)
-    pos = pos_ref[lax.div(r, h)]
+def _attn_kernel(*refs, n_scalar, quant, scale, bk, smax):
+    """Grid ``(b, h, chunks)``: one (batch, head) query row swept over
+    its horizon in ``bk``-position chunks. ``quant`` adds the two fp32
+    scale-row refs of the int8/fp8 layout."""
+    pos_ref = refs[0]
+    if quant:
+        (q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref,
+         l_ref) = refs[n_scalar:]
+    else:
+        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs[n_scalar:]
+    j = pl.program_id(2)        # split-K chunk of the horizon
+    nk = pl.num_programs(2)
+    pos = pos_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -286,19 +288,30 @@ def _attn_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
     # decode analogue of the causal block skip)
     @pl.when(j * bk <= pos)
     def _block():
-        q = q_ref[0]                                      # (1, d)
-        k = k_ref[0]                                      # (bk, d)
-        v = v_ref[0]
+        q = q_ref[0, 0]                                   # (1, d)
+        k = k_ref[0, 0]                                   # (bk, d)
+        v = v_ref[0, 0]
+        if quant:
+            # int8/fp8 chunk straight from HBM; the per-column scale
+            # folds into the SCORE (q·(k_int·s) == (q·k_int)·s) so the
+            # chunk is never materialised dequantized
+            q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (1, bk)
+            preferred_element_type=jnp.float32)           # (1, bk)
+        if quant:
+            s = s * ks_ref[0, 0]
+        s = s * scale
         col = lax.broadcasted_iota(jnp.int32, (1, bk), 1) + j * bk
-        valid = (col <= pos) & (col < sk)
+        valid = (col <= pos) & (col < smax)
+        # the same mask down the sublanes (Mosaic cannot transpose i1)
+        row = lax.broadcasted_iota(jnp.int32, (bk, 1), 0) + j * bk
+        valid_rows = (row <= pos) & (row < smax)
         s = jnp.where(valid, s, _NEG)
         # masked V rows can be horizon padding (NaN in interpret mode,
-        # arbitrary garbage on chip): zero them so 0·garbage can't
-        # poison the accumulator dot
-        v = jnp.where(jnp.transpose(valid), v, 0.0).astype(v.dtype)
+        # arbitrary garbage on chip, NaN bit patterns of stale fp8):
+        # zero them so 0·garbage can't poison the accumulator dot
+        v = jnp.where(valid_rows, v, 0.0).astype(v.dtype)
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
@@ -306,6 +319,10 @@ def _attn_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
         l_ref[:] = jnp.broadcast_to(
             corr * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True),
             l_ref.shape)
+        if quant:
+            # the V scale folds into p the same way (Σ p_j·(v_j·s_j)
+            # == Σ (p_j·s_j)·v_j); masked scale columns are zeroed too
+            p = p * jnp.where(valid, vs_ref[0, 0], 0.0)
         acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -313,23 +330,56 @@ def _attn_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 
     @pl.when(j == nk - 1)
     def _finish():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
-                    ).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
+                       ).astype(o_ref.dtype)
 
 
-def _run_attn(q, k_cache, v_cache, pos, scale, h, block_k):
-    bh, sk, d = k_cache.shape
-    bk = _fit_block_k(block_k or _DEFAULT_BLOCK_K, sk)
+def _run_attn(q, planes, pos, scale, *, bk, table=None):
+    """Sweep ``q [b, h, d]`` over ``planes``: ``(k, v)`` or the
+    quantized ``(k_q, k_s, v_q, v_s)``, contiguous ``[b, h, S(, d)]``
+    in ``bk``-position chunks or — ``table [b, max_pages]`` given —
+    page pools ``[num_pages, h, P(, d)]`` one page per chunk (``bk ==
+    P``; chunk ``j`` of row ``b`` streams page ``table[b, j]``)."""
+    b, h, d = q.shape
+    quant = len(planes) == 4
+    paged = table is not None
+    mp = table.shape[1] if paged else 0
+    smax = mp * bk if paged else planes[0].shape[2]
+
+    def chunk(i, g, j, pos_ref, *tbl_ref):
+        # (leading, head, position-chunk) block index of chunk j
+        if paged:
+            return tbl_ref[0][i * mp + j], g, 0
+        return i, g, j
+
+    def data_map(*args):
+        lead, g, c = chunk(*args)
+        return lead, g, c, 0
+
+    def scale_map(*args):
+        # scale rows ride as [.., h, 1, S]: positions on the lanes
+        lead, g, c = chunk(*args)
+        return lead, g, 0, c
+
+    row_spec = pl.BlockSpec((1, 1, 1, d), lambda i, g, j, *_: (i, g, 0, 0))
+    data_spec = pl.BlockSpec((1, 1, bk, d), data_map)
+    scale_spec = pl.BlockSpec((1, 1, 1, bk), scale_map)
+    scalars = [pos]
+    if paged:
+        scalars.append(jnp.asarray(table, jnp.int32).reshape(-1))
+    if quant:
+        k_q, k_s, v_q, v_s = planes
+        operands = [k_q, jnp.expand_dims(k_s, 2), v_q,
+                    jnp.expand_dims(v_s, 2)]
+        specs = [data_spec, scale_spec, data_spec, scale_spec]
+    else:
+        operands = list(planes)
+        specs = [data_spec, data_spec]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bh, -(-sk // bk)),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda r, j, pos_ref: (r, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda r, j, pos_ref: (r, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda r, j, pos_ref: (r, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d),
-                               lambda r, j, pos_ref: (r, 0, 0)),
+        num_scalar_prefetch=len(scalars),
+        grid=(b, h, mp if paged else -(-smax // bk)),
+        in_specs=[row_spec] + specs,
+        out_specs=row_spec,
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),
             pltpu.VMEM((1, _LANES), jnp.float32),
@@ -337,12 +387,13 @@ def _run_attn(q, k_cache, v_cache, pos, scale, h, block_k):
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_attn_kernel, scale=scale, bk=bk, sk=sk, h=h),
+        functools.partial(_attn_kernel, n_scalar=len(scalars),
+                          quant=quant, scale=scale, bk=bk, smax=smax),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
         interpret=use_interpret(),
-    )(pos, q[:, None], k_cache, v_cache)
-    return out[:, 0]
+    )(*scalars, q[:, :, None], *operands)
+    return out[:, :, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -388,11 +439,11 @@ def decode_attention(q, k_new, v_new, k_cache, v_cache, pos, *,
     k_cache, cache16 = widen_f16(k_cache)
     v_cache, _ = widen_f16(v_cache)
     pos = jnp.asarray(pos, jnp.int32)
-    k_cache, v_cache = _write_column(k_new, v_new, k_cache, v_cache, pos)
-    out = _run_attn(
-        q.reshape(b * h, d), k_cache.reshape(b * h, sk, d),
-        v_cache.reshape(b * h, sk, d), pos, s, h, block_k,
-    ).reshape(b, h, d)
+    k_cache, v_cache = _write_column_planes(
+        [k_new, v_new], [k_cache, v_cache], pos)
+    bk = _fit_block_k(block_k or _DEFAULT_BLOCK_K, sk,
+                      _sublane_tile(k_cache.dtype))
+    out = _run_attn(q, (k_cache, v_cache), pos, s, bk=bk)
     if was16:
         out = out.astype(jnp.float16)
     if cache16:
@@ -423,7 +474,7 @@ def quantize_kv_rows(x, kind: str):
     """THE KV quantizer: ``x [..., head_dim]`` (one K or V row per
     leading coordinate) → ``(q [..., head_dim] storage, scale [...]
     fp32)``. Symmetric absmax per row, deterministic round-to-nearest-
-    even — the in-kernel column write, the XLA-fallback write, bulk
+    even — the kernel column write, the XLA-fallback write, bulk
     prefill, and the prefix pool all call exactly this, so any two
     paths fed the same K/V bits produce the same cache bytes (the
     prefix-reuse bit-parity oracle leans on that; kernel-vs-XLA decode
@@ -446,133 +497,10 @@ def quantize_kv_rows(x, kind: str):
     return q, scale
 
 
-def _write_kernel_quant(pos_ref, kn_ref, vn_ref, kqi_ref, ksi_ref,
-                        vqi_ref, vsi_ref, kq_ref, ks_ref, vq_ref,
-                        vs_ref, *, kind):
-    del pos_ref, kqi_ref, ksi_ref, vqi_ref, vsi_ref  # pos drives the
-    #   index map; the four cache planes are aliased to the outputs
-    kq, ks = quantize_kv_rows(kn_ref[...], kind)      # (1, h, d)/(1, h)
-    vq, vs = quantize_kv_rows(vn_ref[...], kind)
-    kq_ref[...] = kq[:, :, None]
-    ks_ref[...] = ks[:, :, None]
-    vq_ref[...] = vq[:, :, None]
-    vs_ref[...] = vs[:, :, None]
-
-
-def _write_column_quant(k_new, v_new, k_q, k_s, v_q, v_s, pos, kind):
-    """Quantize the incoming ``[b, h, d]`` K/V rows IN-KERNEL and land
-    one quantized column plus one fp32 scale column at each row's own
-    ``pos`` — the quantized form of :func:`_write_column` (same
-    scalar-prefetch index map, all four cache planes aliased
-    input→output so nothing else is touched)."""
-    b, h, sk, d = k_q.shape
-    new_spec = pl.BlockSpec((1, h, d), lambda i, pos_ref: (i, 0, 0))
-    col_spec = pl.BlockSpec((1, h, 1, d),
-                            lambda i, pos_ref: (i, 0, pos_ref[i], 0))
-    scol_spec = pl.BlockSpec((1, h, 1),
-                             lambda i, pos_ref: (i, 0, pos_ref[i]))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b,),
-        in_specs=[new_spec, new_spec]
-        + [pl.BlockSpec(memory_space=pltpu.ANY)] * 4,
-        out_specs=[col_spec, scol_spec, col_spec, scol_spec],
-    )
-    return pl.pallas_call(
-        functools.partial(_write_kernel_quant, kind=kind),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k_q.shape, k_q.dtype),
-                   jax.ShapeDtypeStruct(k_s.shape, k_s.dtype),
-                   jax.ShapeDtypeStruct(v_q.shape, v_q.dtype),
-                   jax.ShapeDtypeStruct(v_s.shape, v_s.dtype)],
-        # operand order: (pos, k_new, v_new, k_q, k_s, v_q, v_s)
-        input_output_aliases={3: 0, 4: 1, 5: 2, 6: 3},
-        interpret=use_interpret(),
-    )(pos, k_new, v_new, k_q, k_s, v_q, v_s)
-
-
-def _attn_kernel_quant(pos_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-                       o_ref, acc_ref, m_ref, l_ref, *, scale, bk, sk,
-                       h):
-    r = pl.program_id(0)        # (batch, head) row
-    j = pl.program_id(1)        # split-K chunk of the horizon
-    nk = pl.num_programs(1)
-    pos = pos_ref[lax.div(r, h)]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j * bk <= pos)
-    def _block():
-        q = q_ref[0].astype(jnp.float32)              # (1, d)
-        col = lax.broadcasted_iota(jnp.int32, (1, bk), 1) + j * bk
-        valid = (col <= pos) & (col < sk)
-        # int8/fp8 chunk straight from HBM; the per-column scale folds
-        # into the SCORE (q·(k_int·s) == (q·k_int)·s) so the chunk is
-        # never materialised dequantized
-        kq = k_ref[0].astype(jnp.float32)             # (bk, d)
-        s = jax.lax.dot_general(
-            q, kq, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = s * ks_ref[0][None, :] * scale            # (1, bk)
-        s = jnp.where(valid, s, _NEG)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        l_ref[:] = jnp.broadcast_to(
-            corr * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True),
-            l_ref.shape)
-        # the V scale folds into p the same way (Σ p_j·(v_j·s_j) ==
-        # Σ (p_j·s_j)·v_j); masked columns zero BOTH the int chunk and
-        # the scale — uninitialised fp8/fp32 garbage can be NaN, and
-        # 0·NaN would poison the accumulator
-        vq = v_ref[0].astype(jnp.float32)
-        vq = jnp.where(jnp.transpose(valid), vq, 0.0)
-        vs = jnp.where(valid[0], vs_ref[0], 0.0)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p * vs[None, :], vq, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    @pl.when(j == nk - 1)
-    def _finish():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
-                    ).astype(o_ref.dtype)
-
-
-def _run_attn_quant(q, k_q, k_s, v_q, v_s, pos, scale, h, block_k):
-    bh, sk, d = k_q.shape
-    bk = _fit_block_k(block_k or _DEFAULT_BLOCK_K, sk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bh, -(-sk // bk)),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda r, j, pos_ref: (r, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda r, j, pos_ref: (r, j, 0)),
-            pl.BlockSpec((1, bk), lambda r, j, pos_ref: (r, j)),
-            pl.BlockSpec((1, bk, d), lambda r, j, pos_ref: (r, j, 0)),
-            pl.BlockSpec((1, bk), lambda r, j, pos_ref: (r, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d),
-                               lambda r, j, pos_ref: (r, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, _LANES), jnp.float32),
-            pltpu.VMEM((1, _LANES), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_attn_kernel_quant, scale=scale, bk=bk,
-                          sk=sk, h=h),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-        interpret=use_interpret(),
-    )(pos, q[:, None], k_q, k_s, v_q, v_s)
-    return out[:, 0]
+def _quantize_pair(k_new, v_new, kind: str):
+    """``[k_q, k_scale, v_q, v_scale]`` of the incoming K/V rows — the
+    plane order of every quantized write."""
+    return [*quantize_kv_rows(k_new, kind), *quantize_kv_rows(v_new, kind)]
 
 
 # ---------------------------------------------------------------------------
@@ -675,110 +603,26 @@ def paged_write_columns_xla(plane, new, table, pos):
         f"{plane.ndim}")
 
 
-def _paged_write_kernel(pos_ref, tbl_ref, kn_ref, vn_ref, ki_ref,
-                        vi_ref, ko_ref, vo_ref):
-    del pos_ref, tbl_ref, ki_ref, vi_ref  # scalars drive the index map
-    ko_ref[...] = kn_ref[...][:, :, None]
-    vo_ref[...] = vn_ref[...][:, :, None]
-
-
 def paged_write_column(k_new, v_new, k_pool, v_pool, table, pos):
     """Write ``k_new/v_new [b, h, d]`` into logical column ``pos[b]``
     of the paged pools ``[num_pages, h, P, d]`` under ``table [b,
-    max_pages]`` — the paged :func:`_write_column`: the output block
-    index is ``(table[b, pos // P], pos % P)``, both pools aliased
-    input→output so only the b touched cells move."""
-    n_pages, h, p, d = k_pool.shape
-    mp = table.shape[1]
-    new_spec = pl.BlockSpec((1, h, d), lambda i, pos_ref, tbl_ref: (i, 0, 0))
-    col_spec = pl.BlockSpec(
-        (1, h, 1, d),
-        lambda i, pos_ref, tbl_ref: (
-            tbl_ref[i * mp + lax.div(pos_ref[i], p)], 0,
-            lax.rem(pos_ref[i], p), 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(k_new.shape[0],),
-        in_specs=[new_spec, new_spec,
-                  pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=[col_spec, col_spec],
-    )
-    return pl.pallas_call(
-        _paged_write_kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
-        # operand order: (pos, table, k_new, v_new, k_pool, v_pool)
-        input_output_aliases={4: 0, 5: 1},
-        interpret=use_interpret(),
-    )(jnp.asarray(pos, jnp.int32),
-      jnp.asarray(table, jnp.int32).reshape(-1),
-      k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype),
-      k_pool, v_pool)
-
-
-def _paged_write_kernel_quant(pos_ref, tbl_ref, kn_ref, vn_ref, kqi_ref,
-                              ksi_ref, vqi_ref, vsi_ref, kq_ref, ks_ref,
-                              vq_ref, vs_ref, *, kind):
-    del pos_ref, tbl_ref, kqi_ref, ksi_ref, vqi_ref, vsi_ref
-    kq, ks = quantize_kv_rows(kn_ref[...], kind)      # (1, h, d)/(1, h)
-    vq, vs = quantize_kv_rows(vn_ref[...], kind)
-    kq_ref[...] = kq[:, :, None]
-    ks_ref[...] = ks[:, :, None]
-    vq_ref[...] = vq[:, :, None]
-    vs_ref[...] = vs[:, :, None]
+    max_pages]`` — the paged :func:`_write_column`: the cell is
+    ``(table[b, pos // P], pos % P)``, both pools aliased input→output
+    so only the b touched windows move."""
+    return _write_column_planes([k_new, v_new], [k_pool, v_pool], pos,
+                                table)
 
 
 def paged_write_column_quant(k_new, v_new, k_q, k_s, v_q, v_s, table,
                              pos, kind):
     """:func:`paged_write_column` over the quantized pool layout
     (``[num_pages, h, P, d]`` storage + ``[num_pages, h, P]`` fp32
-    scales): the incoming rows are quantized IN-KERNEL
-    (:func:`quantize_kv_rows` — the one deterministic quantizer) and
-    land one quantized + one scale cell at ``(table[b, pos // P],
-    pos % P)`` across all four planes."""
-    k_new, _ = widen_f16(k_new)
-    v_new, _ = widen_f16(v_new)
-    n_pages, h, p, d = k_q.shape
-    mp = table.shape[1]
-    new_spec = pl.BlockSpec((1, h, d), lambda i, pos_ref, tbl_ref: (i, 0, 0))
-    col = lambda i, pos_ref, tbl_ref: (
-        tbl_ref[i * mp + lax.div(pos_ref[i], p)], 0,
-        lax.rem(pos_ref[i], p), 0)
-    scol = lambda i, pos_ref, tbl_ref: (
-        tbl_ref[i * mp + lax.div(pos_ref[i], p)], 0,
-        lax.rem(pos_ref[i], p))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(k_new.shape[0],),
-        in_specs=[new_spec, new_spec]
-        + [pl.BlockSpec(memory_space=pltpu.ANY)] * 4,
-        out_specs=[pl.BlockSpec((1, h, 1, d), col),
-                   pl.BlockSpec((1, h, 1), scol),
-                   pl.BlockSpec((1, h, 1, d), col),
-                   pl.BlockSpec((1, h, 1), scol)],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_write_kernel_quant, kind=kind),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k_q.shape, k_q.dtype),
-                   jax.ShapeDtypeStruct(k_s.shape, k_s.dtype),
-                   jax.ShapeDtypeStruct(v_q.shape, v_q.dtype),
-                   jax.ShapeDtypeStruct(v_s.shape, v_s.dtype)],
-        # operand order: (pos, table, k_new, v_new, k_q, k_s, v_q, v_s)
-        input_output_aliases={4: 0, 5: 1, 6: 2, 7: 3},
-        interpret=use_interpret(),
-    )(jnp.asarray(pos, jnp.int32),
-      jnp.asarray(table, jnp.int32).reshape(-1), k_new, v_new,
-      k_q, k_s, v_q, v_s)
-
-
-def _paged_write_cols_kernel(pos_ref, tbl_ref, kn_ref, vn_ref, ki_ref,
-                             vi_ref, ko_ref, vo_ref):
-    del pos_ref, tbl_ref, ki_ref, vi_ref
-    ko_ref[...] = kn_ref[...]    # blocks are (1, h, 1, d) on both sides
-    vo_ref[...] = vn_ref[...]
+    scales): the incoming rows are quantized (:func:`quantize_kv_rows`
+    — the one deterministic quantizer) and land one quantized + one
+    scale cell at ``(table[b, pos // P], pos % P)`` across all four
+    planes."""
+    return _write_column_planes(_quantize_pair(k_new, v_new, kind),
+                                [k_q, k_s, v_q, v_s], pos, table)
 
 
 def paged_write_columns(k_new, v_new, k_pool, v_pool, table, pos):
@@ -788,143 +632,17 @@ def paged_write_columns(k_new, v_new, k_pool, v_pool, table, pos):
     landing). Over-horizon lanes CLAMP onto the row's last logical
     column ``max_pages * P - 1`` (the contiguous kernel's contract —
     that cell is only ever read by discarded lanes)."""
-    n_pages, h, p, d = k_pool.shape
-    mp = table.shape[1]
-    smax = mp * p
-    t = k_new.shape[2]
-    new_spec = pl.BlockSpec((1, h, 1, d),
-                            lambda i, j, pos_ref, tbl_ref: (i, 0, j, 0))
-
-    def col(i, j, pos_ref, tbl_ref):
-        c = jnp.minimum(pos_ref[i] + j, smax - 1)
-        return (tbl_ref[i * mp + lax.div(c, p)], 0, lax.rem(c, p), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(k_new.shape[0], t),
-        in_specs=[new_spec, new_spec,
-                  pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=[pl.BlockSpec((1, h, 1, d), col),
-                   pl.BlockSpec((1, h, 1, d), col)],
-    )
-    return pl.pallas_call(
-        _paged_write_cols_kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
-        # operand order: (pos, table, k_new, v_new, k_pool, v_pool)
-        input_output_aliases={4: 0, 5: 1},
-        interpret=use_interpret(),
-    )(jnp.asarray(pos, jnp.int32),
-      jnp.asarray(table, jnp.int32).reshape(-1),
-      k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype),
-      k_pool, v_pool)
-
-
-def _paged_write_cols_kernel_quant(pos_ref, tbl_ref, kn_ref, vn_ref,
-                                   kqi_ref, ksi_ref, vqi_ref, vsi_ref,
-                                   kq_ref, ks_ref, vq_ref, vs_ref, *,
-                                   kind):
-    del pos_ref, tbl_ref, kqi_ref, ksi_ref, vqi_ref, vsi_ref
-    kq, ks = quantize_kv_rows(kn_ref[:, :, 0], kind)     # (1, h, d)/(1, h)
-    vq, vs = quantize_kv_rows(vn_ref[:, :, 0], kind)
-    kq_ref[...] = kq[:, :, None]
-    ks_ref[...] = ks[:, :, None]
-    vq_ref[...] = vq[:, :, None]
-    vs_ref[...] = vs[:, :, None]
+    return _write_columns_planes([k_new, v_new], [k_pool, v_pool], pos,
+                                 table)
 
 
 def paged_write_columns_quant(k_new, v_new, k_q, k_s, v_q, v_s, table,
                               pos, kind):
     """:func:`paged_write_columns` over the quantized pool layout:
-    each incoming row is quantized IN-KERNEL and lands one quantized +
-    one scale cell per lane; same clamped over-horizon contract."""
-    k_new, _ = widen_f16(k_new)
-    v_new, _ = widen_f16(v_new)
-    n_pages, h, p, d = k_q.shape
-    mp = table.shape[1]
-    smax = mp * p
-    t = k_new.shape[2]
-    new_spec = pl.BlockSpec((1, h, 1, d),
-                            lambda i, j, pos_ref, tbl_ref: (i, 0, j, 0))
-
-    def col(i, j, pos_ref, tbl_ref):
-        c = jnp.minimum(pos_ref[i] + j, smax - 1)
-        return (tbl_ref[i * mp + lax.div(c, p)], 0, lax.rem(c, p), 0)
-
-    def scol(i, j, pos_ref, tbl_ref):
-        c = jnp.minimum(pos_ref[i] + j, smax - 1)
-        return (tbl_ref[i * mp + lax.div(c, p)], 0, lax.rem(c, p))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(k_new.shape[0], t),
-        in_specs=[new_spec, new_spec]
-        + [pl.BlockSpec(memory_space=pltpu.ANY)] * 4,
-        out_specs=[pl.BlockSpec((1, h, 1, d), col),
-                   pl.BlockSpec((1, h, 1), scol),
-                   pl.BlockSpec((1, h, 1, d), col),
-                   pl.BlockSpec((1, h, 1), scol)],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_write_cols_kernel_quant, kind=kind),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(k_q.shape, k_q.dtype),
-                   jax.ShapeDtypeStruct(k_s.shape, k_s.dtype),
-                   jax.ShapeDtypeStruct(v_q.shape, v_q.dtype),
-                   jax.ShapeDtypeStruct(v_s.shape, v_s.dtype)],
-        # operand order: (pos, table, k_new, v_new, k_q, k_s, v_q, v_s)
-        input_output_aliases={4: 0, 5: 1, 6: 2, 7: 3},
-        interpret=use_interpret(),
-    )(jnp.asarray(pos, jnp.int32),
-      jnp.asarray(table, jnp.int32).reshape(-1), k_new, v_new,
-      k_q, k_s, v_q, v_s)
-
-
-def _paged_attn_kernel(pos_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, scale, p, smax, h):
-    r = pl.program_id(0)        # (batch, head) row
-    j = pl.program_id(1)        # logical page index of the horizon
-    nk = pl.num_programs(1)
-    pos = pos_ref[lax.div(r, h)]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    # pages entirely past the row's position contribute nothing — the
-    # same block skip as the contiguous sweep, over remapped chunks
-    @pl.when(j * p <= pos)
-    def _block():
-        q = q_ref[0]                                      # (1, d)
-        k = k_ref[0, 0]                                   # (p, d)
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (1, p)
-        col = lax.broadcasted_iota(jnp.int32, (1, p), 1) + j * p
-        valid = (col <= pos) & (col < smax)
-        s = jnp.where(valid, s, _NEG)
-        v = jnp.where(jnp.transpose(valid), v, 0.0).astype(v.dtype)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        pw = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        l_ref[:] = jnp.broadcast_to(
-            corr * l_ref[:, :1] + jnp.sum(pw, axis=-1, keepdims=True),
-            l_ref.shape)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            pw.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    @pl.when(j == nk - 1)
-    def _finish():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
-                    ).astype(o_ref.dtype)
+    each incoming row is quantized and lands one quantized + one scale
+    cell per lane; same clamped over-horizon contract."""
+    return _write_columns_planes(_quantize_pair(k_new, v_new, kind),
+                                 [k_q, k_s, v_q, v_s], pos, table)
 
 
 def paged_attention(q, k_pool, v_pool, table, pos, *,
@@ -937,98 +655,16 @@ def paged_attention(q, k_pool, v_pool, table, pos, *,
     columns ``0..pos[b]`` with the contiguous kernel's exact masking
     contract; the write is separate (:func:`paged_write_column`) so
     the engine can schedule it against the same dispatch."""
-    b, h, d = q.shape
-    n_pages, _, p, _ = k_pool.shape
-    mp = table.shape[1]
-    smax = mp * p
+    d = q.shape[2]
     s = float(scale) if scale is not None else 1.0 / d ** 0.5
     q, was16 = widen_f16(q)
     k_pool, _ = widen_f16(k_pool)
     v_pool, _ = widen_f16(v_pool)
-    pos = jnp.asarray(pos, jnp.int32)
-    tbl = jnp.asarray(table, jnp.int32).reshape(-1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b * h, mp),
-        in_specs=[
-            pl.BlockSpec((1, 1, d),
-                         lambda r, j, pos_ref, tbl_ref: (r, 0, 0)),
-            pl.BlockSpec(
-                (1, 1, p, d),
-                lambda r, j, pos_ref, tbl_ref: (
-                    tbl_ref[lax.div(r, h) * mp + j], lax.rem(r, h), 0,
-                    0)),
-            pl.BlockSpec(
-                (1, 1, p, d),
-                lambda r, j, pos_ref, tbl_ref: (
-                    tbl_ref[lax.div(r, h) * mp + j], lax.rem(r, h), 0,
-                    0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, d), lambda r, j, pos_ref, tbl_ref: (r, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, _LANES), jnp.float32),
-            pltpu.VMEM((1, _LANES), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, scale=s, p=p, smax=smax,
-                          h=h),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
-        interpret=use_interpret(),
-    )(pos, tbl, q.reshape(b * h, 1, d), k_pool, v_pool)
-    out = out.reshape(b, h, d)
+    out = _run_attn(q, (k_pool, v_pool), jnp.asarray(pos, jnp.int32), s,
+                    bk=k_pool.shape[2], table=table)
     if was16:
         out = out.astype(jnp.float16)
     return out
-
-
-def _paged_attn_kernel_quant(pos_ref, tbl_ref, q_ref, k_ref, ks_ref,
-                             v_ref, vs_ref, o_ref, acc_ref, m_ref,
-                             l_ref, *, scale, p, smax, h):
-    r = pl.program_id(0)
-    j = pl.program_id(1)
-    nk = pl.num_programs(1)
-    pos = pos_ref[lax.div(r, h)]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j * p <= pos)
-    def _block():
-        q = q_ref[0].astype(jnp.float32)              # (1, d)
-        col = lax.broadcasted_iota(jnp.int32, (1, p), 1) + j * p
-        valid = (col <= pos) & (col < smax)
-        kq = k_ref[0, 0].astype(jnp.float32)          # (p, d)
-        s = jax.lax.dot_general(
-            q, kq, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = s * ks_ref[0, 0][None, :] * scale         # (1, p)
-        s = jnp.where(valid, s, _NEG)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        pw = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        l_ref[:] = jnp.broadcast_to(
-            corr * l_ref[:, :1] + jnp.sum(pw, axis=-1, keepdims=True),
-            l_ref.shape)
-        vq = v_ref[0, 0].astype(jnp.float32)
-        vq = jnp.where(jnp.transpose(valid), vq, 0.0)
-        vs = jnp.where(valid[0], vs_ref[0, 0], 0.0)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            pw * vs[None, :], vq, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    @pl.when(j == nk - 1)
-    def _finish():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
-                    ).astype(o_ref.dtype)
 
 
 def paged_attention_quantized(q, k_q, k_s, v_q, v_s, table, pos, *,
@@ -1040,46 +676,11 @@ def paged_attention_quantized(q, k_q, k_s, v_q, v_s, table, pos, *,
     exactly like the contiguous quantized sweep."""
     if kind not in KV_QMAX:
         raise ValueError(f"unknown quantized-KV kind {kind!r}")
-    b, h, d = q.shape
-    n_pages, _, p, _ = k_q.shape
-    mp = table.shape[1]
-    smax = mp * p
+    d = q.shape[2]
     s = float(scale) if scale is not None else 1.0 / d ** 0.5
     q, was16 = widen_f16(q)
-    pos = jnp.asarray(pos, jnp.int32)
-    tbl = jnp.asarray(table, jnp.int32).reshape(-1)
-    page_spec = pl.BlockSpec(
-        (1, 1, p, d),
-        lambda r, j, pos_ref, tbl_ref: (
-            tbl_ref[lax.div(r, h) * mp + j], lax.rem(r, h), 0, 0))
-    scale_spec = pl.BlockSpec(
-        (1, 1, p),
-        lambda r, j, pos_ref, tbl_ref: (
-            tbl_ref[lax.div(r, h) * mp + j], lax.rem(r, h), 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b * h, mp),
-        in_specs=[
-            pl.BlockSpec((1, 1, d),
-                         lambda r, j, pos_ref, tbl_ref: (r, 0, 0)),
-            page_spec, scale_spec, page_spec, scale_spec,
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, d), lambda r, j, pos_ref, tbl_ref: (r, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, _LANES), jnp.float32),
-            pltpu.VMEM((1, _LANES), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel_quant, scale=s, p=p,
-                          smax=smax, h=h),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
-        interpret=use_interpret(),
-    )(pos, tbl, q.reshape(b * h, 1, d), k_q, k_s, v_q, v_s)
-    out = out.reshape(b, h, d)
+    out = _run_attn(q, (k_q, k_s, v_q, v_s), jnp.asarray(pos, jnp.int32),
+                    s, bk=k_q.shape[2], table=table)
     if was16:
         out = out.astype(jnp.float16)
     return out
@@ -1092,7 +693,7 @@ def decode_attention_quantized(q, k_new, v_new, k_q, k_scale, v_q,
     """:func:`decode_attention` over the quantized cache layout: K/V
     stored as ``kind`` (``"int8"``/``"fp8"``) ``[b, h, S, d]`` with
     per-head, per-slot, per-position fp32 scales ``[b, h, S]``. The
-    incoming ``k_new``/``v_new [b, h, d]`` rows are quantized in-kernel
+    incoming ``k_new``/``v_new [b, h, d]`` rows are quantized
     (:func:`quantize_kv_rows` — bit-identical to the XLA fallback and
     bulk prefill) and written as one quantized + one scale column at
     each row's ``pos``; the split-K sweep reads the narrow cache and
@@ -1118,16 +719,13 @@ def decode_attention_quantized(q, k_new, v_new, k_q, k_scale, v_q,
         raise ValueError(f"unknown quantized-KV kind {kind!r}")
     s = float(scale) if scale is not None else 1.0 / d ** 0.5
     q, was16 = widen_f16(q)
-    k_new, _ = widen_f16(k_new)
-    v_new, _ = widen_f16(v_new)
     pos = jnp.asarray(pos, jnp.int32)
-    k_q, k_scale, v_q, v_scale = _write_column_quant(
-        k_new, v_new, k_q, k_scale, v_q, v_scale, pos, kind)
-    out = _run_attn_quant(
-        q.reshape(b * h, d), k_q.reshape(b * h, sk, d),
-        k_scale.reshape(b * h, sk), v_q.reshape(b * h, sk, d),
-        v_scale.reshape(b * h, sk), pos, s, h, block_k,
-    ).reshape(b, h, d)
+    k_q, k_scale, v_q, v_scale = _write_column_planes(
+        _quantize_pair(k_new, v_new, kind),
+        [k_q, k_scale, v_q, v_scale], pos)
+    # the fp32 scale rows put the chunk on the lane dimension too
+    bk = _fit_block_k(block_k or _DEFAULT_BLOCK_K, sk, _LANES)
+    out = _run_attn(q, (k_q, k_scale, v_q, v_scale), pos, s, bk=bk)
     if was16:
         out = out.astype(jnp.float16)
     return out, k_q, k_scale, v_q, v_scale

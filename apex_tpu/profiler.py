@@ -27,7 +27,6 @@ import time
 from typing import Any, Dict, List, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.telemetry.ring import Ring
@@ -47,16 +46,6 @@ def trace(logdir: str):
 def annotate(name: str):
     """Named trace range (nvtx.range_push/pop (U) equivalent)."""
     return jax.profiler.TraceAnnotation(name)
-
-
-def _sync(value):
-    """Device barrier that survives remote-attached runtimes: fetch one
-    element's value instead of trusting block_until_ready."""
-    if value is None:
-        return
-    leaf = jax.tree_util.tree_leaves(value)[0]
-    arr = jnp.asarray(leaf)
-    _ = np.asarray(jax.device_get(arr.ravel()[0] if arr.ndim else arr))
 
 
 class StepTimer:
@@ -83,8 +72,9 @@ class StepTimer:
     def tick(self, sync_on: Any = None) -> float:
         """Record one step boundary; returns the step's duration (0.0 on
         the first call). ``sync_on``: any device value produced by the
-        step — fetched to pin the measurement to real execution."""
-        _sync(sync_on)
+        step — waited on to pin the measurement to real execution."""
+        if sync_on is not None:
+            jax.block_until_ready(sync_on)
         now = time.perf_counter()
         dt = 0.0 if self._last is None else now - self._last
         self._last = now

@@ -15,8 +15,7 @@ in tests; this module makes it observable and enforceable at runtime:
   compiles AND persistent-cache loads, never on in-memory jit-cache
   hits, so it is exactly "a program the warmup didn't cover". Tracked
   functions (``sentinel.track(name, jitted_fn)``) add per-function
-  attribution by polling ``_cache_size`` — also the complete fallback
-  on legacy runtimes without ``jax.monitoring``.
+  attribution by polling ``_cache_size``.
 - :class:`RecompileGuard` is the armed form: entered after warmup, any
   compile event attributed to this sentinel (or unclaimed by every
   live sentinel) increments an alarm counter and — configurably —
@@ -61,8 +60,7 @@ from apex_tpu import _compat
 
 #: the duration event that marks a new executable materialising
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-#: lowering happens once per new traced variant — the cache-miss
-#: counter that backs the legacy fallback's cross-check
+#: lowering happens once per new traced variant
 LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
@@ -101,7 +99,6 @@ class _CompileHub:
         self._unregister: Optional[Callable[[], None]] = None
         self._pending: List[str] = []   # unattributed event details
         self._expected_depth = 0
-        self.available = False
 
     # -- sanctioned compile windows -----------------------------------------
 
@@ -127,17 +124,14 @@ class _CompileHub:
 
     # -- sentinel lifecycle --------------------------------------------------
 
-    def attach(self, sentinel: "RecompileSentinel") -> bool:
-        """Register ``sentinel`` for event delivery; returns whether
-        the monitoring stream is live (first attach performs the one
-        process-wide registration)."""
+    def attach(self, sentinel: "RecompileSentinel") -> None:
+        """Register ``sentinel`` for event delivery (first attach
+        performs the one process-wide registration)."""
         with self._lock:
             if not self._sentinels:
                 self._unregister = _compat.register_monitoring_listeners(
                     self._on_event, self._on_duration)
-                self.available = self._unregister is not None
             self._sentinels.append(sentinel)
-            return self.available
 
     def detach(self, sentinel: "RecompileSentinel") -> None:
         """Drop ``sentinel``; the last detach releases the process
@@ -151,7 +145,6 @@ class _CompileHub:
             if self._sentinels:
                 return
             unregister, self._unregister = self._unregister, None
-            self.available = False
             self._pending.clear()
         if unregister is not None:
             unregister()
@@ -273,7 +266,6 @@ class RecompileSentinel:
         #: compiles_total themselves)
         self._sizes_seen: Dict[str, int] = {}
         self._installed = False
-        self.monitoring_available = False
         self._guards: List["RecompileGuard"] = []
         self._m_compiles = self._m_lowerings = None
         self._m_compile_secs = self._m_alarms = None
@@ -298,11 +290,9 @@ class RecompileSentinel:
     def install(self) -> "RecompileSentinel":
         """Attach to the shared process listener (idempotent; the hub
         refcounts, so N live sentinels hold ONE ``jax.monitoring``
-        registration). Without ``jax.monitoring`` this is a no-op and
-        only tracked-function cache polling is live
-        (``monitoring_available`` says which)."""
+        registration)."""
         if not self._installed:
-            self.monitoring_available = _HUB.attach(self)
+            _HUB.attach(self)
             self._installed = True
         return self
 
@@ -311,7 +301,6 @@ class RecompileSentinel:
         flag is cleared BEFORE the hub detach so a re-entrant or
         repeated uninstall can never double-release)."""
         was_installed, self._installed = self._installed, False
-        self.monitoring_available = False
         if was_installed:
             _HUB.detach(self)
 
@@ -373,8 +362,7 @@ class RecompileSentinel:
     def track(self, name: str, fn) -> None:
         """Attribute compiles to ``name`` by polling ``fn._cache_size``
         (any ``jax.jit`` result). Snapshot deltas are per-function
-        ``compiles_total`` — and the whole mechanism on legacy runtimes
-        without monitoring. Entries already in the cache at track time
+        ``compiles_total``. Entries already in the cache at track time
         are never claimed retroactively."""
         self._tracked[name] = fn
         size = _cache_size(fn)
@@ -406,7 +394,6 @@ class RecompileSentinel:
         with self._lock:
             out: Dict[str, Any] = dict(self._counts)
             out["compile_seconds"] = self._compile_seconds
-        out["monitoring_available"] = self.monitoring_available
         out["tracked"] = {name: _cache_size(fn)
                           for name, fn in self._tracked.items()}
         return out
@@ -451,8 +438,6 @@ class RecompileGuard:
         if exc_type is None:
             # always check on exit: with raise_on_recompile=False this
             # still records the breach in alarms / the alarm counter
-            # (the only detection path on runtimes where tracked-cache
-            # polling is the signal)
             self.check()
 
     def _alarm(self, detail: str) -> None:
@@ -492,9 +477,10 @@ class RecompileGuard:
         _HUB.resolve(final=True)
         delta = self.delta()
         if delta and not self.alarms:
-            # breach seen only through cache polling (legacy runtime,
-            # or growth the event stream missed): record it so the
-            # alarm list and counter reflect it even without raising
+            # breach seen only through cache polling (a new jit-cache
+            # entry that reused an executable fires no compile event):
+            # record it so the alarm list and counter reflect it even
+            # without raising
             self._alarm(f"tracked-cache growth {delta}")
             if self._sentinel._m_alarms is not None:
                 self._sentinel._m_alarms.inc()

@@ -23,19 +23,13 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_threefry_partitionable", True)
 
-# Persistent compile cache (the repo-local .jax_cache bench already
-# uses): test models are tiny, so XLA compile time dominates the
-# CPU-mesh suite — a warm cache halves wall time. Set via jax.config,
-# NOT os.environ: the example-smoke subprocesses must not inherit it
-# (this runtime crashes restoring a cached executable alongside a
-# checkpoint resume — heap corruption in jaxlib, numpy-fallback
-# confirmed native-runtime-clean). An existing JAX_COMPILATION_CACHE_DIR
-# wins (empty value disables, matching _capabilities).
-if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"))
+# Persistent compile cache, placed by the rule every entry point shares
+# (apex_tpu._capabilities.enable_compilation_cache): test models are
+# tiny, so XLA compile time dominates the CPU-mesh suite — a warm cache
+# halves wall time, provided even sub-second compiles are kept.
+from apex_tpu._capabilities import enable_compilation_cache  # noqa: E402
+
+if enable_compilation_cache():
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 import pytest  # noqa: E402

@@ -450,11 +450,9 @@ def test_quantized_prefix_guard_stays_flat(devices8):
             assert g.check() == {}
         assert not g.tripped
         assert eng.compiled_cache_sizes() == sizes0
-        sent = eng.recompile_sentinel()
-        if sent.monitoring_available:
-            with pytest.raises(RecompileError):
-                with eng.recompile_guard():
-                    jax.jit(lambda x: x * 3.0)(np.arange(5.0))
+        with pytest.raises(RecompileError):
+            with eng.recompile_guard():
+                jax.jit(lambda x: x * 3.0)(np.arange(5.0))
     finally:
         eng.close()
 

@@ -275,10 +275,12 @@ def test_metrics_logger_ring_ctx_and_registry(tmp_path):
                                    "step": 0})
 
 
-def test_annotate_and_sync():
+def test_annotate_and_tick_sync():
     with profiler.annotate("test-range"):
         y = jnp.sum(jnp.arange(10.0))
-    profiler._sync(y)
+    timer = profiler.StepTimer()
+    assert timer.tick(y) == 0.0    # first boundary; waits on y
+    assert timer.tick({"loss": y}) > 0.0   # any pytree of arrays
     assert float(y) == 45.0
 
 

@@ -5,6 +5,7 @@ checkpoints; algebraic identities for weight norm.
 """
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -174,6 +175,32 @@ def test_capabilities_registry():
     assert isinstance(caps["native_host_runtime"], bool)
     assert apex_tpu.has_capability("xentropy")
     assert not apex_tpu.has_capability("nonexistent_feature")
+
+
+@pytest.mark.parametrize("env", ["/some/where/else", "", None])
+def test_enable_compilation_cache_placement(monkeypatch, env):
+    """The one cache-placement rule: a set JAX_COMPILATION_CACHE_DIR is
+    the directory (JAX reads it itself, an empty value disabling the
+    cache) and code names no other; an unset one gives the fixed
+    <checkout>/.jax_cache."""
+    import os
+
+    from apex_tpu._capabilities import enable_compilation_cache
+
+    named = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda key, value: named.append((key, value)))
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    got = enable_compilation_cache()
+    if env is None:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(repo, ".jax_cache")
+        assert named == [("jax_compilation_cache_dir", got)]
+    else:
+        assert got == env and named == []
 
 
 def test_capabilities_repeated_access():

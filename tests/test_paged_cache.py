@@ -132,7 +132,7 @@ def test_paged_decode_logits_oracle(devices8, kind):
 
     oc, op, la_c, la_p, lf_c, lf_p = jax.jit(jax.shard_map(
         run, mesh=mesh, in_specs=(pspecs, P(None), P(None, None)),
-        out_specs=P(*[None] * 3), check_vma=False))(params, tok, table)
+        out_specs=P(), check_vma=False))(params, tok, table)
     np.testing.assert_array_equal(np.asarray(oc), np.asarray(op))
     np.testing.assert_array_equal(np.asarray(la_c), np.asarray(la_p))
     np.testing.assert_array_equal(np.asarray(lf_c), np.asarray(lf_p))

@@ -199,31 +199,12 @@ def test_syncbn_grads_match_full_batch(dp8):
         return jnp.mean(y ** 2)
 
     # check_vma=True so psum transposes efficiently (replicated cotangents);
-    # grads of replicated params come out correctly reduced. Legacy
-    # check_rep can't infer replication through a grad-of-psum (and with
-    # the check off, the psum transpose over-counts replicated
-    # cotangents by the axis size) — there, differentiate the LOCAL
-    # loss piece and psum the grads instead: L = Σ_d L_d, so
-    # ∇L = psum(∇L_d), the same math with correct unreplicated-cotangent
-    # transposes (the numeric oracle below pins both forms).
-    from apex_tpu import _compat
-
-    def grads(scale, bias, x):
-        if not _compat.LEGACY_SHARD_MAP:
-            return jax.grad(loss_sharded, argnums=(0, 1))(scale, bias, x)
-
-        def loss_local(scale, bias, x):
-            y, _, _ = sync_batch_norm(x, scale, bias)
-            return jnp.sum(y ** 2) / (n * c * 4)
-
-        g = jax.grad(loss_local, argnums=(0, 1))(scale, bias, x)
-        return jax.tree_util.tree_map(lambda t: jax.lax.psum(t, "dp"), g)
-
+    # grads of replicated params come out correctly reduced.
     g = jax.jit(jax.shard_map(
-        grads, mesh=dp8,
+        jax.grad(loss_sharded, argnums=(0, 1)), mesh=dp8,
         in_specs=(P(), P(), P("dp", None, None, None)),
         out_specs=(P(), P()),
-        check_vma=not _compat.LEGACY_SHARD_MAP))(scale, bias, x)
+        check_vma=True))(scale, bias, x)
     gref = jax.grad(loss_ref, argnums=(0, 1))(scale, bias, x)
     np.testing.assert_allclose(np.asarray(g[0]), np.asarray(gref[0]),
                                rtol=1e-4, atol=1e-5)

@@ -179,8 +179,6 @@ def test_recompile_sentinel_counts_and_guard_trip():
     reg = Registry()
     sent = rc.RecompileSentinel(registry=reg).install()
     try:
-        if not sent.monitoring_available:
-            pytest.skip("runtime has no jax.monitoring")
         f = jax.jit(lambda x: x * 3 + 1)
         before = sent.compiles_total()
         f(jnp.ones((4,)))  # first call: an executable materialises
@@ -223,23 +221,14 @@ def test_recompile_sentinel_counts_and_guard_trip():
 
 def test_sentinel_uninstall_releases_listener():
     """install/uninstall is listener-neutral — engines created in a
-    loop must not grow jax.monitoring's listener list (uninstall used
-    to silently no-op: the private unregister helpers live on
-    jax._src.monitoring, not the public re-export). All live sentinels
-    now share ONE refcounted hub listener: a second sentinel adds no
-    registration, and the LAST uninstall releases the one there is —
+    loop must not grow jax.monitoring's listener list. All live
+    sentinels share ONE refcounted hub listener: a second sentinel adds
+    no registration, and the LAST uninstall releases the one there is —
     pinned here so N engine replicas hold exactly one listener."""
-    try:
-        from jax._src import monitoring as impl
-    except ImportError:
-        pytest.skip("no jax._src.monitoring")
-    get = getattr(impl, "get_event_duration_listeners", None)
-    if get is None:
-        pytest.skip("runtime lacks listener introspection")
+    from jax._src.monitoring import get_event_duration_listeners as get
+
     n0 = len(get())
     sent = rc.RecompileSentinel().install()
-    if not sent.monitoring_available:
-        pytest.skip("runtime has no jax.monitoring")
     assert len(get()) == n0 + 1
     sent.install()  # idempotent: no second registration
     assert len(get()) == n0 + 1
@@ -254,35 +243,37 @@ def test_sentinel_uninstall_releases_listener():
     sent.uninstall()  # idempotent
 
 
-def test_recompile_guard_cache_poll_fallback(monkeypatch):
-    """Legacy runtimes without jax.monitoring: the sentinel degrades to
-    tracked-function jit-cache polling and the guard still trips."""
+def test_register_monitoring_listeners_round_trip():
+    """The one seam onto jax.monitoring: both listeners receive the
+    installed runtime's compile events, and the returned callable
+    releases exactly the pair it registered."""
+    from jax._src.monitoring import (
+        get_event_duration_listeners,
+        get_event_listeners,
+    )
+
     from apex_tpu import _compat
 
-    monkeypatch.setattr(_compat, "register_monitoring_listeners",
-                        lambda *a: None)
-    reg = Registry()
-    sent = rc.RecompileSentinel(registry=reg).install()
-    assert not sent.monitoring_available
-    f = jax.jit(lambda x: x - 2)
-    f(jnp.ones((3,)))
-    sent.track("f", f)
-    with sent.guard() as g:
-        f(jnp.ones((3,)))
-        assert g.check() == {}
-    with pytest.raises(RecompileError, match="tracked"):
-        with sent.guard():
-            f(jnp.ones((6,)))
-    # the breach is visible on the alarm counter even though no event
-    # listener exists — cache-poll detection feeds the same metric
-    assert reg.counter("recompile_alarms_total").value == 1.0
-    # ...and with raise_on_recompile=False the exit check still records
-    with sent.guard(raise_on_recompile=False) as g:
-        f(jnp.ones((9,)))
-    assert g.tripped and g.alarms
-    assert reg.counter("recompile_alarms_total").value == 2.0
-    assert sent.compiles_total()["backend_compiles"] == 0  # no listener
-    sent.uninstall()  # no-op, must not raise
+    points, durations = [], []
+    on_event = lambda name, **kw: points.append(name)
+    on_duration = lambda name, secs, **kw: durations.append(name)
+    n_ev = len(get_event_listeners())
+    n_dur = len(get_event_duration_listeners())
+    unregister = _compat.register_monitoring_listeners(on_event,
+                                                       on_duration)
+    try:
+        assert len(get_event_listeners()) == n_ev + 1
+        assert len(get_event_duration_listeners()) == n_dur + 1
+        jax.jit(lambda x: x * 5 - 3)(jnp.ones((11,)))
+        assert rc.BACKEND_COMPILE_EVENT in durations
+    finally:
+        unregister()
+    assert on_event not in get_event_listeners()
+    assert on_duration not in get_event_duration_listeners()
+    assert len(get_event_listeners()) == n_ev
+    n_seen = len(durations)
+    jax.jit(lambda x: x * 7 - 1)(jnp.ones((13,)))
+    assert len(durations) == n_seen
 
 
 # --- the engine acceptance: warmup → guard → flat --------------------------
@@ -395,8 +386,6 @@ def test_engine_recompile_guard_stays_flat(served_engine):
         "admit_p8_k1": 1, "admit_p8_k2": 1,
         "admit_p10_k1": 1, "admit_p10_k2": 1}
     assert eng.compiled_cache_sizes() == sizes0
-    if not sent.monitoring_available:
-        pytest.skip("no jax.monitoring: event-trip half needs it")
     # the same guard trips on a deliberately shape-busting call
     with pytest.raises(RecompileError, match="RecompileGuard"):
         with eng.recompile_guard():
