@@ -10,9 +10,10 @@ Both directions are checked:
   ``bench.py`` that no ``registry.counter/gauge/histogram`` call in
   ``apex_tpu/telemetry`` or ``apex_tpu/serving`` registers is drift
   (anchored at the doc mention);
-- a registered ``serving_*``/``api_*`` metric — or ``engine.*`` span
-  section — that ``docs/API.md`` never mentions is an undocumented
-  export (anchored at the registration site, suppressible there).
+- a registered ``serving_*``/``api_*`` metric — or ``engine.*`` /
+  ``sched.*`` span section — that ``docs/API.md`` never mentions is an
+  undocumented export (anchored at the registration site, suppressible
+  there).
 
 Doc tokens support the label and brace-alternation shorthand the docs
 already use: ``serving_requests_shed_total{reason="..."}`` is the bare
@@ -32,13 +33,18 @@ from apex_tpu.analysis._astutil import const_str
 from apex_tpu.analysis.core import Finding, Project
 
 _REGISTER_METHODS = {"counter", "gauge", "histogram"}
-_SPAN_METHODS = {"section", "section_at"}
 _METRIC_PREFIX = re.compile(r"^(serving|api)_[a-z0-9_]+$")
-_SPAN_PREFIX = re.compile(r"^engine\.[a-z_]+$")
+#: a call whose first argument is such a constant records that section
+#: (``spans.section``, ``section_at``, or a wrapper around them)
+_SPAN_PREFIX = re.compile(r"^(engine|sched)\.[a-z_]+$")
 
 _DOC_METRIC_TOKEN = re.compile(
     r"\b((?:serving|api)_[a-z0-9_]+(?:\{[^}\n]*\}[a-z0-9_]*)?)")
 _DOC_SPAN_TOKEN = re.compile(r"\bengine\.([a-z_]+)\b")
+#: ``sched`` is also what code samples and bench.py call their
+#: Scheduler, so a ``sched.<x>`` token is a span claim only where it
+#: stands alone in backticks or quotes
+_DOC_SCHED_TOKEN = re.compile(r"[`\"'](sched\.[a-z_]+)[`\"']")
 #: an unregistered doc mention is only drift when it looks like a
 #: metric, not a JSON key that happens to share the prefix
 _CANONICAL_SUFFIX = ("_total", "_seconds", "_bytes", "_state")
@@ -89,19 +95,17 @@ class MetricDriftRule:
                     ctx.rel.startswith(p) for p in _REGISTRY_SUBTREES):
                 continue
             for node in ast.walk(ctx.tree):
-                if not (isinstance(node, ast.Call)
-                        and isinstance(node.func, ast.Attribute)
-                        and node.args):
+                if not (isinstance(node, ast.Call) and node.args):
                     continue
                 name = const_str(node.args[0])
                 if name is None:
                     continue
-                if node.func.attr in _REGISTER_METHODS and \
+                if _SPAN_PREFIX.match(name):
+                    spans.setdefault(name, (ctx.rel, node.lineno))
+                elif isinstance(node.func, ast.Attribute) and \
+                        node.func.attr in _REGISTER_METHODS and \
                         _METRIC_PREFIX.match(name):
                     registered.setdefault(name, (ctx.rel, node.lineno))
-                elif node.func.attr in _SPAN_METHODS and \
-                        _SPAN_PREFIX.match(name):
-                    spans.setdefault(name, (ctx.rel, node.lineno))
 
         if not registered and not spans:
             return []  # nothing to drift against (synthetic tree)
@@ -130,6 +134,10 @@ class MetricDriftRule:
                                 f"never registered in apex_tpu/telemetry"
                                 f" or apex_tpu/serving — renamed or "
                                 f"removed without updating the doc"))
+                claims = [m.group(1)
+                          for m in _DOC_SCHED_TOKEN.finditer(line)]
+                if rel == "docs/API.md":
+                    mentioned_api.update(claims)
                 for m in _DOC_SPAN_TOKEN.finditer(line):
                     name = f"engine.{m.group(1)}"
                     if rel == "docs/API.md":
@@ -140,14 +148,16 @@ class MetricDriftRule:
                     # (engine.admit, engine.fetch) is still a span claim
                     # and must be backed by a registration
                     is_call = line[m.end():m.end() + 1] == "("
-                    if name not in spans and not (
-                            is_call and m.group(1) in engine_api):
+                    if not (is_call and m.group(1) in engine_api):
+                        claims.append(name)
+                for name in claims:
+                    if name not in spans:
                         findings.append(Finding(
                             self.id, rel, lineno,
                             f"span section {name!r} is mentioned here "
-                            f"but never emitted by any spans.section/"
-                            f"section_at call — renamed or removed "
-                            f"without updating the doc"))
+                            f"but never emitted by any call that names "
+                            f"it — renamed or removed without updating "
+                            f"the doc"))
         for name, (rel, lineno) in sorted(registered.items()):
             if name not in mentioned_api:
                 findings.append(Finding(

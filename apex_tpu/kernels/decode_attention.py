@@ -168,6 +168,7 @@ def _write_column_planes(news, planes, pos, table=None):
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in planes],
         # operand order: (*scalars, *news, *planes)
         input_output_aliases={n_scalar + n + k: k for k in range(n)},
+        name="decode_attn_write",
         interpret=use_interpret(),
     )(*scalars,
       *[jnp.expand_dims(new, 2).astype(plane.dtype)
@@ -391,6 +392,7 @@ def _run_attn(q, planes, pos, scale, *, bk, table=None):
                           quant=quant, scale=scale, bk=bk, smax=smax),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+        name="decode_attn_read",
         interpret=use_interpret(),
     )(*scalars, q[:, :, None], *operands)
     return out[:, :, 0]

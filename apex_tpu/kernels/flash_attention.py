@@ -405,6 +405,7 @@ def _run_fwd(q, k, v, lengths, segments, scale, causal, block_q=None,
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
+        name="flash_attn_fwd",
         interpret=use_interpret(),
     )(*operands)
     return out[:, :sq, :d], lse[:, :sq, :1]
@@ -527,6 +528,7 @@ def _run_bwd(q, k, v, do, lse, delta, lengths, segments, scale, causal,
                 pltpu.VMEM((bk, dp), jnp.float32),
                 pltpu.VMEM((bk, dp), jnp.float32),
             ],
+            name="flash_attn_bwd",
             interpret=use_interpret(),
         )(*operands)
         return (dq[:, :sq, :d].astype(q.dtype),
@@ -552,6 +554,7 @@ def _run_bwd(q, k, v, do, lse, delta, lengths, segments, scale, causal,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, sqp, dp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, dp), jnp.float32)],
+        name="flash_attn_bwd_dq",
         interpret=use_interpret(),
     )(*operands)
 
@@ -580,6 +583,7 @@ def _run_bwd(q, k, v, do, lse, delta, lengths, segments, scale, causal,
             pltpu.VMEM((bk, dp), jnp.float32),
             pltpu.VMEM((bk, dp), jnp.float32),
         ],
+        name="flash_attn_bwd_dkv",
         interpret=use_interpret(),
     )(*operands2)
     return (dq[:, :sq, :d].astype(q.dtype),
@@ -1017,6 +1021,7 @@ def _run_fwd_bsh(q, k, v, lengths, segments, scale, causal, d, g, n_grp,
             pltpu.VMEM((bq, g), jnp.float32),
             pltpu.VMEM((bq, g), jnp.float32),
         ],
+        name="flash_attn_fwd_bsh",
         interpret=use_interpret(),
     )(*operands)
     return out[:, :sq], lse[:, :, :sq]
@@ -1073,6 +1078,7 @@ def _run_bwd_bsh(q, k, v, do, lse, delta, lengths, segments, scale, causal,
             pltpu.VMEM((bk, LANE), jnp.float32),
             pltpu.VMEM((bk, LANE), jnp.float32),
         ],
+        name="flash_attn_bwd_bsh",
         interpret=use_interpret(),
     )(*operands)
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]

@@ -100,6 +100,7 @@ def scale_flat(bufs: Sequence[jnp.ndarray], scale) -> Tuple[List[jnp.ndarray], j
                 jax.ShapeDtypeStruct(x2.shape, buf.dtype),
                 jax.ShapeDtypeStruct((1, 1), jnp.int32),
             ],
+            name="flat_scale",
             interpret=use_interpret(),
         )(s, x2)
         outs.append(_narrow(out.reshape(-1), want))
@@ -151,6 +152,7 @@ def axpby_flat(a, xbufs: Sequence[jnp.ndarray], b, ybufs: Sequence[jnp.ndarray],
                 jax.ShapeDtypeStruct(x2.shape, dt),
                 jax.ShapeDtypeStruct((1, 1), jnp.int32),
             ],
+            name="flat_axpby",
             interpret=use_interpret(),
         )(s, x2, y2)
         outs.append(_narrow(out.reshape(-1), want))
@@ -190,6 +192,7 @@ def l2norm_flat(bufs: Sequence[jnp.ndarray]) -> jnp.ndarray:
             in_specs=[_vspec(bm)],
             out_specs=_smem_spec((1, 1)),
             out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            name="flat_sumsq",
             interpret=use_interpret(),
         )(x2)
         total = total + acc[0, 0]
@@ -267,6 +270,7 @@ def adam_flat(p_bufs, g_bufs, m_bufs, v_bufs, *, lr, b1, b2, eps, weight_decay,
                 jax.ShapeDtypeStruct(m2.shape, jnp.float32),
                 jax.ShapeDtypeStruct(v2.shape, jnp.float32),
             ],
+            name="flat_adam",
             interpret=use_interpret(),
         )(s, p2, g2, m2, v2)
         new_p.append(_narrow(np_.reshape(-1), want))
@@ -327,6 +331,7 @@ def sgd_flat(p_bufs, g_bufs, m_bufs, *, lr, momentum, dampening, weight_decay,
                 jax.ShapeDtypeStruct(p2.shape, pb.dtype),
                 jax.ShapeDtypeStruct(m2.shape, jnp.float32),
             ],
+            name="flat_sgd",
             interpret=use_interpret(),
         )(s, p2, g2, m2)
         new_p.append(_narrow(np_.reshape(-1), want))
@@ -377,6 +382,7 @@ def adagrad_flat(p_bufs, g_bufs, h_bufs, *, lr, eps, weight_decay,
                 jax.ShapeDtypeStruct(p2.shape, pb.dtype),
                 jax.ShapeDtypeStruct(h2.shape, jnp.float32),
             ],
+            name="flat_adagrad",
             interpret=use_interpret(),
         )(s, p2, g2, h2)
         new_p.append(_narrow(np_.reshape(-1), want))

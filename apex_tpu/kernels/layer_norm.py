@@ -141,6 +141,7 @@ def _fwd(x2, w, b, eps: float, subtract_mean: bool):
             jax.ShapeDtypeStruct((rp, 1), jnp.float32),
             jax.ShapeDtypeStruct((rp, 1), jnp.float32),
         ],
+        name="layer_norm_fwd",
         interpret=use_interpret(),
     )(xp, wp, bp)
     return y[:rows, :hidden], mean[:rows], rstd[:rows]
@@ -178,6 +179,7 @@ def _bwd(x2, w, mean, rstd, dy2, subtract_mean: bool):
             jax.ShapeDtypeStruct((1, hp), jnp.float32),
             jax.ShapeDtypeStruct((1, hp), jnp.float32),
         ],
+        name="layer_norm_bwd",
         interpret=use_interpret(),
     )(xp, wp, meanp, rstdp, dyp)
     return dx[:rows, :hidden], dw[0, :hidden], db[0, :hidden]

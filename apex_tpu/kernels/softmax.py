@@ -96,6 +96,7 @@ def _run_fwd(x3, mask3, scale: float, causal: bool):
         out_specs=pl.BlockSpec((1, bm, skp), lambda i, j: (i, j, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((nb, sqp, skp), x3.dtype),
+        name="scaled_softmax_fwd",
         interpret=use_interpret(),
     )(*operands)
     return y[:, :sq, :sk]
@@ -118,6 +119,7 @@ def _run_bwd(y3, dy3, scale: float):
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((nb, sqp, skp), y3.dtype),
+        name="scaled_softmax_bwd",
         interpret=use_interpret(),
     )(yp, dyp)
     return dx[:, :sq, :sk]

@@ -92,6 +92,7 @@ def _run_fwd(x2, t2, smoothing: float, ignore_index: int):
             jax.ShapeDtypeStruct((rp, 1), jnp.float32),
             jax.ShapeDtypeStruct((rp, 1), jnp.float32),
         ],
+        name="xentropy_fwd",
         interpret=use_interpret(),
     )(xp, tp)
     return loss[:rows, 0], lse[:rows]
@@ -117,6 +118,7 @@ def _run_bwd(x2, t2, lse, g, smoothing: float, ignore_index: int):
         out_specs=pl.BlockSpec((bm, vp), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rp, vp), x2.dtype),
+        name="xentropy_bwd",
         interpret=use_interpret(),
     )(xp, tp, lsep, gp)
     return dx[:rows, :vocab]

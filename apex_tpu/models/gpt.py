@@ -664,26 +664,29 @@ def _block(cfg: GPTConfig, p, h, *, return_kv: bool = False,
     when ``return_kv`` (bulk prefill's cache capture). ``lora`` is the
     per-layer ``(page, ids, scale)`` adapter bundle (serving prefill
     only — training never threads it)."""
-    x = _layer_norm(cfg, h, p["ln1"]["scale"], p["ln1"]["bias"])
-    attn = _attention(cfg, p["attn"], x, return_kv=return_kv,
-                      lora=lora)
-    kv = None
-    if return_kv:
-        attn, kv = attn
-    h = h + attn
-    x = _layer_norm(cfg, h, p["ln2"]["scale"], p["ln2"]["bias"])
-    if cfg.num_experts:
-        if cfg.sequence_parallel:
-            raise ValueError(
-                "num_experts > 0 does not compose with sequence_parallel "
-                "(MoE routes over full-h activations); shard the batch "
-                "over ep instead")
-        b, s, hd = x.shape
-        y, aux = moe_mod.moe_ffn(
-            _moe_cfg(cfg), p["moe"], x.reshape(b * s, hd))
-        h = h + y.reshape(b, s, hd)
-    else:
-        h, aux = h + _mlp(cfg, p["mlp"], x, lora=lora), jnp.float32(0.0)
+    with jax.named_scope("apex.attn"):
+        x = _layer_norm(cfg, h, p["ln1"]["scale"], p["ln1"]["bias"])
+        attn = _attention(cfg, p["attn"], x, return_kv=return_kv,
+                          lora=lora)
+        kv = None
+        if return_kv:
+            attn, kv = attn
+        h = h + attn
+    if cfg.num_experts and cfg.sequence_parallel:
+        raise ValueError(
+            "num_experts > 0 does not compose with sequence_parallel "
+            "(MoE routes over full-h activations); shard the batch "
+            "over ep instead")
+    with jax.named_scope("apex.mlp"):
+        x = _layer_norm(cfg, h, p["ln2"]["scale"], p["ln2"]["bias"])
+        if cfg.num_experts:
+            b, s, hd = x.shape
+            y, aux = moe_mod.moe_ffn(
+                _moe_cfg(cfg), p["moe"], x.reshape(b * s, hd))
+            h = h + y.reshape(b, s, hd)
+        else:
+            h, aux = (h + _mlp(cfg, p["mlp"], x, lora=lora),
+                      jnp.float32(0.0))
     if return_kv:
         return h, aux, kv
     return h, aux
@@ -706,6 +709,7 @@ def _cp_slice(cfg: GPTConfig, x, dim: int):
     return lax.dynamic_slice_in_dim(x, r * (s // cp), s // cp, dim)
 
 
+@jax.named_scope("apex.embed")
 def _embed(cfg: GPTConfig, params, tokens):
     """tokens [b, s] → entry activation [b, s(_local under SP/CP),
     hidden]."""
@@ -739,8 +743,11 @@ def _scan_blocks(cfg: GPTConfig, h, layers):
 
     if cfg.remat:
         body = tpr.checkpoint(body, policy=_remat_policy(cfg))
-    (h, aux), _ = lax.scan(
-        body, (h, jnp.float32(0.0)), layers, unroll=cfg.scan_unroll)
+    # the scan's own slicing of the stacked layer params (and, under
+    # remat, the stacking of what it saves) carries this scope alone
+    with jax.named_scope("apex.layers"):
+        (h, aux), _ = lax.scan(
+            body, (h, jnp.float32(0.0)), layers, unroll=cfg.scan_unroll)
     return h, aux
 
 
@@ -752,8 +759,9 @@ def hidden_states_and_aux(cfg: GPTConfig, params, tokens):
                           params["layers"])
     # final LN runs inside the SP region (Megatron: its grads are
     # tp-partial — see seq_partial_grad_mask)
-    return _layer_norm(cfg, h, params["final_ln"]["scale"],
-                       params["final_ln"]["bias"]), aux
+    with jax.named_scope("apex.ce_head"):
+        return _layer_norm(cfg, h, params["final_ln"]["scale"],
+                           params["final_ln"]["bias"]), aux
 
 
 def hidden_states(cfg: GPTConfig, params, tokens):
@@ -778,6 +786,7 @@ def logits(cfg: GPTConfig, params, tokens):
     return jnp.einsum("bsh,vh->bsv", h, table)
 
 
+@jax.named_scope("apex.ce_head")
 def _ce_of_hidden(cfg: GPTConfig, params, h, targets_bs):
     """Mean CE from final hidden states ``h [b, s, hid]`` (already
     SP-gathered / copy-region'd) against ``targets_bs [b, s]``.
@@ -847,10 +856,11 @@ def loss(cfg: GPTConfig, params, tokens, targets):
     load-balance term is folded in at ``moe_aux_coef``.
     """
     h, aux = hidden_states_and_aux(cfg, params, tokens)
-    if cfg.sequence_parallel:
-        h = gather_from_sequence_parallel_region(h, cfg.axis, True, 1)
-    else:
-        h = copy_to_tensor_model_parallel_region(h, cfg.axis)
+    with jax.named_scope("apex.ce_head"):
+        if cfg.sequence_parallel:
+            h = gather_from_sequence_parallel_region(h, cfg.axis, True, 1)
+        else:
+            h = copy_to_tensor_model_parallel_region(h, cfg.axis)
     tgt = targets
     if cfg.context_parallel:
         # local mean over this rank's chunk; shards are equal-sized so the
@@ -926,6 +936,7 @@ def _remat_policy(cfg: GPTConfig):
     raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}")
 
 
+@jax.named_scope("apex.weights")
 def _cast_layer(cfg: GPTConfig, layer_p):
     """Matmul weights to compute dtype; LN affine stays fp32 (MixedFused
     behaviour (U)). Under ``cfg.fsdp`` the dp-sharded kernels are
@@ -997,6 +1008,7 @@ def pipeline_loss(
     item = jax.ShapeDtypeStruct((mb, seq_local, cfg.hidden_size),
                                 cfg.compute_dtype)
 
+    @jax.named_scope("apex.ce_head")
     def loss_of_outputs(outs):
         # outs [n_micro, mb, s_local, h] → final LN + tied head + CE
         # (microbatch dims merge contiguously in the batch-major layout)
@@ -1322,6 +1334,26 @@ def _decode_attn_impl(cfg: GPTConfig, s_max: int) -> str:
     return impl
 
 
+@jax.named_scope("apex.decode.cache_slice")
+def _cache_planes(kv, quant: bool):
+    """One layer's cache taken apart: ``(k, v, k_scale, v_scale)``, the
+    scales None unless ``kv`` is the quantized ``{"kv", "scale"}``
+    pytree."""
+    if quant:
+        return kv["kv"][0], kv["kv"][1], kv["scale"][0], kv["scale"][1]
+    return kv[0], kv[1], None, None
+
+
+@jax.named_scope("apex.decode.cache_stack")
+def _stack_planes(k, v, k_scale=None, v_scale=None):
+    """:func:`_cache_planes` undone: the layer's cache in the layout it
+    came in."""
+    kv = jnp.stack([k, v])
+    if k_scale is None:
+        return kv
+    return {"kv": kv, "scale": jnp.stack([k_scale, v_scale])}
+
+
 def _decode_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos):
     """The decode-attention core shared by both cache layouts: write
     this token's K/V at ``pos`` and attend ``q`` over ``0..pos`` —
@@ -1336,21 +1368,19 @@ def _decode_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos):
     b, heads, d = q.shape
     kind = _kv_cache_dtype(cfg)
     quant = kind != "compute"
-    kvq = kv["kv"] if quant else kv
-    s_max = kvq.shape[3]
+    k_in, v_in, ks_in, vs_in = _cache_planes(kv, quant)
+    s_max = k_in.shape[2]
     if _decode_attn_impl(cfg, s_max) == "kernel":
         posv = (jnp.full((b,), pos, jnp.int32) if pos.ndim == 0
                 else pos)
         if quant:
             ctx, kq, ks, vq, vs = decode_attention_quantized(
-                q, k_new, v_new, kvq[0], kv["scale"][0], kvq[1],
-                kv["scale"][1], posv, scale=1.0 / np.sqrt(d), kind=kind)
-            return ctx, {"kv": jnp.stack([kq, vq]),
-                         "scale": jnp.stack([ks, vs])}
+                q, k_new, v_new, k_in, ks_in, v_in, vs_in, posv,
+                scale=1.0 / np.sqrt(d), kind=kind)
+            return ctx, _stack_planes(kq, vq, ks, vs)
         ctx, k_cache, v_cache = decode_attention(
-            q, k_new, v_new, kvq[0], kvq[1], posv,
-            scale=1.0 / np.sqrt(d))
-        return ctx, jnp.stack([k_cache, v_cache])
+            q, k_new, v_new, k_in, v_in, posv, scale=1.0 / np.sqrt(d))
+        return ctx, _stack_planes(k_cache, v_cache)
     if quant:
         # quantize the incoming rows ONCE (bit-identical to the kernel
         # and prefill quantizers), then write both planes
@@ -1367,20 +1397,19 @@ def _decode_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos):
             hit4[..., 0] if c.ndim == 3 else hit4,
             n[:, :, None].astype(c.dtype), c)
         valid = (jnp.arange(s_max)[None] <= pos[:, None])[:, None]
-    k_cache = upd(kvq[0], k_new)
-    v_cache = upd(kvq[1], v_new)
+    k_cache = upd(k_in, k_new)
+    v_cache = upd(v_in, v_new)
     if quant:
-        k_scale = upd(kv["scale"][0], k_s)
-        v_scale = upd(kv["scale"][1], v_s)
-        new_kv = {"kv": jnp.stack([k_cache, v_cache]),
-                  "scale": jnp.stack([k_scale, v_scale])}
+        k_scale = upd(ks_in, k_s)
+        v_scale = upd(vs_in, v_s)
+        new_kv = _stack_planes(k_cache, v_cache, k_scale, v_scale)
         # dequantize for the materialised-scores read (semantically the
         # per-chunk dequant the kernel does in VMEM; off-TPU this is
         # the correctness backbone, not the fast path)
         k_cache = dequantize_kv(k_cache, k_scale, cfg.compute_dtype)
         v_cache = dequantize_kv(v_cache, v_scale, cfg.compute_dtype)
     else:
-        new_kv = jnp.stack([k_cache, v_cache])
+        new_kv = _stack_planes(k_cache, v_cache)
     # scale folded into q BEFORE the einsum: the unscaled dot
     # product overflows fp16's 65504 range (same guard as the
     # training path's compute-dtype branch). Keep in lockstep with
@@ -1411,39 +1440,35 @@ def _paged_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos, table):
     b, heads, d = q.shape
     kind = _kv_cache_dtype(cfg)
     quant = kind != "compute"
-    kvq = kv["kv"] if quant else kv        # [2, num_pages, hl, P, d]
-    p_sz = kvq.shape[3]
+    k_in, v_in, ks_in, vs_in = _cache_planes(kv, quant)
+    p_sz = k_in.shape[2]
     s_max = table.shape[1] * p_sz
     posv = (jnp.full((b,), pos, jnp.int32) if pos.ndim == 0 else pos)
     if _decode_attn_impl(cfg, s_max) == "kernel":
         if quant:
             kq, ks, vq, vs = _paged_write_column_quant(
-                k_new, v_new, kvq[0], kv["scale"][0], kvq[1],
-                kv["scale"][1], table, posv, kind)
+                k_new, v_new, k_in, ks_in, v_in, vs_in, table, posv,
+                kind)
             ctx = _paged_attention_quantized(
                 q, kq, ks, vq, vs, table, posv, kind=kind,
                 scale=1.0 / np.sqrt(d))
-            return ctx, {"kv": jnp.stack([kq, vq]),
-                         "scale": jnp.stack([ks, vs])}
-        kp, vp = _paged_write_column(k_new, v_new, kvq[0], kvq[1],
-                                     table, posv)
+            return ctx, _stack_planes(kq, vq, ks, vs)
+        kp, vp = _paged_write_column(k_new, v_new, k_in, v_in, table,
+                                     posv)
         ctx = _paged_attention(q, kp, vp, table, posv,
                                scale=1.0 / np.sqrt(d))
-        return ctx, jnp.stack([kp, vp])
+        return ctx, _stack_planes(kp, vp)
     if quant:
         k_new, k_s = quantize_kv_rows(k_new, kind)
         v_new, v_s = quantize_kv_rows(v_new, kind)
-    kp = _paged_write_columns_xla(kvq[0], k_new[:, :, None], table,
-                                  posv)
-    vp = _paged_write_columns_xla(kvq[1], v_new[:, :, None], table,
-                                  posv)
+    kp = _paged_write_columns_xla(k_in, k_new[:, :, None], table, posv)
+    vp = _paged_write_columns_xla(v_in, v_new[:, :, None], table, posv)
     if quant:
-        ksp = _paged_write_columns_xla(kv["scale"][0],
-                                       k_s[:, :, None], table, posv)
-        vsp = _paged_write_columns_xla(kv["scale"][1],
-                                       v_s[:, :, None], table, posv)
-        new_kv = {"kv": jnp.stack([kp, vp]),
-                  "scale": jnp.stack([ksp, vsp])}
+        ksp = _paged_write_columns_xla(ks_in, k_s[:, :, None], table,
+                                       posv)
+        vsp = _paged_write_columns_xla(vs_in, v_s[:, :, None], table,
+                                       posv)
+        new_kv = _stack_planes(kp, vp, ksp, vsp)
         k_cache = dequantize_kv(_paged_gather_xla(kp, table),
                                 _paged_gather_xla(ksp, table),
                                 cfg.compute_dtype)
@@ -1451,7 +1476,7 @@ def _paged_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos, table):
                                 _paged_gather_xla(vsp, table),
                                 cfg.compute_dtype)
     else:
-        new_kv = jnp.stack([kp, vp])
+        new_kv = _stack_planes(kp, vp)
         k_cache = _paged_gather_xla(kp, table)
         v_cache = _paged_gather_xla(vp, table)
     valid = (jnp.arange(s_max)[None] <= posv[:, None])[:, None]
@@ -1481,37 +1506,42 @@ def _decode_layer(cfg: GPTConfig, p, x, kv, pos, table=None,
     ``dynamic_update_slice`` at per-row offsets is not expressible —
     the full-cache rewrite the kernel exists to remove) and masks per
     row."""
-    xa = _layer_norm(cfg, x, p["ln1"]["scale"], p["ln1"]["bias"])
-    d = cfg.head_dim
-    b = xa.shape[0]
-    hl = p["attn"]["qkv"]["kernel"].shape[-1]
-    lq = None if lora is None else (lora[0]["qkv"],) + lora[1:]
-    q, k_new, v_new = (
-        t.reshape(b, hl // d, d)
-        for t in _qkv_project(cfg, p["attn"]["qkv"], xa, lora=lq))
-    if table is None:
-        ctx, new_kv = _decode_attend(cfg, q, k_new, v_new, kv, pos)
-    else:
-        ctx, new_kv = _paged_attend(cfg, q, k_new, v_new, kv, pos,
-                                    table)
-    out = ctx.reshape(b, hl)
-    attn = row_parallel_linear(
-        out, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
-        axis=cfg.axis)
-    if lora is not None:
-        page, ids, scale = lora
-        attn = attn + _lora_delta(out, page["proj"]["a"],
-                                  page["proj"]["b"], ids, scale,
-                                  axis=cfg.axis)
-    x = x + attn
-    xb = _layer_norm(cfg, x, p["ln2"]["scale"], p["ln2"]["bias"])
-    if cfg.num_experts:
-        y, _ = moe_mod.moe_ffn(_moe_cfg(cfg), p["moe"], xb)  # aux unused
-    else:
-        y = _mlp(cfg, p["mlp"], xb, lora=lora)
-    return x + y, new_kv
+    with jax.named_scope("apex.attn"):
+        xa = _layer_norm(cfg, x, p["ln1"]["scale"], p["ln1"]["bias"])
+        d = cfg.head_dim
+        b = xa.shape[0]
+        hl = p["attn"]["qkv"]["kernel"].shape[-1]
+        lq = None if lora is None else (lora[0]["qkv"],) + lora[1:]
+        q, k_new, v_new = (
+            t.reshape(b, hl // d, d)
+            for t in _qkv_project(cfg, p["attn"]["qkv"], xa, lora=lq))
+        with jax.named_scope("apex.decode.attn"):
+            if table is None:
+                ctx, new_kv = _decode_attend(cfg, q, k_new, v_new, kv,
+                                             pos)
+            else:
+                ctx, new_kv = _paged_attend(cfg, q, k_new, v_new, kv,
+                                            pos, table)
+        out = ctx.reshape(b, hl)
+        attn = row_parallel_linear(
+            out, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
+            axis=cfg.axis)
+        if lora is not None:
+            page, ids, scale = lora
+            attn = attn + _lora_delta(out, page["proj"]["a"],
+                                      page["proj"]["b"], ids, scale,
+                                      axis=cfg.axis)
+        x = x + attn
+    with jax.named_scope("apex.mlp"):
+        xb = _layer_norm(cfg, x, p["ln2"]["scale"], p["ln2"]["bias"])
+        if cfg.num_experts:
+            y, _ = moe_mod.moe_ffn(_moe_cfg(cfg), p["moe"], xb)  # aux unused
+        else:
+            y = _mlp(cfg, p["mlp"], xb, lora=lora)
+        return x + y, new_kv
 
 
+@jax.named_scope("apex.lm_head")
 def _lm_head(cfg: GPTConfig, params, h):
     """Tied-embedding LM head for a single position: ``h [b, hidden]``
     (pre-final-LN) → full-vocab fp32 logits ``[b, vocab]`` — shared by
@@ -1561,15 +1591,18 @@ def decode_step(cfg: GPTConfig, params, cache, token, pos, table=None,
     if cfg.sequence_parallel:
         cfg = dataclasses.replace(cfg, sequence_parallel=False)
     pos = jnp.asarray(pos, jnp.int32)
-    emb_t = params["embedding"]["word"]["table"].astype(cfg.compute_dtype)
-    emb = vocab_parallel_embedding(token[:, None], emb_t, axis=cfg.axis)
-    if pos.ndim == 0:
-        pos_e = lax.dynamic_index_in_dim(
-            params["embedding"]["position"], pos, 0, keepdims=False)
-    else:
-        pos_e = jnp.take(params["embedding"]["position"], pos, axis=0)
-    x = (emb[:, 0] + pos_e.astype(cfg.compute_dtype)).astype(
-        cfg.compute_dtype)
+    with jax.named_scope("apex.embed"):
+        emb_t = params["embedding"]["word"]["table"].astype(
+            cfg.compute_dtype)
+        emb = vocab_parallel_embedding(token[:, None], emb_t,
+                                       axis=cfg.axis)
+        if pos.ndim == 0:
+            pos_e = lax.dynamic_index_in_dim(
+                params["embedding"]["position"], pos, 0, keepdims=False)
+        else:
+            pos_e = jnp.take(params["embedding"]["position"], pos, axis=0)
+        x = (emb[:, 0] + pos_e.astype(cfg.compute_dtype)).astype(
+            cfg.compute_dtype)
 
     if lora is None:
         def body(carry, inp):
@@ -1578,7 +1611,7 @@ def decode_step(cfg: GPTConfig, params, cache, token, pos, table=None,
                                   kv, pos, table)
             return y, kv
 
-        x, new_cache = lax.scan(body, x, (params["layers"], cache))
+        xs = (params["layers"], cache)
     else:
         pool, ids, scale = lora
 
@@ -1589,8 +1622,11 @@ def decode_step(cfg: GPTConfig, params, cache, token, pos, table=None,
                                   lora=(page, ids, scale))
             return y, kv
 
-        x, new_cache = lax.scan(body, x,
-                                (params["layers"], cache, pool))
+        xs = (params["layers"], cache, pool)
+    # the scan's own slicing of the stacked params and cache, and the
+    # stacking of the layers' caches it returns, carry this scope alone
+    with jax.named_scope("apex.decode.layers"):
+        x, new_cache = lax.scan(body, x, xs)
     return _lm_head(cfg, params, x), new_cache
 
 
@@ -1646,15 +1682,16 @@ def decode_steps(cfg: GPTConfig, params, cache, state, n: int, *,
         cache, st = carry
         logits, cache = decode_step(
             cfg, params, cache, st["tok"], st["pos"], table, lora)
-        if draw_fn is None:
-            nxt = _sampling.draw_slots(
-                logits, st["key"], st["pos"], st["temp"], st["top_k"],
-                st["top_p"], masks=masks)
-        else:
-            nxt = draw_fn(logits, st["pos"])
-        lp = jnp.take_along_axis(
-            jax.nn.log_softmax(logits, axis=-1), nxt[:, None], axis=1
-        )[:, 0]
+        with jax.named_scope("apex.sample"):
+            if draw_fn is None:
+                nxt = _sampling.draw_slots(
+                    logits, st["key"], st["pos"], st["temp"],
+                    st["top_k"], st["top_p"], masks=masks)
+            else:
+                nxt = draw_fn(logits, st["pos"])
+            lp = jnp.take_along_axis(
+                jax.nn.log_softmax(logits, axis=-1), nxt[:, None], axis=1
+            )[:, 0]
         live = ~st["done"]
         emit = jnp.where(live, nxt, pad)
         lp = jnp.where(live, lp, jnp.float32(0.0))
@@ -1752,17 +1789,15 @@ def _paged_attend_multi(cfg: GPTConfig, q, k_new, v_new, kv, pos,
     b, heads, t, d = q.shape
     kind = _kv_cache_dtype(cfg)
     quant = kind != "compute"
-    kvq = kv["kv"] if quant else kv
-    p_sz = kvq.shape[3]
+    k_in, v_in, ks_in, vs_in = _cache_planes(kv, quant)
+    p_sz = k_in.shape[2]
     s_max = table.shape[1] * p_sz
     use_kernel = _decode_attn_impl(cfg, s_max) == "kernel"
     if use_kernel:
         if quant:
             kq, ks, vq, vs = _paged_write_columns_quant(
-                k_new, v_new, kvq[0], kv["scale"][0], kvq[1],
-                kv["scale"][1], table, pos, kind)
-            new_kv = {"kv": jnp.stack([kq, vq]),
-                      "scale": jnp.stack([ks, vs])}
+                k_new, v_new, k_in, ks_in, v_in, vs_in, table, pos, kind)
+            new_kv = _stack_planes(kq, vq, ks, vs)
             k_cache = dequantize_kv(_paged_gather_xla(kq, table),
                                     _paged_gather_xla(ks, table),
                                     cfg.compute_dtype)
@@ -1770,24 +1805,21 @@ def _paged_attend_multi(cfg: GPTConfig, q, k_new, v_new, kv, pos,
                                     _paged_gather_xla(vs, table),
                                     cfg.compute_dtype)
         else:
-            kp, vp = _paged_write_columns(k_new, v_new, kvq[0],
-                                          kvq[1], table, pos)
-            new_kv = jnp.stack([kp, vp])
+            kp, vp = _paged_write_columns(k_new, v_new, k_in, v_in,
+                                          table, pos)
+            new_kv = _stack_planes(kp, vp)
             k_cache = _paged_gather_xla(kp, table)
             v_cache = _paged_gather_xla(vp, table)
     else:
         if quant:
             k_new, k_s = quantize_kv_rows(k_new, kind)
             v_new, v_s = quantize_kv_rows(v_new, kind)
-        kp = _paged_write_columns_xla(kvq[0], k_new, table, pos)
-        vp = _paged_write_columns_xla(kvq[1], v_new, table, pos)
+        kp = _paged_write_columns_xla(k_in, k_new, table, pos)
+        vp = _paged_write_columns_xla(v_in, v_new, table, pos)
         if quant:
-            ksp = _paged_write_columns_xla(kv["scale"][0], k_s, table,
-                                           pos)
-            vsp = _paged_write_columns_xla(kv["scale"][1], v_s, table,
-                                           pos)
-            new_kv = {"kv": jnp.stack([kp, vp]),
-                      "scale": jnp.stack([ksp, vsp])}
+            ksp = _paged_write_columns_xla(ks_in, k_s, table, pos)
+            vsp = _paged_write_columns_xla(vs_in, v_s, table, pos)
+            new_kv = _stack_planes(kp, vp, ksp, vsp)
             k_cache = dequantize_kv(_paged_gather_xla(kp, table),
                                     _paged_gather_xla(ksp, table),
                                     cfg.compute_dtype)
@@ -1795,7 +1827,7 @@ def _paged_attend_multi(cfg: GPTConfig, q, k_new, v_new, kv, pos,
                                     _paged_gather_xla(vsp, table),
                                     cfg.compute_dtype)
         else:
-            new_kv = jnp.stack([kp, vp])
+            new_kv = _stack_planes(kp, vp)
             k_cache = _paged_gather_xla(kp, table)
             v_cache = _paged_gather_xla(vp, table)
     # the contiguous _decode_attend_multi read expressions VERBATIM
@@ -1829,37 +1861,34 @@ def _decode_attend_multi(cfg: GPTConfig, q, k_new, v_new, kv, pos):
     b, heads, t, d = q.shape
     kind = _kv_cache_dtype(cfg)
     quant = kind != "compute"
-    kvq = kv["kv"] if quant else kv
-    s_max = kvq.shape[3]
+    k_in, v_in, ks_in, vs_in = _cache_planes(kv, quant)
+    s_max = k_in.shape[2]
     use_kernel = _decode_attn_impl(cfg, s_max) == "kernel"
     if use_kernel:
         if quant:
             kq, ks, vq, vs = _cache_write_columns_quant(
-                k_new, v_new, kvq[0], kv["scale"][0], kvq[1],
-                kv["scale"][1], pos, kind)
-            new_kv = {"kv": jnp.stack([kq, vq]),
-                      "scale": jnp.stack([ks, vs])}
+                k_new, v_new, k_in, ks_in, v_in, vs_in, pos, kind)
+            new_kv = _stack_planes(kq, vq, ks, vs)
             k_cache = dequantize_kv(kq, ks, cfg.compute_dtype)
             v_cache = dequantize_kv(vq, vs, cfg.compute_dtype)
         else:
             k_cache, v_cache = _cache_write_columns(
-                k_new, v_new, kvq[0], kvq[1], pos)
-            new_kv = jnp.stack([k_cache, v_cache])
+                k_new, v_new, k_in, v_in, pos)
+            new_kv = _stack_planes(k_cache, v_cache)
     else:
         if quant:
             k_new, k_s = quantize_kv_rows(k_new, kind)
             v_new, v_s = quantize_kv_rows(v_new, kind)
-        k_cache = _cache_write_columns_xla(kvq[0], k_new, pos)
-        v_cache = _cache_write_columns_xla(kvq[1], v_new, pos)
+        k_cache = _cache_write_columns_xla(k_in, k_new, pos)
+        v_cache = _cache_write_columns_xla(v_in, v_new, pos)
         if quant:
-            k_scale = _cache_write_columns_xla(kv["scale"][0], k_s, pos)
-            v_scale = _cache_write_columns_xla(kv["scale"][1], v_s, pos)
-            new_kv = {"kv": jnp.stack([k_cache, v_cache]),
-                      "scale": jnp.stack([k_scale, v_scale])}
+            k_scale = _cache_write_columns_xla(ks_in, k_s, pos)
+            v_scale = _cache_write_columns_xla(vs_in, v_s, pos)
+            new_kv = _stack_planes(k_cache, v_cache, k_scale, v_scale)
             k_cache = dequantize_kv(k_cache, k_scale, cfg.compute_dtype)
             v_cache = dequantize_kv(v_cache, v_scale, cfg.compute_dtype)
         else:
-            new_kv = jnp.stack([k_cache, v_cache])
+            new_kv = _stack_planes(k_cache, v_cache)
     # row t attends over 0 .. pos + t (its own just-written column
     # included, like the plain path); later verify columns are masked
     # to exact softmax zeros. This expression MUST stay in lockstep
@@ -1892,32 +1921,35 @@ def _verify_layer(cfg: GPTConfig, p, x, kv, pos, table=None,
     per-position (row-independent matmuls — the :func:`prefill_extend`
     argument), attention via :func:`_decode_attend_multi` (or its
     paged sibling when ``table`` is given)."""
-    xa = _layer_norm(cfg, x, p["ln1"]["scale"], p["ln1"]["bias"])
-    d = cfg.head_dim
-    b, t, _ = xa.shape
-    hl = p["attn"]["qkv"]["kernel"].shape[-1]
-    lq = None if lora is None else (lora[0]["qkv"],) + lora[1:]
-    q, k_new, v_new = (
-        jnp.transpose(z.reshape(b, t, hl // d, d), (0, 2, 1, 3))
-        for z in _qkv_project(cfg, p["attn"]["qkv"], xa, lora=lq))
-    if table is None:
-        ctx, new_kv = _decode_attend_multi(cfg, q, k_new, v_new, kv,
-                                           pos)
-    else:
-        ctx, new_kv = _paged_attend_multi(cfg, q, k_new, v_new, kv,
-                                          pos, table)
-    out = jnp.transpose(ctx, (0, 2, 1, 3)).reshape(b, t, hl)
-    attn = row_parallel_linear(
-        out, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
-        axis=cfg.axis)
-    if lora is not None:
-        page, ids, scale = lora
-        attn = attn + _lora_delta(out, page["proj"]["a"],
-                                  page["proj"]["b"], ids, scale,
-                                  axis=cfg.axis)
-    x = x + attn
-    xb = _layer_norm(cfg, x, p["ln2"]["scale"], p["ln2"]["bias"])
-    return x + _mlp(cfg, p["mlp"], xb, lora=lora), new_kv
+    with jax.named_scope("apex.attn"):
+        xa = _layer_norm(cfg, x, p["ln1"]["scale"], p["ln1"]["bias"])
+        d = cfg.head_dim
+        b, t, _ = xa.shape
+        hl = p["attn"]["qkv"]["kernel"].shape[-1]
+        lq = None if lora is None else (lora[0]["qkv"],) + lora[1:]
+        q, k_new, v_new = (
+            jnp.transpose(z.reshape(b, t, hl // d, d), (0, 2, 1, 3))
+            for z in _qkv_project(cfg, p["attn"]["qkv"], xa, lora=lq))
+        with jax.named_scope("apex.decode.attn"):
+            if table is None:
+                ctx, new_kv = _decode_attend_multi(cfg, q, k_new, v_new,
+                                                   kv, pos)
+            else:
+                ctx, new_kv = _paged_attend_multi(cfg, q, k_new, v_new,
+                                                  kv, pos, table)
+        out = jnp.transpose(ctx, (0, 2, 1, 3)).reshape(b, t, hl)
+        attn = row_parallel_linear(
+            out, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
+            axis=cfg.axis)
+        if lora is not None:
+            page, ids, scale = lora
+            attn = attn + _lora_delta(out, page["proj"]["a"],
+                                      page["proj"]["b"], ids, scale,
+                                      axis=cfg.axis)
+        x = x + attn
+    with jax.named_scope("apex.mlp"):
+        xb = _layer_norm(cfg, x, p["ln2"]["scale"], p["ln2"]["bias"])
+        return x + _mlp(cfg, p["mlp"], xb, lora=lora), new_kv
 
 
 def decode_verify(cfg: GPTConfig, params, cache, tokens, pos,
@@ -1958,17 +1990,20 @@ def decode_verify(cfg: GPTConfig, params, cache, tokens, pos,
             cfg, sequence_parallel=False, context_parallel=False)
     pos = jnp.asarray(pos, jnp.int32)
     b, t = tokens.shape
-    emb_t = params["embedding"]["word"]["table"].astype(cfg.compute_dtype)
-    emb = vocab_parallel_embedding(tokens.astype(jnp.int32), emb_t,
-                                   axis=cfg.axis)
-    # over-horizon lanes (a near-budget row drafting past its last
-    # position) clamp their position-embedding index — their logits
-    # are discarded by the accept logic, never emitted
-    posn = jnp.minimum(
-        pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None],
-        cfg.seq_len - 1)
-    pos_e = jnp.take(params["embedding"]["position"], posn, axis=0)
-    x = (emb + pos_e.astype(cfg.compute_dtype)).astype(cfg.compute_dtype)
+    with jax.named_scope("apex.embed"):
+        emb_t = params["embedding"]["word"]["table"].astype(
+            cfg.compute_dtype)
+        emb = vocab_parallel_embedding(tokens.astype(jnp.int32), emb_t,
+                                       axis=cfg.axis)
+        # over-horizon lanes (a near-budget row drafting past its last
+        # position) clamp their position-embedding index — their logits
+        # are discarded by the accept logic, never emitted
+        posn = jnp.minimum(
+            pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None],
+            cfg.seq_len - 1)
+        pos_e = jnp.take(params["embedding"]["position"], posn, axis=0)
+        x = (emb + pos_e.astype(cfg.compute_dtype)).astype(
+            cfg.compute_dtype)
 
     if lora is None:
         def body(carry, inp):
@@ -1977,7 +2012,7 @@ def decode_verify(cfg: GPTConfig, params, cache, tokens, pos,
                                   kv, pos, table)
             return y, kv
 
-        x, new_cache = lax.scan(body, x, (params["layers"], cache))
+        xs = (params["layers"], cache)
     else:
         pool, ids, scale = lora
 
@@ -1988,8 +2023,9 @@ def decode_verify(cfg: GPTConfig, params, cache, tokens, pos,
                                   lora=(page, ids, scale))
             return y, kv
 
-        x, new_cache = lax.scan(body, x,
-                                (params["layers"], cache, pool))
+        xs = (params["layers"], cache, pool)
+    with jax.named_scope("apex.decode.layers"):
+        x, new_cache = lax.scan(body, x, xs)
     lg = _lm_head(cfg, params, x.reshape(b * t, cfg.hidden_size))
     return lg.reshape(b, t, -1), new_cache
 
@@ -2162,7 +2198,7 @@ def _prefill_states(cfg: GPTConfig, params, prompt, max_len: int,
                                return_kv=True)
             return hh, kv
 
-        h, (ks, vs) = lax.scan(body, h, params["layers"])
+        xs = params["layers"]
     else:
         pool, ids, scale = lora
 
@@ -2173,14 +2209,19 @@ def _prefill_states(cfg: GPTConfig, params, prompt, max_len: int,
                                lora=(page, ids, scale))
             return hh, kv
 
-        h, (ks, vs) = lax.scan(body, h, (params["layers"], pool))
-    # ks/vs [l_local, b, heads_local, p_len, d] → cache [l, 2, b, hl, S, d]
-    pad = ((0, 0),) * 3 + ((0, max_len - p_len), (0, 0))
-    cache = jnp.stack([jnp.pad(ks, pad), jnp.pad(vs, pad)], axis=1)
-    # quantized storage quantizes here (identity otherwise) — the SAME
-    # per-row quantizer the decode write and prefix pool use, so every
-    # path produces bit-identical cache bytes for the same K/V values
-    return quantize_cache_block(cfg, cache), h
+        xs = (params["layers"], pool)
+    with jax.named_scope("apex.layers"):
+        h, (ks, vs) = lax.scan(body, h, xs)
+    with jax.named_scope("apex.prefill.cache_insert"):
+        # ks/vs [l_local, b, heads_local, p_len, d] → cache
+        # [l, 2, b, hl, S, d]
+        pad = ((0, 0),) * 3 + ((0, max_len - p_len), (0, 0))
+        cache = jnp.stack([jnp.pad(ks, pad), jnp.pad(vs, pad)], axis=1)
+        # quantized storage quantizes here (identity otherwise) — the
+        # SAME per-row quantizer the decode write and prefix pool use, so
+        # every path produces bit-identical cache bytes for the same K/V
+        # values
+        return quantize_cache_block(cfg, cache), h
 
 
 def prefill(cfg: GPTConfig, params, prompt, *, max_len: Optional[int] = None):
@@ -2287,11 +2328,14 @@ def prefill_extend(cfg: GPTConfig, params, prefix_kv, tail, last, *,
             "capacity depends on the routed token count; tail-only "
             "routing breaks prefix-hit == cold-prefill parity)")
     d = cfg.head_dim
-    table = params["embedding"]["word"]["table"].astype(cfg.compute_dtype)
-    emb = vocab_parallel_embedding(tail.astype(jnp.int32), table,
-                                   axis=cfg.axis)
-    pos_e = params["embedding"]["position"][prefix_len:prefix_len + tb]
-    h = emb + pos_e[None].astype(cfg.compute_dtype)
+    with jax.named_scope("apex.embed"):
+        table = params["embedding"]["word"]["table"].astype(
+            cfg.compute_dtype)
+        emb = vocab_parallel_embedding(tail.astype(jnp.int32), table,
+                                       axis=cfg.axis)
+        pos_e = params["embedding"]["position"][
+            prefix_len:prefix_len + tb]
+        h = emb + pos_e[None].astype(cfg.compute_dtype)
     # static causal mask over [tail rows, prefix+tail cols]: a tail
     # query at local i (global prefix_len + i) sees the whole prefix
     # and tail columns j <= i; pad tail columns are only ever visible
@@ -2307,29 +2351,33 @@ def prefill_extend(cfg: GPTConfig, params, prefix_kv, tail, last, *,
         # adapter scans so the two can never diverge.
         lo = None if page is None else (page, ids, scale)
         lq = None if page is None else (page["qkv"], ids, scale)
-        x = _layer_norm(cfg, carry, p["ln1"]["scale"], p["ln1"]["bias"])
-        qh, kh, vh = _qkv_project(cfg, p["attn"]["qkv"], x, lora=lq)
-        heads = qh.shape[-1] // d
-        split = lambda t: jnp.transpose(
-            t.reshape(b, tb, heads, d), (0, 2, 1, 3))
-        qs, kt, vt = split(qh), split(kh), split(vh)
-        k_full = jnp.concatenate([pkv[0], kt], axis=2)
-        v_full = jnp.concatenate([pkv[1], vt], axis=2)
-        # THE shared score expression — attn_score_dtype semantics
-        # included, so hit and cold can never diverge here
-        p_attn = _xla_attn_probs(cfg, qs, k_full, mask)
-        ctx = jnp.einsum("bhqk,bhkd->bhqd", p_attn, v_full)
-        out = jnp.transpose(ctx, (0, 2, 1, 3)).reshape(b, tb, heads * d)
-        attn = row_parallel_linear(
-            out, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
-            axis=cfg.axis)
-        if page is not None:
-            attn = attn + _lora_delta(out, page["proj"]["a"],
-                                      page["proj"]["b"], ids, scale,
-                                      axis=cfg.axis)
-        hh = carry + attn
-        x2 = _layer_norm(cfg, hh, p["ln2"]["scale"], p["ln2"]["bias"])
-        hh = hh + _mlp(cfg, p["mlp"], x2, lora=lo)
+        with jax.named_scope("apex.attn"):
+            x = _layer_norm(cfg, carry, p["ln1"]["scale"],
+                            p["ln1"]["bias"])
+            qh, kh, vh = _qkv_project(cfg, p["attn"]["qkv"], x, lora=lq)
+            heads = qh.shape[-1] // d
+            split = lambda t: jnp.transpose(
+                t.reshape(b, tb, heads, d), (0, 2, 1, 3))
+            qs, kt, vt = split(qh), split(kh), split(vh)
+            k_full = jnp.concatenate([pkv[0], kt], axis=2)
+            v_full = jnp.concatenate([pkv[1], vt], axis=2)
+            # THE shared score expression — attn_score_dtype semantics
+            # included, so hit and cold can never diverge here
+            p_attn = _xla_attn_probs(cfg, qs, k_full, mask)
+            ctx = jnp.einsum("bhqk,bhkd->bhqd", p_attn, v_full)
+            out = jnp.transpose(ctx, (0, 2, 1, 3)).reshape(
+                b, tb, heads * d)
+            attn = row_parallel_linear(
+                out, p["attn"]["proj"]["kernel"],
+                p["attn"]["proj"]["bias"], axis=cfg.axis)
+            if page is not None:
+                attn = attn + _lora_delta(out, page["proj"]["a"],
+                                          page["proj"]["b"], ids, scale,
+                                          axis=cfg.axis)
+            hh = carry + attn
+        with jax.named_scope("apex.mlp"):
+            x2 = _layer_norm(cfg, hh, p["ln2"]["scale"], p["ln2"]["bias"])
+            hh = hh + _mlp(cfg, p["mlp"], x2, lora=lo)
         return hh, jnp.stack([kt, vt])
 
     if lora is None:
@@ -2338,7 +2386,7 @@ def prefill_extend(cfg: GPTConfig, params, prefix_kv, tail, last, *,
             return layer_body(_cast_layer(cfg, layer_p), pkv, carry,
                               None, None, None)
 
-        h, tail_kv = lax.scan(body, h, (params["layers"], prefix_kv))
+        xs = (params["layers"], prefix_kv)
     else:
         pool, ids, scale = lora
 
@@ -2347,13 +2395,15 @@ def prefill_extend(cfg: GPTConfig, params, prefix_kv, tail, last, *,
             return layer_body(_cast_layer(cfg, layer_p), pkv, carry,
                               page, ids, scale)
 
-        h, tail_kv = lax.scan(body, h,
-                              (params["layers"], prefix_kv, pool))
+        xs = (params["layers"], prefix_kv, pool)
+    with jax.named_scope("apex.layers"):
+        h, tail_kv = lax.scan(body, h, xs)
     last = jnp.asarray(last, jnp.int32)
     h_last = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
     return tail_kv, _lm_head(cfg, params, h_last)
 
 
+@jax.named_scope("apex.prefill.cache_insert")
 def cache_insert_slot(cache, block, slot, *, pos: int = 0):
     """Insert one request's prefilled cache block ``[l, 2, 1, hl, P, d]``
     into slot ``slot`` of a shared decode cache ``[l, 2, B, hl, S, d]``
@@ -2381,6 +2431,7 @@ def cache_insert_slot(cache, block, slot, *, pos: int = 0):
     return jax.tree.map(ins, cache, block)
 
 
+@jax.named_scope("apex.prefill.cache_insert")
 def cache_insert_slots(cache, blocks, slots):
     """:func:`cache_insert_slot` for a batch: ``blocks [l, 2, k, hl, P,
     d]`` (one prefilled block per row, ``P <= S``) written at slot
@@ -2397,6 +2448,7 @@ def cache_insert_slots(cache, blocks, slots):
     return cache
 
 
+@jax.named_scope("apex.prefill.cache_insert")
 def cache_insert_pages(cache, blocks, pages, *, page_size: int):
     """Scatter prefilled cache blocks into a PAGED pool: ``blocks
     [l, 2, k, hl, span, d]`` (or the quantized pytree; ``span`` a

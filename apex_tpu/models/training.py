@@ -135,6 +135,7 @@ def _clip_leaf_axes(pspecs, norm_axes):
             pspecs, is_leaf=lambda x: isinstance(x, P))]
 
 
+@jax.named_scope("apex.clip")
 def _clip_by_global_norm(grads, leaf_axes, clip):
     """(clipped grads, pre-clip global L2 norm): each leaf's shard
     sum-of-squares is psum'd over its sharded axes so every rank clips
@@ -360,24 +361,27 @@ def make_train_step(
             lambda p: _local_loss(p, tokens, targets), scaler_cfg)
         value, grads, finite = vag(params, scaler_state=state.scaler)
 
-        grads = _dp_grad_sync(grads, optimizer, axes_present,
-                              fsdp=cfg.fsdp, fsdp_mask=fsdp_mask,
-                              dp_size=dp_size)
-        if ep_size > 1:
-            inv = 1.0 / ep_size
-            grads = jax.tree.map(
-                lambda g, m: g * inv if m else lax.pmean(g, ep_axis),
-                grads, ep_mask)
-        if cp_active:
-            # params are replicated over cp but each rank saw only its
-            # sequence chunk — mean of equal-sized chunk losses
-            grads = lax.pmean(grads, cfg.cp_axis)
-        if cfg.sequence_parallel:
-            grads = jax.tree.map(
-                lambda g, m: lax.psum(g, AXIS_TP) if m else g, grads, sp_mask)
-        if pipelined:
-            grads = jax.tree.map(
-                lambda g, m: lax.psum(g, AXIS_PP) if m else g, grads, pp_mask)
+        with jax.named_scope("apex.grad_sync"):
+            grads = _dp_grad_sync(grads, optimizer, axes_present,
+                                  fsdp=cfg.fsdp, fsdp_mask=fsdp_mask,
+                                  dp_size=dp_size)
+            if ep_size > 1:
+                inv = 1.0 / ep_size
+                grads = jax.tree.map(
+                    lambda g, m: g * inv if m else lax.pmean(g, ep_axis),
+                    grads, ep_mask)
+            if cp_active:
+                # params are replicated over cp but each rank saw only
+                # its sequence chunk — mean of equal-sized chunk losses
+                grads = lax.pmean(grads, cfg.cp_axis)
+            if cfg.sequence_parallel:
+                grads = jax.tree.map(
+                    lambda g, m: lax.psum(g, AXIS_TP) if m else g,
+                    grads, sp_mask)
+            if pipelined:
+                grads = jax.tree.map(
+                    lambda g, m: lax.psum(g, AXIS_PP) if m else g,
+                    grads, pp_mask)
         sync_names = [AXIS_DP, AXIS_TP, AXIS_PP]
         if cp_active:
             sync_names.append(cfg.cp_axis)
@@ -393,11 +397,13 @@ def make_train_step(
             # update direction)
             grads, grad_norm = _clip_by_global_norm(
                 grads, clip_leaf_axes, clip_grad_norm)
-        new_params, new_opt = optimizer.step(grads, state.opt_state, params)
-        if scaler_cfg.enabled:
-            # a single rank overflowing skips the step everywhere
-            new_params = apply_if_finite(new_params, params, finite)
-            new_opt = apply_if_finite(new_opt, state.opt_state, finite)
+        with jax.named_scope("apex.optimizer"):
+            new_params, new_opt = optimizer.step(
+                grads, state.opt_state, params)
+            if scaler_cfg.enabled:
+                # a single rank overflowing skips the step everywhere
+                new_params = apply_if_finite(new_params, params, finite)
+                new_opt = apply_if_finite(new_opt, state.opt_state, finite)
         # identity scaler: like apex without a scaler the step is never
         # skipped — grads_finite stays a truthful observability metric
         new_scaler = scaler_update(scaler_cfg, state.scaler, finite)
@@ -540,13 +546,14 @@ def make_loss_train_step(
                 lambda p: loss_fn(p, *batch), scaler_cfg)
             value, grads, finite = vag(params, scaler_state=state.scaler)
 
-        grads = _dp_grad_sync(grads, optimizer, axes_present,
-                              fsdp=fsdp, fsdp_mask=fsdp_mask,
-                              dp_size=dp_size)
-        if sp_psum_mask is not None:
-            grads = jax.tree.map(
-                lambda g, m: lax.psum(g, model_axis) if m else g,
-                grads, sp_psum_mask)
+        with jax.named_scope("apex.grad_sync"):
+            grads = _dp_grad_sync(grads, optimizer, axes_present,
+                                  fsdp=fsdp, fsdp_mask=fsdp_mask,
+                                  dp_size=dp_size)
+            if sp_psum_mask is not None:
+                grads = jax.tree.map(
+                    lambda g, m: lax.psum(g, model_axis) if m else g,
+                    grads, sp_psum_mask)
         sync_axes = tuple(
             a for a in (AXIS_DP, model_axis) if a in axes_present)
         finite = lax.pmin(finite.astype(jnp.int32), sync_axes) > 0
@@ -554,12 +561,15 @@ def make_loss_train_step(
         if clip_grad_norm is not None:
             grads, grad_norm = _clip_by_global_norm(
                 grads, clip_leaf_axes, clip_grad_norm)
-        new_params, new_opt = optimizer.step(grads, state.opt_state, params)
-        if scaler_cfg.enabled:
-            new_params = apply_if_finite(new_params, params, finite)
-            new_opt = apply_if_finite(new_opt, state.opt_state, finite)
-            if has_extra:
-                new_extra = apply_if_finite(new_extra, state.extra, finite)
+        with jax.named_scope("apex.optimizer"):
+            new_params, new_opt = optimizer.step(
+                grads, state.opt_state, params)
+            if scaler_cfg.enabled:
+                new_params = apply_if_finite(new_params, params, finite)
+                new_opt = apply_if_finite(new_opt, state.opt_state, finite)
+                if has_extra:
+                    new_extra = apply_if_finite(new_extra, state.extra,
+                                                finite)
         new_scaler = scaler_update(scaler_cfg, state.scaler, finite)
         loss_out = value
         if AXIS_DP in axes_present:

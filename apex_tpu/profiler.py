@@ -9,10 +9,9 @@ contrib benchmarks (U). The TPU build makes this a component:
   time on remote-attached devices), windowed statistics, and derived
   throughput/MFU,
 - :func:`trace` / :func:`annotate` — ``jax.profiler`` xprof trace capture
-  and named ranges (the nvtx equivalent, viewable in XProf/TensorBoard),
-- :func:`op_profile` — parse a :func:`trace` capture into per-op device
-  self-times WITHOUT TensorBoard (terminal-friendly xprof: aggregate,
-  categorize, attribute to source lines),
+  and named ranges (the nvtx equivalent, viewable in XProf/TensorBoard;
+  ``benchmark/tools/trace_regions.py`` prints a capture's device time
+  by the program's regions and kernel names in a terminal),
 - :class:`MetricsLogger` — structured per-step metrics: in-memory ring,
   optional JSONL file, optional TensorBoard writer when available,
 - :class:`LatencyStats` — streaming latency accumulator with percentile
@@ -224,124 +223,3 @@ class LatencyStats:
             "p99_ms": float(np.percentile(v, 99)),
             "max_ms": float(v.max()),
         }
-
-
-def model_flops_per_token(n_params: int, *, include_backward: bool = True,
-                          remat: bool = False) -> float:
-    """6N per token (fwd+bwd), 2N fwd-only; +2N when full-remat replays
-    the forward — the MFU denominators used in bench.py."""
-    if not include_backward:
-        return 2.0 * n_params
-    return (8.0 if remat else 6.0) * n_params
-
-
-# ---------------------------------------------------------------------------
-# terminal xprof: trace.json.gz → per-op device self-times
-# ---------------------------------------------------------------------------
-
-def op_profile(logdir: str, *, top: int = 40) -> Dict[str, Any]:
-    """Aggregate a :func:`trace` capture into per-op **device self-times**
-    — profiling analysis with no TensorBoard in the loop (nsys stats'
-    role for the reference's workflow (U)).
-
-    Reads the newest ``plugins/profile/*/ *.trace.json.gz`` under
-    ``logdir`` (the Chrome-trace view jax.profiler always writes next to
-    the ``.xplane.pb``), walks the device "XLA Ops" thread with a stack
-    so nested HLO regions (whiles, calls, fusion containers) don't
-    double-count, and returns::
-
-        {"total_s":      device-busy seconds over the captured window,
-         "by_category":  {hlo_category: seconds},       # fusion kinds,
-                                                        # custom-call, copies…
-         "top_ops":      [{"name", "seconds", "count", "category",
-                           "source"}...],               # self-time ranked
-         "trace_path":   the file parsed}
-
-    Self-time = an op's duration minus its children's — the number that
-    says where the step actually goes. ``source`` is the ``op.source``
-    attribution xprof records (file:line of the producing Python), so a
-    hot copy points at the exact model line. The measured workflow this
-    encodes: capture 2-3 steps under :func:`trace`, `op_profile(...)`,
-    read the category table first (a large ``data formatting`` bucket =
-    layout copies to hunt), then the top ops.
-    """
-    import glob
-    import gzip
-    import os
-
-    candidates = sorted(
-        glob.glob(os.path.join(logdir, "plugins", "profile", "*",
-                               "*.trace.json.gz")),
-        key=os.path.getmtime)
-    if not candidates:
-        raise FileNotFoundError(
-            f"no plugins/profile/*/*.trace.json.gz under {logdir!r} — "
-            "capture with apex_tpu.profiler.trace(logdir) first")
-    path = candidates[-1]
-    with gzip.open(path, "rt") as f:
-        data = json.load(f)
-    events = data.get("traceEvents", [])
-
-    pids: Dict[Any, str] = {}
-    tids: Dict[Any, str] = {}
-    for e in events:
-        if e.get("ph") == "M":
-            if e.get("name") == "process_name":
-                pids[e["pid"]] = e.get("args", {}).get("name", "")
-            elif e.get("name") == "thread_name":
-                tids[(e["pid"], e.get("tid"))] = e.get(
-                    "args", {}).get("name", "")
-
-    def _device_op(e):
-        if e.get("ph") != "X":
-            return False
-        pname = pids.get(e.get("pid"), "")
-        tname = tids.get((e.get("pid"), e.get("tid")), "")
-        return ("TPU" in pname or "GPU" in pname) and "XLA Ops" in tname
-
-    # nesting is per event stream: one '/device:TPU:N' process per core,
-    # each with its own 'XLA Ops' thread — a shared stack would treat
-    # concurrent ops on different cores as parent/child
-    streams: Dict[Any, List[Any]] = {}
-    for e in events:
-        if _device_op(e):
-            streams.setdefault((e.get("pid"), e.get("tid")), []).append(e)
-
-    self_us: Dict[str, float] = {}
-    count: Dict[str, int] = {}
-    meta: Dict[str, Dict[str, str]] = {}
-    for stream in streams.values():
-        stream.sort(key=lambda e: (e["ts"], -e.get("dur", 0)))
-        stack: List[Any] = []   # (end_ts, name)
-        for e in stream:
-            ts, dur, name = e["ts"], e.get("dur", 0), e["name"]
-            while stack and ts >= stack[-1][0]:
-                stack.pop()
-            if stack:
-                self_us[stack[-1][1]] = self_us.get(
-                    stack[-1][1], 0.0) - dur
-            self_us[name] = self_us.get(name, 0.0) + dur
-            count[name] = count.get(name, 0) + 1
-            if name not in meta:
-                args = e.get("args", {})
-                meta[name] = {
-                    "category": args.get("hlo_category", ""),
-                    "source": args.get("source", ""),
-                }
-            stack.append((ts + dur, name))
-
-    by_cat: Dict[str, float] = {}
-    for name, us in self_us.items():
-        cat = meta[name]["category"] or "(uncategorized)"
-        by_cat[cat] = by_cat.get(cat, 0.0) + us / 1e6
-    ranked = sorted(self_us.items(), key=lambda kv: -kv[1])[:top]
-    return {
-        "total_s": sum(self_us.values()) / 1e6,
-        "by_category": dict(
-            sorted(by_cat.items(), key=lambda kv: -kv[1])),
-        "top_ops": [
-            {"name": n, "seconds": us / 1e6, "count": count[n],
-             "category": meta[n]["category"], "source": meta[n]["source"]}
-            for n, us in ranked],
-        "trace_path": path,
-    }

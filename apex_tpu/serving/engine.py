@@ -1000,19 +1000,20 @@ class Engine:
                 # the zero base key ON DEVICE (no host-side compile to
                 # trip a recompile guard); seeded rows keep their host
                 # key bit-for-bit
-                base = jnp.zeros((2,), jnp.uint32)
-                folded = jax.vmap(
-                    lambda i: jax.random.fold_in(base, i))(req_idx)
-                keys = jnp.where(seeded[:, None], keys, folded)
-                # the k-row draw_slots call vmaps per row over a
-                # [1, vocab] lane — each row IS the solo-generate first
-                # draw (same gumbel shape, same fold index)
-                first = sampling.draw_slots(
-                    logits0, keys, p_lens - 1, temp, top_k, top_p,
-                    masks=masks)
-                first_lp = jnp.take_along_axis(
-                    jax.nn.log_softmax(logits0, axis=-1),
-                    first[:, None], axis=1)[:, 0]
+                with jax.named_scope("apex.sample"):
+                    base = jnp.zeros((2,), jnp.uint32)
+                    folded = jax.vmap(
+                        lambda i: jax.random.fold_in(base, i))(req_idx)
+                    keys = jnp.where(seeded[:, None], keys, folded)
+                    # the k-row draw_slots call vmaps per row over a
+                    # [1, vocab] lane — each row IS the solo-generate
+                    # first draw (same gumbel shape, same fold index)
+                    first = sampling.draw_slots(
+                        logits0, keys, p_lens - 1, temp, top_k, top_p,
+                        masks=masks)
+                    first_lp = jnp.take_along_axis(
+                        jax.nn.log_softmax(logits0, axis=-1),
+                        first[:, None], axis=1)[:, 0]
                 if paged:
                     # the paged scatter: row i's bucket columns land
                     # in its own allocated pages (pad columns reach

@@ -117,6 +117,7 @@ def draw(logits, t, *, temperature: float = 0.0, top_k: int = 0,
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
+@jax.named_scope("apex.sample")
 def draw_slots(logits, keys, t, temperature, top_k, top_p, masks=None):
     """Per-slot batched draw: ``logits [B, vocab]``; ``keys [B, 2]``
     (raw PRNG key data); ``t``/``temperature``/``top_k``/``top_p`` all

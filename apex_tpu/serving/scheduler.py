@@ -76,6 +76,7 @@ tokens — it never occupies a slot (admitting it would burn
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import os
 import time
@@ -144,6 +145,9 @@ FAULT_CAUSES = ("admit", "dispatch", "fetch", "retire", "invalid_token")
 
 #: shed reasons (label values of ``serving_requests_shed_total``)
 SHED_REASONS = ("queue_full", "deadline", "tenant_rate")
+
+#: what a phase of the tick is without a span recorder
+_NO_PHASE = contextlib.nullcontext()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -784,14 +788,19 @@ class Scheduler:
         self._throttled = 0
         #: telemetry sinks (both optional): a telemetry.Registry the
         #: scheduler counts/observes into, and a telemetry.SpanRecorder
-        #: receiving per-request phase marks + dispatch sections. The
+        #: receiving per-request phase marks, the tick's phases
+        #: (``sched.*``) and the engine sections inside them. The
         #: recorder's clock is slaved to the scheduler's so injected
-        #: test clocks produce deterministic timelines.
+        #: test clocks produce deterministic timelines, and its
+        #: sections are annotated into the profiler's trace
+        #: (``apex.sched.*`` / ``apex.engine.*`` on the device trace's
+        #: clock). Without a recorder a phase costs one ``is None``.
         self.telemetry = (None if registry is None
                           else _RegistryMetrics(registry, engine))
         self.spans = spans
         if spans is not None:
             spans.clock = self.clock
+            spans.annotate = profiler.annotate
         self._registry = registry
         #: flight recorder (telemetry.flightrec.FlightRecorder) — the
         #: always-on black box: every load-bearing host decision is one
@@ -1026,6 +1035,15 @@ class Scheduler:
         from the prompt and suppresses the duplicate events, exactly
         like local fault replay, so the continued stream is
         bit-identical."""
+        # a section of its own beside the tick's: what the host does
+        # between two ticks is its callers' time and this
+        with self._phase("sched.submit"):
+            self._submit(request, replay_prefix, replay_logprobs)
+
+    def _submit(self, request: Request,
+                replay_prefix: Optional[Sequence[int]],
+                replay_logprobs: Optional[Sequence[float]]) -> None:
+        """:meth:`submit`'s body."""
         if self.health.state == HEALTH_FAILED:
             raise EngineFailed(
                 f"engine health is failed ({self.health.last_cause}); "
@@ -1231,6 +1249,30 @@ class Scheduler:
 
     # -- the loop ----------------------------------------------------------
 
+    def _phase(self, name: str):
+        """A phase of the tick: a span section (and profiler
+        annotation) when a recorder is attached, nothing otherwise."""
+        sp = self.spans
+        return _NO_PHASE if sp is None else sp.section(name)
+
+    def _timed(self, name: str):
+        """A block the scheduler times for its own accounting
+        (``.start`` / ``.end`` on its clock); with a recorder the same
+        two clock reads are the section ``name``."""
+        sp = self.spans
+        return (spans_mod.Stopwatch(self.clock) if sp is None
+                else sp.section(name))
+
+    def _count_prefill(self, real: int, padded: int, rows: int,
+                       dispatches: int) -> None:
+        """Admission counts into the recorder: prompt tokens given,
+        token rows the programs ran at, requests, device programs."""
+        count = self.spans.count
+        count("prefill.tokens_real", real)
+        count("prefill.tokens_padded", padded)
+        count("prefill.rows", rows)
+        count("prefill.dispatches", dispatches)
+
     def step(self) -> None:
         """One scheduler tick: expire/shed deadlines, batch-admit
         queued requests into free slots, dispatch the next decode chunk
@@ -1247,26 +1289,40 @@ class Scheduler:
         self._dump_token += 1
         if self.health.state == HEALTH_FAILED:
             return
+        with self._phase("sched.step"):
+            self._tick()
+
+    def _tick(self) -> None:
+        """:meth:`step`'s body, in its five phases."""
         now = self.clock()
         if self._started is None:
             self._started = now
-        self._poll_guard_alarms()
-        self._sync_tuner()
-        self._sync_slo(now)
-        self._expire(now)
+        with self._phase("sched.housekeeping"):
+            self._poll_guard_alarms()
+            self._sync_tuner()
+            self._sync_slo(now)
+            self._expire(now)
         # admissions FIRST, then one chunk of any in-progress chunked
         # prefill, then the decode dispatch: a short prompt's
         # admission never queues behind this tick's chunk forward, so
         # the long admission inflates nobody's TTFT — the interleave
         # that keeps a 32k-token admission from stalling every other
         # stream
-        self._admit_queued(now)
-        self._advance_chunked(now)
-        dispatched = bool(self.active) and self._dispatch_chunk()
+        with self._phase("sched.admit"):
+            self._admit_queued(now)
+            self._advance_chunked(now)
+        with self._phase("sched.dispatch"):
+            dispatched = bool(self.active) and self._dispatch_chunk()
         keep = self.pipeline_depth - 1 if dispatched else 0
-        while len(self._inflight) > keep:
-            self._collect_oldest()
+        with self._phase("sched.collect"):
+            while len(self._inflight) > keep:
+                self._collect_oldest()
         self._steps += 1
+        with self._phase("sched.publish"):
+            self._publish()
+
+    def _publish(self) -> None:
+        """The tick's gauges and its metrics line."""
         if self.telemetry is not None:
             self.telemetry.steps.inc()
             self.telemetry.queue_depth.set(len(self.queue))
@@ -1946,20 +2002,18 @@ class Scheduler:
                 point = None
         else:
             step_kw["spec"] = self._use_spec()
-        t0 = self.clock()
         try:
-            handle = self.engine.step_async(**step_kw)
+            # the host-side cost of getting the chunk onto the device —
+            # the half of the old engine.step section the pipeline
+            # cannot hide
+            with self._timed("engine.dispatch") as timed:
+                handle = self.engine.step_async(**step_kw)
         except Exception as e:  # device error escaping the dispatch
             self._recover(self.clock(), cause="dispatch", detail=str(e),
                           affected=[a.request for _, a in
                                     sorted(self.active.items())])
             return False
-        t1 = self.clock()
-        if self.spans is not None:
-            # the host-side cost of getting the chunk onto the device —
-            # the half of the old engine.step section the pipeline
-            # cannot hide
-            self.spans.section_at("engine.dispatch", t0, t1)
+        t0 = timed.start
         # snapshot the live slots: by the time this chunk is fetched,
         # some may have been released (finish seen in an earlier chunk,
         # deadline retire) and their columns must be dropped
@@ -1975,23 +2029,22 @@ class Scheduler:
     def _collect_oldest(self) -> None:
         handle, snapshot, t_dispatch, depth_at_dispatch, point = \
             self._inflight.popleft()
-        t0 = self.clock()
         try:
-            tokens, logprobs, finished = handle.fetch()
+            # the blocking wait for the chunk's value — under pipelining
+            # this shrinks toward zero while engine.dispatch stays put
+            with self._timed("engine.fetch") as timed:
+                tokens, logprobs, finished = handle.fetch()
         except Exception as e:  # device error escaping the fetch
             self._recover(self.clock(), cause="fetch", detail=str(e),
                           affected=[a.request
                                     for s, a in sorted(snapshot.items())
                                     if self.active.get(s) is a])
             return
-        now = self.clock()
+        now = timed.end
         tele = self.telemetry
         if tele is not None:
             tele.inflight.set(len(self._inflight))
         if self.spans is not None:
-            # the blocking wait for the chunk's value — under pipelining
-            # this shrinks toward zero while engine.dispatch stays put
-            self.spans.section_at("engine.fetch", t0, now)
             for slot, act in snapshot.items():
                 if self.active.get(slot) is act:
                     self.spans.mark(act.request.request_id,
@@ -2865,9 +2918,13 @@ class Scheduler:
                     raw.append({"kind": "mark", "t": e[1],
                                 "request_id": e[2], "phase": e[3],
                                 "note": e[4]})
+                elif e[0] == spans_mod._COUNT:
+                    raw.append({"kind": "count", "t": e[1],
+                                "name": e[2], "n": e[3]})
                 else:
                     raw.append({"kind": "section", "t": e[1],
-                                "name": e[2], "t_end": e[3]})
+                                "name": e[2], "t_end": e[3],
+                                "parent": e[4]})
             files["spans_raw.jsonl"] = raw
         plan = engine.fault_plan
         if plan is not None:
@@ -3055,6 +3112,13 @@ class Scheduler:
         self._chunked_admissions += 1
         self._admitted_requests += 1
         self._admit_dispatches += 1
+        if self.spans is not None:
+            # one row through chunks_total forwards of a whole chunk
+            # each, and the finish
+            self._count_prefill(
+                ca.p_len,
+                ca.chunks_total * self.engine.engine_cfg.prefill_chunk,
+                1, ca.chunks_total + 1)
         st = self._replay.get(r.request_id)
         act = _Active(r)
         act.suppress = 0 if st is None else len(st.tokens)
@@ -3270,12 +3334,11 @@ class Scheduler:
                 # from the prompt, and the constraint must follow it
                 if r.constraint is not None:
                     r.constraint.reset()
-            t_admit = self.clock()
-
             try:
-                results = self.engine.admit_many([
-                    self._admission_of(r, slot)
-                    for r, slot in zip(reqs, slots)])
+                with self._timed("engine.admit") as timed:
+                    results = self.engine.admit_many([
+                        self._admission_of(r, slot)
+                        for r, slot in zip(reqs, slots)])
             except PagesExhausted:
                 # backpressure raced the pre-flight check (a stale
                 # mapping, a share) — requeue and wait, no fault; the
@@ -3290,7 +3353,7 @@ class Scheduler:
                 self._recover(self.clock(), cause="admit", detail=str(e),
                               affected=list(reqs), batch_reqs=list(reqs))
                 return
-            t_first = self.clock()
+            t_admit, t_first = timed.start, timed.end
             # NaN-poisoned prefill: a garbage first token means the
             # admission's cache insert cannot be trusted — quarantine
             # before any event leaks, charging only the bad rows
@@ -3307,7 +3370,14 @@ class Scheduler:
             self._admitted_requests += len(reqs)
             self._admit_dispatches += n_groups
             if self.spans is not None:
-                self.spans.section_at("engine.admit", t_admit, t_first)
+                # what the admission programs were given, against what
+                # they ran at: rows x bucket is the padded batch
+                hits = self._prefix_hits
+                self._count_prefill(
+                    sum(len(r.prompt) - hits.get(r.request_id, (0, 0))[1]
+                        for r in reqs),
+                    sum(res.bucket for res in results),
+                    len(reqs), n_groups)
             tele = self.telemetry
             if tele is not None:
                 tele.admit_dispatches.inc(n_groups)

@@ -7,7 +7,11 @@ marks — ``queued`` at submit, ``prefill`` entering admission,
 the slot rode, ``retired`` at release — each an O(1) ring append of a
 4-tuple (no allocation-heavy objects, no dict per event, safe on the
 per-chunk hot path). ``section()`` is the host-side ``annotate``
-analogue for non-request work (engine dispatch, scrape handlers).
+analogue for non-request work (the scheduler's tick and its phases,
+engine dispatch, scrape handlers): sections nest, each row names the
+section it ran inside, and with an ``annotate`` hook the same ranges
+land in the profiler's trace. ``count()`` records how much of something
+a section handled (tokens admitted, rows padded).
 
 ``to_chrome_trace()`` renders the ring as Chrome-trace JSON: one lane
 (tid) per request plus a lane for host sections, consecutive marks of a
@@ -23,9 +27,8 @@ which this module never triggers).
 
 from __future__ import annotations
 
-import contextlib
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from apex_tpu.telemetry.ring import Ring
 
@@ -41,6 +44,61 @@ PHASE_ERROR = "error"
 
 _MARK = 0
 _SECTION = 1
+_COUNT = 2
+
+#: prefix of a section's name on the profiler's side
+ANNOTATION_PREFIX = "apex."
+
+
+class Stopwatch:
+    """Times a ``with`` block on ``clock``: ``start`` is read on entry,
+    ``end`` on exit — what a caller that needs the interval for its own
+    accounting uses where no recorder is attached."""
+
+    __slots__ = ("clock", "start", "end")
+
+    def __init__(self, clock: Callable[[], float]):
+        self.clock = clock
+        self.start = self.end = 0.0
+
+    def __enter__(self):
+        self.start = self.clock()
+        return self
+
+    def __exit__(self, *exc):
+        self.end = self.clock()
+        return False
+
+
+class _Section(Stopwatch):
+    """One open :meth:`SpanRecorder.section`: a :class:`Stopwatch` that
+    on exit appends its row, naming the section it ran inside."""
+
+    __slots__ = ("rec", "name", "parent", "_annotation")
+
+    def __init__(self, rec: "SpanRecorder", name: str):
+        super().__init__(rec.clock)
+        self.rec, self.name = rec, name
+
+    def __enter__(self):
+        rec = self.rec
+        self.parent = rec._open[-1] if rec._open else None
+        rec._open.append(self.name)
+        self._annotation = None
+        if rec.annotate is not None:
+            self._annotation = rec.annotate(ANNOTATION_PREFIX + self.name)
+            self._annotation.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        rec = self.rec
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        rec._open.pop()
+        rec._events.append(
+            (_SECTION, self.start, self.name, self.end, self.parent))
+        return False
 
 
 class SpanRecorder:
@@ -48,15 +106,27 @@ class SpanRecorder:
 
     ``clock`` is injectable (the scheduler passes its own, so test
     clocks drive deterministic timelines); it must be monotonic
-    seconds. The ring keeps the most recent ``capacity`` events —
+    seconds. ``annotate`` (optional, ``name -> context manager``) is
+    entered around every :meth:`section` under the section's name
+    prefixed ``apex.`` — the scheduler sets it to
+    ``jax.profiler.TraceAnnotation``, which puts the sections on the
+    profiler's clock beside the device trace (this module stays free
+    of jax). The ring keeps the most recent ``capacity`` events —
     ``summary()`` reports how many were dropped so a truncated export
     is never mistaken for a complete one.
+
+    Rows: ``(0, time, request, phase, note)`` for a mark, ``(1, start,
+    name, end, parent)`` for a section (``parent`` the name of the
+    section open around it, None at top level) and ``(2, time, name,
+    n, None)`` for a count. One thread records.
     """
 
     def __init__(self, capacity: int = 65536,
-                 clock=time.perf_counter):
+                 clock=time.perf_counter, annotate=None):
         self._events = Ring(capacity)
         self.clock = clock
+        self.annotate = annotate
+        self._open: List[str] = []      # names of the open sections
 
     # -- recording (hot path) ----------------------------------------------
 
@@ -66,21 +136,28 @@ class SpanRecorder:
         self._events.append(
             (_MARK, self.clock(), request_id, phase, note))
 
-    @contextlib.contextmanager
-    def section(self, name: str):
-        """Host-side named range (engine dispatch, scrape, IO) — the
-        wall-clock sibling of :func:`apex_tpu.profiler.annotate`."""
-        t0 = self.clock()
-        try:
-            yield
-        finally:
-            self._events.append((_SECTION, t0, name, self.clock(), None))
+    def section(self, name: str) -> _Section:
+        """Host-side named range (a scheduler phase, engine dispatch,
+        scrape, IO) — the wall-clock sibling of
+        :func:`apex_tpu.profiler.annotate`, and with an ``annotate``
+        hook the same range on the profiler's clock. The ``with``
+        target carries ``start`` and ``end`` (the clock reads the row
+        is made of), for a caller that accounts with them too."""
+        return _Section(self, name)
 
     def section_at(self, name: str, t_start: float, t_end: float) -> None:
-        """Record an already-measured range (a caller that timed the
-        interval itself — e.g. the scheduler's dispatch timing, which it
-        needs for throughput accounting anyway)."""
-        self._events.append((_SECTION, t_start, name, t_end, None))
+        """Record an already-measured range whose start lies in an
+        earlier call (dispatch → value of a speculative chunk, fault →
+        rebuilt engine): host clock only, its parent the section open
+        when it is recorded."""
+        self._events.append(
+            (_SECTION, t_start, name, t_end,
+             self._open[-1] if self._open else None))
+
+    def count(self, name: str, n: float) -> None:
+        """O(1): ``n`` more of ``name`` now (prompt tokens admitted,
+        rows of a padded batch)."""
+        self._events.append((_COUNT, self.clock(), name, n, None))
 
     # -- export -------------------------------------------------------------
 
@@ -125,12 +202,20 @@ class SpanRecorder:
         # one lane per request, in order of first appearance
         lanes: Dict[str, int] = {}
         last_mark: Dict[str, tuple] = {}
+        totals: Dict[str, float] = {}
         for e in evs:
             if e[0] == _SECTION:
                 _, t_start, name, t_end, _ = e
                 out.append({"ph": "X", "pid": 2, "tid": 0, "name": name,
                             "ts": us(t_start),
                             "dur": max(us(t_end) - us(t_start), 0.0)})
+                continue
+            if e[0] == _COUNT:
+                # a running total per name, as a counter track
+                _, t, name, n, _ = e
+                totals[name] = totals.get(name, 0) + n
+                out.append({"ph": "C", "pid": 2, "name": name,
+                            "ts": us(t), "args": {name: totals[name]}})
                 continue
             _, t, rid, phase, note = e
             tid = lanes.get(rid)

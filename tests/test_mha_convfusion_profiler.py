@@ -171,7 +171,14 @@ def test_step_timer_and_metrics(tmp_path):
         timer.tick(x * 2)
     s = timer.summary()
     assert s["steps"] == 3 and s["tokens_per_sec"] > 0
-    assert profiler.model_flops_per_token(100, remat=True) == 800.0
+    # a FLOP count per step (the caller's: the benchmark counts from
+    # shapes) becomes a rate over the same median
+    flops = profiler.StepTimer(model_flops_per_step=6e9, window=10)
+    for _ in range(3):
+        flops.tick(x)
+    fs = flops.summary()
+    assert fs["model_flops_per_sec"] == pytest.approx(
+        6e9 / fs["median_step_s"])
 
     log = profiler.MetricsLogger(jsonl_path=str(tmp_path / "m.jsonl"))
     log.log(0, {"loss": jnp.float32(3.5), "lr": 0.1})
@@ -284,146 +291,135 @@ def test_annotate_and_tick_sync():
     assert float(y) == 45.0
 
 
-def test_op_profile_self_times(tmp_path):
-    """op_profile parses a trace capture into nested-aware self-times:
-    a while containing two fusions self-times to its remainder, and
-    category/source attribution survives aggregation."""
-    import gzip
-    import json
+# --- the host side of a capture: sections, parents, counts ----------------
+# (what a capture's DEVICE time is called — regions, kernel names — is
+# tests/test_regions.py; benchmark/tools/trace_regions.py reads it)
+
+def test_sections_reach_the_profilers_trace(tmp_path):
+    """``SpanRecorder(annotate=profiler.annotate)`` under a real
+    :func:`profiler.trace` capture: every ``with`` section is a host
+    event ``apex.<section>`` in the ``.xplane.pb``, nested as recorded,
+    on the profiler's clock — the capture the device trace is in."""
+    import glob
     import os
 
-    d = tmp_path / "plugins" / "profile" / "2026_01_01_00_00_00"
-    os.makedirs(d)
-    events = [
-        {"ph": "M", "pid": 3, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "pid": 3, "tid": 1, "name": "thread_name",
-         "args": {"name": "XLA Ops"}},
-        # host-side event must be ignored
-        {"ph": "M", "pid": 9, "name": "process_name",
-         "args": {"name": "/host:CPU"}},
-        {"ph": "M", "pid": 9, "tid": 1, "name": "thread_name",
-         "args": {"name": "XLA Ops"}},
-        {"ph": "X", "pid": 9, "tid": 1, "name": "hostjunk",
-         "ts": 0, "dur": 999},
-        # while.1 [0, 100) containing fusion.1 [10, 40) and fusion.2
-        # [50, 90) -> self 30
-        {"ph": "X", "pid": 3, "tid": 1, "name": "while.1", "ts": 0,
-         "dur": 100, "args": {"hlo_category": "while"}},
-        {"ph": "X", "pid": 3, "tid": 1, "name": "fusion.1", "ts": 10,
-         "dur": 30, "args": {"hlo_category": "convolution fusion",
-                             "source": "model.py:42"}},
-        {"ph": "X", "pid": 3, "tid": 1, "name": "fusion.2", "ts": 50,
-         "dur": 40, "args": {"hlo_category": "loop fusion"}},
-        # top-level copy after the while
-        {"ph": "X", "pid": 3, "tid": 1, "name": "copy.1", "ts": 120,
-         "dur": 10, "args": {"hlo_category": "data formatting",
-                             "source": "model.py:99"}},
-    ]
-    with gzip.open(d / "vm.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": events}, f)
+    from jax.profiler import ProfileData
 
-    prof = profiler.op_profile(str(tmp_path))
-    by_name = {o["name"]: o for o in prof["top_ops"]}
-    assert by_name["while.1"]["seconds"] == pytest.approx(30e-6)
-    assert by_name["fusion.1"]["seconds"] == pytest.approx(30e-6)
-    assert by_name["fusion.2"]["seconds"] == pytest.approx(40e-6)
-    assert by_name["copy.1"]["seconds"] == pytest.approx(10e-6)
-    assert "hostjunk" not in by_name
-    assert prof["total_s"] == pytest.approx(110e-6)
-    assert prof["by_category"]["data formatting"] == pytest.approx(10e-6)
-    assert by_name["fusion.1"]["source"] == "model.py:42"
-    assert by_name["fusion.1"]["count"] == 1
+    from apex_tpu.telemetry.spans import SpanRecorder
+
+    rec = SpanRecorder(annotate=profiler.annotate)
+    with profiler.trace(str(tmp_path)):
+        with rec.section("sched.step"):
+            with rec.section("engine.fetch"):
+                jnp.sum(jnp.arange(10.0)).block_until_ready()
+        rec.section_at("engine.verify", 0.0, 1.0)   # host clock only
+    path, = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    found = {ev.name: (ev.start_ns, ev.start_ns + ev.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("apex.")}
+    assert set(found) == {"apex.sched.step", "apex.engine.fetch"}
+    (a, b), (c, d) = found["apex.sched.step"], found["apex.engine.fetch"]
+    assert a <= c < d <= b
+    assert [(e[2], e[4]) for e in rec.events()] == [
+        ("engine.fetch", "sched.step"), ("sched.step", None),
+        ("engine.verify", None)]
 
 
-def test_op_profile_missing_trace(tmp_path):
-    with pytest.raises(FileNotFoundError, match="trace.json.gz"):
-        profiler.op_profile(str(tmp_path))
+def test_section_rows_name_their_parent():
+    """A section row's fifth field is the section open around it —
+    also for a ``section_at`` recorded inside one, and the stack
+    unwinds when a section's body raises."""
+    from apex_tpu.telemetry.spans import SpanRecorder
+
+    t = [0.0]
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(("in", self.name))
+
+        def __exit__(self, *exc):
+            entered.append(("out", self.name))
+
+    rec = SpanRecorder(clock=lambda: t[0], annotate=Annotation)
+    with rec.section("sched.step") as step:
+        t[0] = 1.0
+        with pytest.raises(RuntimeError):
+            with rec.section("sched.collect"):
+                t[0] = 2.0
+                rec.section_at("engine.verify", 0.5, 2.0)
+                raise RuntimeError("fetch failed")
+        with rec.section("sched.publish"):
+            t[0] = 3.0
+    with rec.section("sched.submit"):
+        t[0] = 4.0
+    assert (step.start, step.end) == (0.0, 3.0)
+    assert [e[1:] for e in rec.events()] == [
+        (0.5, "engine.verify", 2.0, "sched.collect"),
+        (1.0, "sched.collect", 2.0, "sched.step"),
+        (2.0, "sched.publish", 3.0, "sched.step"),
+        (0.0, "sched.step", 3.0, None),
+        (3.0, "sched.submit", 4.0, None)]
+    assert entered[:4] == [("in", "apex.sched.step"),
+                           ("in", "apex.sched.collect"),
+                           ("out", "apex.sched.collect"),
+                           ("in", "apex.sched.publish")]
+    assert not rec._open
 
 
-def test_op_profile_newest_capture_and_nested_streams(tmp_path):
-    """Two capture dirs under one logdir: op_profile parses the newest
-    (by mtime); its fixture nests ops on BOTH cores, so per-stream
-    self-time accounting and category rollup are exercised together."""
-    import gzip
-    import json
-    import os
-    import time
+def test_span_counts_are_chrome_counter_tracks():
+    """``count()`` rows render as Chrome "C" events carrying the
+    running total of their name, beside the sections' lane, and are no
+    request's mark."""
+    from apex_tpu.telemetry.spans import SpanRecorder
 
-    def write(dirname, events):
-        d = tmp_path / "plugins" / "profile" / dirname
-        os.makedirs(d)
-        path = d / "vm.trace.json.gz"
-        with gzip.open(path, "wt") as f:
-            json.dump({"traceEvents": events}, f)
-        return path
-
-    meta = []
-    for pid in (3, 4):
-        meta += [
-            {"ph": "M", "pid": pid, "name": "process_name",
-             "args": {"name": f"/device:TPU:{pid - 3}"}},
-            {"ph": "M", "pid": pid, "tid": 1, "name": "thread_name",
-             "args": {"name": "XLA Ops"}},
-        ]
-    write("2026_01_01_00_00_00", meta + [
-        {"ph": "X", "pid": 3, "tid": 1, "name": "stale.1", "ts": 0,
-         "dur": 50, "args": {"hlo_category": "loop fusion"}}])
-    time.sleep(0.05)  # distinct mtimes
-    # newest capture: a while on each core, each containing one fusion
-    newest = write("2026_01_01_00_00_59", meta + [
-        {"ph": "X", "pid": 3, "tid": 1, "name": "while.a", "ts": 0,
-         "dur": 100, "args": {"hlo_category": "while"}},
-        {"ph": "X", "pid": 3, "tid": 1, "name": "fusion.a", "ts": 20,
-         "dur": 30, "args": {"hlo_category": "loop fusion"}},
-        {"ph": "X", "pid": 4, "tid": 1, "name": "while.b", "ts": 10,
-         "dur": 60, "args": {"hlo_category": "while"}},
-        {"ph": "X", "pid": 4, "tid": 1, "name": "fusion.b", "ts": 30,
-         "dur": 20, "args": {"hlo_category": "convolution fusion"}},
-    ])
-    prof = profiler.op_profile(str(tmp_path))
-    assert prof["trace_path"] == str(newest)
-    by_name = {o["name"]: o for o in prof["top_ops"]}
-    assert "stale.1" not in by_name
-    # self-time = parent minus its own core's child only
-    assert by_name["while.a"]["seconds"] == pytest.approx(70e-6)
-    assert by_name["while.b"]["seconds"] == pytest.approx(40e-6)
-    assert prof["total_s"] == pytest.approx(160e-6)
-    assert prof["by_category"]["while"] == pytest.approx(110e-6)
-    assert prof["by_category"]["loop fusion"] == pytest.approx(30e-6)
-    assert prof["by_category"]["convolution fusion"] == \
-        pytest.approx(20e-6)
+    t = [0.0]
+    rec = SpanRecorder(clock=lambda: t[0])
+    rec.mark("r0", "prefill")
+    rec.count("prefill.tokens_real", 100)
+    rec.count("prefill.tokens_padded", 256)
+    t[0] = 0.5
+    rec.count("prefill.tokens_real", 30)
+    rec.mark("r0", "first_token")
+    ct = rec.to_chrome_trace()
+    counters = [(e["name"], e["ts"], e["args"]) for e in ct["traceEvents"]
+                if e["ph"] == "C"]
+    assert counters == [
+        ("prefill.tokens_real", 0.0, {"prefill.tokens_real": 100}),
+        ("prefill.tokens_padded", 0.0, {"prefill.tokens_padded": 256}),
+        ("prefill.tokens_real", 5e5, {"prefill.tokens_real": 130})]
+    assert all(e["pid"] == 2 for e in ct["traceEvents"] if e["ph"] == "C")
+    # the request's lane holds its two marks and nothing of the counts
+    assert [e["name"] for e in ct["traceEvents"]
+            if e["ph"] in ("X", "i") and e["pid"] == 1] == [
+        "prefill", "first_token"]
+    assert rec.summary()["requests"] == 1 and rec.summary()["events"] == 5
 
 
-def test_op_profile_multi_device_streams(tmp_path):
-    """Concurrent ops on different cores must NOT nest: each (pid, tid)
-    stream gets its own stack, so overlapping-in-time ops on two devices
-    keep their full self-times."""
-    import gzip
-    import json
-    import os
+def test_timed_block_without_a_recorder_is_a_stopwatch():
+    """What the scheduler times for its own accounting it reads from
+    the same ``start`` / ``end`` with or without a recorder: a bare
+    :class:`Stopwatch` records nothing, a section the same two reads."""
+    from apex_tpu.telemetry.spans import SpanRecorder, Stopwatch
 
-    d = tmp_path / "plugins" / "profile" / "2026_01_01_00_00_01"
-    os.makedirs(d)
-    events = []
-    for pid in (3, 4):
-        events += [
-            {"ph": "M", "pid": pid, "name": "process_name",
-             "args": {"name": f"/device:TPU:{pid - 3}"}},
-            {"ph": "M", "pid": pid, "tid": 1, "name": "thread_name",
-             "args": {"name": "XLA Ops"}},
-        ]
-    # core0 op [0, 100) and core1 op [10, 40) overlap in wall time
-    events += [
-        {"ph": "X", "pid": 3, "tid": 1, "name": "fusion.a", "ts": 0,
-         "dur": 100, "args": {"hlo_category": "loop fusion"}},
-        {"ph": "X", "pid": 4, "tid": 1, "name": "fusion.b", "ts": 10,
-         "dur": 30, "args": {"hlo_category": "loop fusion"}},
-    ]
-    with gzip.open(d / "vm.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": events}, f)
-    prof = profiler.op_profile(str(tmp_path))
-    by_name = {o["name"]: o for o in prof["top_ops"]}
-    assert by_name["fusion.a"]["seconds"] == pytest.approx(100e-6)
-    assert by_name["fusion.b"]["seconds"] == pytest.approx(30e-6)
-    assert prof["total_s"] == pytest.approx(130e-6)
+    t = [10.0]
+
+    def clock():
+        t[0] += 1.0
+        return t[0]
+
+    with Stopwatch(clock) as bare:
+        pass
+    assert (bare.start, bare.end) == (11.0, 12.0)
+    rec = SpanRecorder(clock=clock)
+    with rec.section("engine.fetch") as timed:
+        pass
+    assert (timed.start, timed.end) == (13.0, 14.0)
+    assert rec.events() == [(1, 13.0, "engine.fetch", 14.0, None)]
+    assert t[0] == 14.0     # two reads each, none for the row

@@ -29,6 +29,7 @@ from apex_tpu.serving import Request, SamplingParams
 from apex_tpu.serving.engine import Engine, EngineConfig
 from apex_tpu.serving.pages import SINK, PageAllocator, PagesExhausted
 from apex_tpu.serving.scheduler import Scheduler
+from apex_tpu.telemetry.spans import SpanRecorder
 from apex_tpu.transformer.testing import standalone_gpt_config
 
 VOCAB = 96
@@ -410,8 +411,9 @@ def test_chunked_prefill_stream_parity(devices8):
     eng_ch = _mk_engine(_cfg(), dataclasses.replace(
         _POOL_ECFG, prefix_pool_slots=0, page_size=8,
         prefill_chunk=16), mesh)
+    spans = SpanRecorder()
     with eng_ch.recompile_guard():
-        toks, s = _run(eng_ch, _trace(**trace_kw))
+        toks, s = _run(eng_ch, _trace(**trace_kw), spans=spans)
     sizes = {k: v for k, v in eng_ch.compiled_cache_sizes().items()
              if v is not None}
     eng_ch.close()
@@ -419,6 +421,23 @@ def test_chunked_prefill_stream_parity(devices8):
     assert s["chunked_admissions"] == 2.0  # the two 30-token prompts
     assert s["chunked_chunks"] == 4.0      # two chunks each
     assert all(v == 1 for v in sizes.values()), sizes
+    # the recorder's admission counts hold the chunked rows too: each
+    # 30-token prompt ran two forwards of a whole 16-token chunk and a
+    # finish
+    counts = {}
+    for e in spans.events():
+        if e[0] == 2:
+            counts.setdefault(e[2], []).append(e[3])
+    assert sum(counts["prefill.rows"]) == 6
+    assert sum(counts["prefill.tokens_real"]) == sum(
+        len(r.prompt) for r in _trace(**trace_kw))
+    chunked = [i for i, n in enumerate(counts["prefill.tokens_real"])
+               if n == 30]
+    assert [(counts["prefill.tokens_padded"][i],
+             counts["prefill.dispatches"][i]) for i in chunked] == [
+        (32, 3), (32, 3)]
+    assert sum(counts["prefill.dispatches"]) == s["admit_dispatches"] \
+        + s["chunked_chunks"]
 
 
 def test_paged_backpressure_completes_everything(devices8):

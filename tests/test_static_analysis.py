@@ -696,6 +696,32 @@ def test_metric_drift_span_colliding_with_engine_api(tmp_path):
         "\n".join(f.render() for f in res.findings)
 
 
+def test_metric_drift_sched_sections(tmp_path):
+    # a section is registered by whatever call names it (the
+    # scheduler's own wrappers here); `sched.<x>` alone in backticks is
+    # a span claim even where <x> is also a method (`sched.step`) — the
+    # call spelling and a code sample's attribute access are not
+    res = _synth(tmp_path, {
+        "apex_tpu/__init__.py": "",
+        "apex_tpu/serving/__init__.py": "",
+        "apex_tpu/serving/scheduler.py": '''
+            class Scheduler:
+                def step(self):
+                    with self._phase("sched.collect"):
+                        with self._timed("engine.fetch"):
+                            self.completions = {}
+        ''',
+        "docs/API.md": "`sched.collect` and `engine.fetch` sections "
+                       "inside `sched.step`; call `sched.step()`, "
+                       "read `sched.completions[rid]`.\n",
+        "bench.py": "done = sched.completions\n",
+    }, targets=["apex_tpu"])
+    hits = [f for f in res.findings if f.rule == "METRIC-DRIFT"]
+    assert len(hits) == 1 and "sched.step" in hits[0].message \
+        and hits[0].path == "docs/API.md", \
+        "\n".join(f.render() for f in res.findings)
+
+
 def test_metric_drift_label_and_alternation_tokens(tmp_path):
     res = _synth(tmp_path, {
         "apex_tpu/__init__.py": "",
