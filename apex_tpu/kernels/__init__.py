@@ -29,6 +29,8 @@ from apex_tpu.kernels.decode_attention import (
     paged_write_columns_quant,
     paged_write_columns_xla,
     quantize_kv_rows,
+    stacked_decode_attention,
+    stacked_write_columns,
 )
 from apex_tpu.kernels.flash_attention import (
     flash_attention,
@@ -69,6 +71,8 @@ __all__ = [
     "paged_write_columns_quant",
     "paged_write_columns_xla",
     "quantize_kv_rows",
+    "stacked_decode_attention",
+    "stacked_write_columns",
     "flash_attention",
     "flash_attention_bsh",
     "flash_attention_with_lse",

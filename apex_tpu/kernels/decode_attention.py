@@ -12,18 +12,31 @@ composed by :func:`decode_attention`:
 
 - **column write**: the new K/V column lands at each row's own ``pos``
   through a scalar-prefetch index map with the cache aliased
-  input→output (``input_output_aliases``). A cache position is one ROW
-  of a ``(sublane, lane)`` tile and Mosaic moves whole tiles, so each
-  grid step reads the one tile-high window that holds ``pos[b]``
-  (8 rows f32 / 16 bf16 / 32 int8, fp8 — the block index is ``pos[b]
-  // tile``), replaces row ``pos[b] % tile`` and writes the window
-  back; the rest of the cache is never touched;
+  input→output (``input_output_aliases``). The operand is the WHOLE
+  stacked cache ``[L, 2, b, h, S, d]`` and the layer is one more
+  scalar-prefetch operand: the block index carries the leading
+  ``(layer, plane)`` coordinates, so the model's layer scan carries the
+  cache and never slices a layer out or stacks it back. A cache
+  position is one ROW of a ``(sublane, lane)`` tile and Mosaic moves
+  whole tiles, so each grid step reads the one tile-high window that
+  holds ``pos[b]`` in the K and the V plane of that layer (8 rows f32 /
+  16 bf16 / 32 int8, fp8 — the block index is ``pos[b] // tile``),
+  replaces row ``pos[b] % tile`` and writes the window back; the rest
+  of the cache — every other layer included — is never touched;
 - **split-K read**: flash-decode attention — the cache horizon is swept
   in ``block_k`` chunks with a running online-softmax ``(out, lse)``
   merge (the same ``m/l/acc`` update as the training flash kernel),
   per-row masking ``col <= pos[b]`` matching ``gpt.decode_step``'s
   vector-``pos`` semantics exactly: garbage cache entries past a row's
-  position contribute exact softmax zeros.
+  position contribute exact softmax zeros. K and V chunks are blocks
+  of plane 0 and plane 1 of the same stacked operand at the prefetched
+  layer.
+
+The stacked forms (:func:`stacked_decode_attention`,
+:func:`stacked_write_columns`) are what the model calls; the per-layer
+functions (:func:`decode_attention`, the ``paged_*`` family) take one
+layer's K and V planes, stack them into a one-layer cache — a copy —
+and run the same two kernels at layer 0.
 
 Numerics match the materialised-scores XLA path: scores are computed
 with fp32 accumulation (``preferred_element_type``) and the softmax
@@ -91,66 +104,75 @@ def _fit_block_k(want: int, sk: int, align: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# column write: plane[row, :, pos, ...] = new[b]  (one window per row)
+# column write: cache[layer, :, row, :, pos, ...] = new[:, b]
+# (the K and the V window of one layer per row, in one block)
 # ---------------------------------------------------------------------------
 
 def _write_kernel(*refs, n_scalar, windows, page):
-    """Land one column per cache plane. Each plane's block is the
-    tile-aligned window of ``windows[k]`` positions around ``pos`` (dim
-    2 of the block: sublanes of a ``[.., h, S, d]`` data plane, lanes of
-    a ``[.., h, S]`` scale plane); the incoming block is one position
-    wide and broadcasts over it."""
+    """Land one column per cache operand. Each operand's block is the
+    K and the V plane's tile-aligned window of ``windows[k]`` positions
+    around ``pos`` (dim 3 of the block: sublanes of a ``[2, 1, h, w,
+    d]`` data block, lanes of a ``[2, 1, h, w]`` scale block); the
+    incoming block is one position wide and broadcasts over it."""
     n = len(windows)
     news = refs[n_scalar:n_scalar + n]
     olds = refs[n_scalar + n:n_scalar + 2 * n]
     outs = refs[n_scalar + 2 * n:]
-    pos = refs[0][pl.program_id(0)]
+    pos = refs[1][pl.program_id(0)]
     if page:
         pos = lax.rem(pos, page)
     for new_ref, old_ref, out_ref, w in zip(news, olds, outs, windows):
-        hit = (lax.broadcasted_iota(jnp.int32, out_ref.shape, 2)
+        hit = (lax.broadcasted_iota(jnp.int32, out_ref.shape, 3)
                == lax.rem(pos, w))
         out_ref[...] = jnp.where(hit, new_ref[...], old_ref[...])
 
 
-def _write_column_planes(news, planes, pos, table=None):
-    """Write ``news[k] [b, h(, d)]`` into position ``pos[b]`` of
-    ``planes[k]`` — contiguous caches ``[b, h, S(, d)]``, or, with
-    ``table [b, max_pages]``, page pools ``[num_pages, h, P(, d)]``
-    where the cell is ``(table[b, pos // P], pos % P)``. One grid step
-    per batch row; every plane is aliased input→output so only the
-    windows holding the written cells move. ``0 <= pos[b]`` must lie
+def _layer_scalar(layer):
+    """The layer index as a scalar-prefetch operand."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _write_column_planes(news, planes, layer, pos, table=None):
+    """Write ``news[k] [2, b, h(, d)]`` (the K and the V row) into
+    position ``pos[b]`` of layer ``layer`` of ``planes[k]`` — stacked
+    contiguous caches ``[L, 2, b, h, S(, d)]``, or, with ``table [b,
+    max_pages]``, page pools ``[L, 2, num_pages, h, P(, d)]`` where the
+    cell is ``(table[b, pos // P], pos % P)``. ``planes`` is ``[kv]``
+    or the quantized ``[kv, scale]``. One grid step per batch row;
+    every operand is aliased input→output so only the windows holding
+    the written cells move, whatever ``L`` is. ``0 <= pos[b]`` must lie
     inside the row's horizon, and rows must target distinct windows —
     except inside a shared garbage/sink page, where the pipelined
     read-modify-write of one window by two rows keeps only one row's
     cell (the sink holds garbage by contract)."""
-    b = news[0].shape[0]
-    p_sz = planes[0].shape[2]
+    b = news[0].shape[1]
+    p_sz = planes[0].shape[4]
     paged = table is not None
     mp = table.shape[1] if paged else 0
     new_specs, plane_specs, windows = [], [], []
     for plane in planes:
-        h = plane.shape[1]
-        if plane.ndim == 4:
+        h = plane.shape[3]
+        if plane.ndim == 6:
             w = min(_sublane_tile(plane.dtype), p_sz)
-            tail = (plane.shape[3],)
+            tail = (plane.shape[5],)
         else:
             w = min(_LANES, p_sz)
             tail = ()
         zeros = (0,) * len(tail)
 
-        def where(i, pos_ref, *tbl_ref, w=w, zeros=zeros):
-            if not paged:
-                return (i, 0, lax.div(pos_ref[i], w)) + zeros
-            page = tbl_ref[0][i * mp + lax.div(pos_ref[i], p_sz)]
-            return (page, 0, lax.div(lax.rem(pos_ref[i], p_sz), w)) + zeros
+        def where(i, layer_ref, pos_ref, *tbl_ref, w=w, zeros=zeros):
+            row, col = i, pos_ref[i]
+            if paged:
+                row = tbl_ref[0][i * mp + lax.div(col, p_sz)]
+                col = lax.rem(col, p_sz)
+            return (layer_ref[0], 0, row, 0, lax.div(col, w)) + zeros
 
         new_specs.append(pl.BlockSpec(
-            (1, h, 1) + tail,
-            lambda i, *_, zeros=zeros: (i, 0, 0) + zeros))
-        plane_specs.append(pl.BlockSpec((1, h, w) + tail, where))
+            (2, 1, h, 1) + tail,
+            lambda i, *_, zeros=zeros: (0, i, 0, 0) + zeros))
+        plane_specs.append(pl.BlockSpec((None, 2, 1, h, w) + tail, where))
         windows.append(w)
-    scalars = [jnp.asarray(pos, jnp.int32)]
+    scalars = [_layer_scalar(layer), jnp.asarray(pos, jnp.int32)]
     if paged:
         scalars.append(jnp.asarray(table, jnp.int32).reshape(-1))
     n_scalar, n = len(scalars), len(planes)
@@ -171,48 +193,98 @@ def _write_column_planes(news, planes, pos, table=None):
         name="decode_attn_write",
         interpret=use_interpret(),
     )(*scalars,
-      *[jnp.expand_dims(new, 2).astype(plane.dtype)
+      *[jnp.expand_dims(new, 3).astype(plane.dtype)
         for new, plane in zip(news, planes)],
       *planes)
 
 
-def _write_columns_planes(news, planes, pos, table=None):
-    """The T-column write: ``news[k] [b, h, T(, d)]`` land at positions
-    ``pos[b] .. pos[b] + T - 1``, one :func:`_write_column_planes` pass
-    per lane (T is the tiny static draft width; two lanes of one row
-    usually share a window, so they cannot ride one pipelined grid).
-    Lanes past the row's horizon CLAMP onto its last position."""
-    p_sz = planes[0].shape[2]
+def _write_columns_planes(news, planes, layer, pos, table=None):
+    """The T-column write: ``news[k] [2, b, h, T(, d)]`` land at
+    positions ``pos[b] .. pos[b] + T - 1``, one
+    :func:`_write_column_planes` pass per lane (T is the tiny static
+    draft width; two lanes of one row usually share a window, so they
+    cannot ride one pipelined grid). Lanes past the row's horizon CLAMP
+    onto its last position."""
+    p_sz = planes[0].shape[4]
     smax = p_sz * table.shape[1] if table is not None else p_sz
     pos = jnp.asarray(pos, jnp.int32)
-    for j in range(news[0].shape[2]):
+    for j in range(news[0].shape[3]):
         planes = _write_column_planes(
-            [new[:, :, j] for new in news], planes,
+            [new[:, :, :, j] for new in news], planes, layer,
             jnp.minimum(pos + j, smax - 1), table)
     return planes
 
 
-def cache_write_columns(k_new, v_new, k_cache, v_cache, pos):
-    """Write ``k_new/v_new [b, h, T, d]`` into columns ``pos[b] .. pos[b]
-    + T - 1`` of the caches ``[b, h, S, d]`` — the T-column
-    generalisation of the one-column window write (the speculative
-    verify forward's cache landing, T = draft k + 1), with the caches
-    aliased input→output so only the touched windows move and the rest
-    of the cache stays in place.
+def _stack_news(k_new, v_new, kind):
+    """The incoming K and V rows as the write's operands: ``[kv_new]``,
+    or — ``kind`` a quantized storage — ``[kv_q, kv_scale]`` through
+    :func:`quantize_kv_rows`, the one deterministic quantizer."""
+    k_new, _ = widen_f16(k_new)
+    v_new, _ = widen_f16(v_new)
+    kv_new = jnp.stack([k_new, v_new])
+    if kind is None:
+        return [kv_new]
+    if kind not in KV_QMAX:
+        raise ValueError(f"unknown quantized-KV kind {kind!r}")
+    return list(quantize_kv_rows(kv_new, kind))
 
-    Columns past the horizon are CLAMPED onto ``S - 1``: a row whose
-    tail lanes overrun the cache end (a near-budget slot drafting past
-    its horizon, or a done slot's frozen lanes) smashes only the last
-    column. That can never corrupt an emitted token: a lane's draw is
-    only emitted when the row's remaining budget covers it, and the
-    engine bounds ``pos + remaining <= S - 1`` — so any lane whose
-    query would attend column ``S - 1`` (``pos + j = S - 1``) needs
-    ``remaining >= j + 1 = S - pos``, a contradiction. Column ``S - 1``
-    is therefore only ever read by discarded lanes, and only ever
-    holds a real token's K/V once the row is done (frozen done-row
-    writes) — the same masked-garbage contract every over-position
-    cache entry already lives under."""
-    return _write_columns_planes([k_new, v_new], [k_cache, v_cache], pos)
+
+def stacked_write_columns(k_new, v_new, cache, layer, pos, *, table=None,
+                          kind: Optional[str] = None):
+    """Write ``k_new/v_new [b, h, T, d]`` into columns ``pos[b] ..
+    pos[b] + T - 1`` of layer ``layer`` of the stacked cache ``[L, 2,
+    b, h, S, d]`` — or of the page pool ``[L, 2, num_pages, h, P, d]``
+    under ``table [b, max_pages]``; or, ``kind`` ``"int8"``/``"fp8"``,
+    of the quantized ``{"kv", "scale"}`` pair, each incoming row
+    quantized into one storage column plus one fp32 scale cell. The
+    cache is aliased input→output, so only the touched windows of that
+    one layer move (the speculative verify forward's cache landing, T =
+    draft k + 1). Returns the cache.
+
+    Columns past the horizon are CLAMPED onto the row's last column
+    ``S - 1``: a row whose tail lanes overrun the cache end (a
+    near-budget slot drafting past its horizon, or a done slot's frozen
+    lanes) smashes only the last column. That can never corrupt an
+    emitted token: a lane's draw is only emitted when the row's
+    remaining budget covers it, and the engine bounds ``pos + remaining
+    <= S - 1`` — so any lane whose query would attend column ``S - 1``
+    (``pos + j = S - 1``) needs ``remaining >= j + 1 = S - pos``, a
+    contradiction. Column ``S - 1`` is therefore only ever read by
+    discarded lanes, and only ever holds a real token's K/V once the
+    row is done (frozen done-row writes) — the same masked-garbage
+    contract every over-position cache entry already lives under."""
+    # [kv], or [kv, scale] of the quantized {"kv", "scale"} pair
+    planes, layout = jax.tree.flatten(cache)
+    planes = _write_columns_planes(_stack_news(k_new, v_new, kind), planes,
+                                   layer, pos, table)
+    return jax.tree.unflatten(layout, planes)
+
+
+def _one_layer(k, v, k_scale=None, v_scale=None):
+    """One layer's planes as a one-layer stacked cache — the per-layer
+    functions' way into the stacked kernels (a copy of both planes)."""
+    kv = jnp.stack([k, v])[None]
+    if k_scale is None:
+        return kv
+    return {"kv": kv, "scale": jnp.stack([k_scale, v_scale])[None]}
+
+
+def _layer_planes(cache):
+    """:func:`_one_layer` undone: ``(k, v)`` or ``(k, k_scale, v,
+    v_scale)``."""
+    if isinstance(cache, dict):
+        kv, sc = cache["kv"][0], cache["scale"][0]
+        return kv[0], sc[0], kv[1], sc[1]
+    return cache[0, 0], cache[0, 1]
+
+
+def cache_write_columns(k_new, v_new, k_cache, v_cache, pos):
+    """:func:`stacked_write_columns` for one layer's planes: ``k_new/
+    v_new [b, h, T, d]`` into columns ``pos[b] .. pos[b] + T - 1`` of
+    the caches ``[b, h, S, d]``, same clamped over-horizon contract.
+    Returns ``(k_cache, v_cache)``."""
+    return _layer_planes(stacked_write_columns(
+        k_new, v_new, _one_layer(k_cache, v_cache), 0, pos))
 
 
 def cache_write_columns_xla(cache, new, pos):
@@ -257,8 +329,8 @@ def cache_write_columns_quant(k_new, v_new, k_q, k_s, v_q, v_s, pos,
     lands one quantized column plus one fp32 scale column at ``pos[b] +
     j`` across all four planes; same clamped over-horizon contract as
     the plain variant."""
-    return _write_columns_planes(_quantize_pair(k_new, v_new, kind),
-                                 [k_q, k_s, v_q, v_s], pos)
+    return _layer_planes(stacked_write_columns(
+        k_new, v_new, _one_layer(k_q, v_q, k_s, v_s), 0, pos, kind=kind))
 
 
 # ---------------------------------------------------------------------------
@@ -269,7 +341,7 @@ def _attn_kernel(*refs, n_scalar, quant, scale, bk, smax):
     """Grid ``(b, h, chunks)``: one (batch, head) query row swept over
     its horizon in ``bk``-position chunks. ``quant`` adds the two fp32
     scale-row refs of the int8/fp8 layout."""
-    pos_ref = refs[0]
+    pos_ref = refs[1]
     if quant:
         (q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref,
          l_ref) = refs[n_scalar:]
@@ -335,47 +407,62 @@ def _attn_kernel(*refs, n_scalar, quant, scale, bk, smax):
                        ).astype(o_ref.dtype)
 
 
-def _run_attn(q, planes, pos, scale, *, bk, table=None):
-    """Sweep ``q [b, h, d]`` over ``planes``: ``(k, v)`` or the
-    quantized ``(k_q, k_s, v_q, v_s)``, contiguous ``[b, h, S(, d)]``
-    in ``bk``-position chunks or — ``table [b, max_pages]`` given —
-    page pools ``[num_pages, h, P(, d)]`` one page per chunk (``bk ==
-    P``; chunk ``j`` of row ``b`` streams page ``table[b, j]``)."""
+def _run_attn(q, planes, layer, pos, scale, *, bk, table=None):
+    """Sweep ``q [b, h, d]`` over layer ``layer`` of ``planes``:
+    ``[kv]`` or the quantized ``[kv, scale]``, stacked contiguous ``[L,
+    2, b, h, S(, d)]`` in ``bk``-position chunks or — ``table [b,
+    max_pages]`` given — page pools ``[L, 2, num_pages, h, P(, d)]``
+    one page per chunk (``bk == P``; chunk ``j`` of row ``b`` streams
+    page ``table[b, j]``). The K and the V chunk are blocks of plane 0
+    and plane 1 of the one ``kv`` operand, read where it lies.
+
+    The fp32 scales ride with positions on the lanes, ``[.., h, 1, S]``
+    — a relayout, not a reshape, on a tiled device layout — so that one
+    layer's scale planes (a sixteenth of its int8/fp8 bytes at head
+    size 64) are sliced out and relaid here; the storage planes are
+    not."""
     b, h, d = q.shape
-    quant = len(planes) == 4
+    kv = planes[0]
+    quant = len(planes) == 2
     paged = table is not None
     mp = table.shape[1] if paged else 0
-    smax = mp * bk if paged else planes[0].shape[2]
+    smax = mp * bk if paged else kv.shape[4]
 
-    def chunk(i, g, j, pos_ref, *tbl_ref):
-        # (leading, head, position-chunk) block index of chunk j
+    def chunk(i, j, tbl_ref):
+        # (leading, position-chunk) block index of chunk j of row i
         if paged:
-            return tbl_ref[0][i * mp + j], g, 0
-        return i, g, j
+            return tbl_ref[0][i * mp + j], 0
+        return i, j
 
-    def data_map(*args):
-        lead, g, c = chunk(*args)
-        return lead, g, c, 0
+    def data_map(plane):
+        def index(i, g, j, layer_ref, pos_ref, *tbl_ref):
+            lead, c = chunk(i, j, tbl_ref)
+            return layer_ref[0], plane, lead, g, c, 0
+        return index
 
-    def scale_map(*args):
-        # scale rows ride as [.., h, 1, S]: positions on the lanes
-        lead, g, c = chunk(*args)
-        return lead, g, 0, c
+    def scale_map(plane):
+        def index(i, g, j, layer_ref, pos_ref, *tbl_ref):
+            lead, c = chunk(i, j, tbl_ref)
+            return plane, lead, g, 0, c
+        return index
 
     row_spec = pl.BlockSpec((1, 1, 1, d), lambda i, g, j, *_: (i, g, 0, 0))
-    data_spec = pl.BlockSpec((1, 1, bk, d), data_map)
-    scale_spec = pl.BlockSpec((1, 1, 1, bk), scale_map)
-    scalars = [pos]
+    operands, specs = [], []
+    if quant:
+        scale_rows = jnp.expand_dims(lax.dynamic_index_in_dim(
+            planes[1], jnp.asarray(layer, jnp.int32), 0, keepdims=False),
+            3)                                   # [2, rows, h, 1, S]
+    for plane in (0, 1):
+        operands.append(kv)
+        specs.append(pl.BlockSpec((None, None, 1, 1, bk, d),
+                                  data_map(plane)))
+        if quant:
+            operands.append(scale_rows)
+            specs.append(pl.BlockSpec((None, 1, 1, 1, bk),
+                                      scale_map(plane)))
+    scalars = [_layer_scalar(layer), pos]
     if paged:
         scalars.append(jnp.asarray(table, jnp.int32).reshape(-1))
-    if quant:
-        k_q, k_s, v_q, v_s = planes
-        operands = [k_q, jnp.expand_dims(k_s, 2), v_q,
-                    jnp.expand_dims(v_s, 2)]
-        specs = [data_spec, scale_spec, data_spec, scale_spec]
-    else:
-        operands = list(planes)
-        specs = [data_spec, data_spec]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b, h, mp if paged else -(-smax // bk)),
@@ -402,56 +489,101 @@ def _run_attn(q, planes, pos, scale, *, bk, table=None):
 # public API
 # ---------------------------------------------------------------------------
 
-def decode_attention(q, k_new, v_new, k_cache, v_cache, pos, *,
-                     scale: Optional[float] = None,
-                     block_k: Optional[int] = None):
-    """One decode step of attention for every (batch, head) row.
+def stacked_decode_attention(q, k_new, v_new, cache, layer, pos, *,
+                             table=None, kind: Optional[str] = None,
+                             scale: Optional[float] = None,
+                             block_k: Optional[int] = None):
+    """One decode step of attention for every (batch, head) row, in
+    layer ``layer`` of the stacked cache — the form the model's layer
+    scan calls with the cache in its carry.
 
     ``q``/``k_new``/``v_new`` are ``[b, h, d]`` (this token's projected
-    query and cache entries), ``k_cache``/``v_cache`` ``[b, h, S, d]``,
-    ``pos`` ``[b] int32`` — each row's write/attend position (``0 <=
-    pos[i] < S``; ``gpt.decode_step`` guarantees this by freezing done
-    slots). Returns ``(out [b, h, d], k_cache, v_cache)`` where the
-    caches hold the new column at ``pos`` (written in place when XLA
-    honours the alias — inside the donated decode scan it does) and
-    ``out`` attends over positions ``0..pos[i]`` inclusive, bit-exactly
-    masked like the XLA path: rows past ``pos`` are exact softmax
-    zeros, so stale cache garbage never leaks into the output.
+    query and cache entries); ``cache`` is ``[L, 2, b, h, S, d]``, or
+    the page pool ``[L, 2, num_pages, h, P, d]`` under ``table [b,
+    max_pages] int32``, or — ``kind`` ``"int8"``/``"fp8"`` — the
+    quantized ``{"kv": storage, "scale": fp32 [..., S]}`` pair of
+    either; ``layer`` an int32 scalar (traced or not); ``pos`` ``[b]
+    int32`` each row's write/attend position (``0 <= pos[i] < S``;
+    ``gpt.decode_step`` guarantees this by freezing done slots).
+    Returns ``(out [b, h, d], cache)``.
+
+    The cache holds the new column at ``(layer, pos)``: the write
+    kernel aliases the whole stacked cache input→output and moves only
+    the ``b`` windows it touches, so a caller that owns the buffer (a
+    scan carry) keeps it in place; a caller that still holds the input
+    pays XLA's copy of all of it. ``out`` attends over positions
+    ``0..pos[i]`` inclusive, bit-exactly masked like the XLA path: rows
+    past ``pos`` are exact softmax zeros, so stale cache garbage — NaN
+    bit patterns of stale quantized cells included — never leaks into
+    the output. Under a quantized layout the incoming rows are
+    quantized (:func:`quantize_kv_rows` — bit-identical to the XLA
+    fallback and bulk prefill) and the split-K sweep reads the narrow
+    cache and folds the scales into the fp32 scores/probabilities per
+    chunk, so the steady-decode HBM read traffic shrinks with the
+    storage width.
 
     ``scale`` defaults to ``1/sqrt(d)`` and is applied to the fp32
     scores (no overflow at any IO dtype — the XLA path instead folds it
     into q in compute dtype, the fp16-range guard a fp32-accumulating
-    kernel doesn't need).
+    kernel doesn't need). A float16-stored cache is widened to fp32
+    and narrowed back WHOLE (Mosaic has no f16): correct, and never the
+    fast path — ``gpt._decode_attn_impl`` keeps such caches on XLA.
     """
+    # [kv], or [kv, scale] of the quantized {"kv", "scale"} pair
+    planes, layout = jax.tree.flatten(cache)
+    kv = planes[0]
+    b, h, d = q.shape
+    if kv.ndim != 6 or kv.shape[1] != 2 or kv.shape[3::2] != (h, d):
+        raise ValueError(
+            f"expected q [b, h, d] and a stacked cache [L, 2, rows, h, "
+            f"S, d], got {q.shape} / {kv.shape}")
+    if table is None and kv.shape[2] != b:
+        raise ValueError(
+            f"cache shape {kv.shape} inconsistent with q {q.shape}")
+    if pos.shape != (b,):
+        raise ValueError(f"pos must be [{b}], got {pos.shape}")
+    s = float(scale) if scale is not None else 1.0 / d ** 0.5
+    q, was16 = widen_f16(q)
+    cache16 = kv.dtype == jnp.float16
+    planes[0] = kv = widen_f16(kv)[0]
+    pos = jnp.asarray(pos, jnp.int32)
+    planes = list(_write_column_planes(
+        _stack_news(k_new, v_new, kind), planes, layer, pos, table))
+    if table is not None:
+        bk = kv.shape[4]
+    else:
+        # fp32 scale rows put the chunk on the lane dimension too
+        bk = _fit_block_k(block_k or _DEFAULT_BLOCK_K, kv.shape[4],
+                          _LANES if kind else _sublane_tile(kv.dtype))
+    out = _run_attn(q, planes, layer, pos, s, bk=bk, table=table)
+    if was16:
+        out = out.astype(jnp.float16)
+    if cache16:
+        planes[0] = planes[0].astype(jnp.float16)
+    return out, jax.tree.unflatten(layout, planes)
+
+
+def decode_attention(q, k_new, v_new, k_cache, v_cache, pos, *,
+                     scale: Optional[float] = None,
+                     block_k: Optional[int] = None):
+    """:func:`stacked_decode_attention` for one layer's planes:
+    ``k_cache``/``v_cache`` ``[b, h, S, d]``. Returns ``(out [b, h, d],
+    k_cache, v_cache)`` with the new column at ``pos``. The two planes
+    are stacked into a one-layer cache on the way in — a copy of both;
+    a caller that wants the column landed in place hands the stacked
+    form its whole cache."""
     if q.ndim != 3 or k_cache.ndim != 4:
         raise ValueError(
             f"expected q [b, h, d] and caches [b, h, S, d], got "
             f"{q.shape} / {k_cache.shape}")
     b, h, d = q.shape
-    sk = k_cache.shape[2]
-    if k_cache.shape != (b, h, sk, d):
+    if k_cache.shape != (b, h, k_cache.shape[2], d):
         raise ValueError(
             f"cache shape {k_cache.shape} inconsistent with q {q.shape}")
-    if pos.shape != (b,):
-        raise ValueError(f"pos must be [{b}], got {pos.shape}")
-    s = float(scale) if scale is not None else 1.0 / d ** 0.5
-    q, was16 = widen_f16(q)
-    k_new, _ = widen_f16(k_new)
-    v_new, _ = widen_f16(v_new)
-    k_cache, cache16 = widen_f16(k_cache)
-    v_cache, _ = widen_f16(v_cache)
-    pos = jnp.asarray(pos, jnp.int32)
-    k_cache, v_cache = _write_column_planes(
-        [k_new, v_new], [k_cache, v_cache], pos)
-    bk = _fit_block_k(block_k or _DEFAULT_BLOCK_K, sk,
-                      _sublane_tile(k_cache.dtype))
-    out = _run_attn(q, (k_cache, v_cache), pos, s, bk=bk)
-    if was16:
-        out = out.astype(jnp.float16)
-    if cache16:
-        k_cache = k_cache.astype(jnp.float16)
-        v_cache = v_cache.astype(jnp.float16)
-    return out, k_cache, v_cache
+    out, cache = stacked_decode_attention(
+        q, k_new, v_new, _one_layer(k_cache, v_cache), 0, pos,
+        scale=scale, block_k=block_k)
+    return (out, *_layer_planes(cache))
 
 
 # ---------------------------------------------------------------------------
@@ -497,12 +629,6 @@ def quantize_kv_rows(x, kind: str):
     else:
         q = jnp.clip(y, -qmax, qmax).astype(jnp.float8_e4m3fn)
     return q, scale
-
-
-def _quantize_pair(k_new, v_new, kind: str):
-    """``[k_q, k_scale, v_q, v_scale]`` of the incoming K/V rows — the
-    plane order of every quantized write."""
-    return [*quantize_kv_rows(k_new, kind), *quantize_kv_rows(v_new, kind)]
 
 
 # ---------------------------------------------------------------------------
@@ -608,11 +734,10 @@ def paged_write_columns_xla(plane, new, table, pos):
 def paged_write_column(k_new, v_new, k_pool, v_pool, table, pos):
     """Write ``k_new/v_new [b, h, d]`` into logical column ``pos[b]``
     of the paged pools ``[num_pages, h, P, d]`` under ``table [b,
-    max_pages]`` — the paged :func:`_write_column`: the cell is
-    ``(table[b, pos // P], pos % P)``, both pools aliased input→output
-    so only the b touched windows move."""
-    return _write_column_planes([k_new, v_new], [k_pool, v_pool], pos,
-                                table)
+    max_pages]``: the cell is ``(table[b, pos // P], pos % P)``.
+    Returns ``(k_pool, v_pool)``."""
+    return paged_write_columns(k_new[:, :, None], v_new[:, :, None],
+                               k_pool, v_pool, table, pos)
 
 
 def paged_write_column_quant(k_new, v_new, k_q, k_s, v_q, v_s, table,
@@ -623,8 +748,9 @@ def paged_write_column_quant(k_new, v_new, k_q, k_s, v_q, v_s, table,
     — the one deterministic quantizer) and land one quantized + one
     scale cell at ``(table[b, pos // P], pos % P)`` across all four
     planes."""
-    return _write_column_planes(_quantize_pair(k_new, v_new, kind),
-                                [k_q, k_s, v_q, v_s], pos, table)
+    return paged_write_columns_quant(
+        k_new[:, :, None], v_new[:, :, None], k_q, k_s, v_q, v_s, table,
+        pos, kind)
 
 
 def paged_write_columns(k_new, v_new, k_pool, v_pool, table, pos):
@@ -634,8 +760,8 @@ def paged_write_columns(k_new, v_new, k_pool, v_pool, table, pos):
     landing). Over-horizon lanes CLAMP onto the row's last logical
     column ``max_pages * P - 1`` (the contiguous kernel's contract —
     that cell is only ever read by discarded lanes)."""
-    return _write_columns_planes([k_new, v_new], [k_pool, v_pool], pos,
-                                 table)
+    return _layer_planes(stacked_write_columns(
+        k_new, v_new, _one_layer(k_pool, v_pool), 0, pos, table=table))
 
 
 def paged_write_columns_quant(k_new, v_new, k_q, k_s, v_q, v_s, table,
@@ -643,8 +769,21 @@ def paged_write_columns_quant(k_new, v_new, k_q, k_s, v_q, v_s, table,
     """:func:`paged_write_columns` over the quantized pool layout:
     each incoming row is quantized and lands one quantized + one scale
     cell per lane; same clamped over-horizon contract."""
-    return _write_columns_planes(_quantize_pair(k_new, v_new, kind),
-                                 [k_q, k_s, v_q, v_s], pos, table)
+    return _layer_planes(stacked_write_columns(
+        k_new, v_new, _one_layer(k_q, v_q, k_s, v_s), 0, pos,
+        table=table, kind=kind))
+
+
+def _paged_read(q, cache, table, pos, scale):
+    """The split-K sweep alone, over a one-layer page pool."""
+    d = q.shape[2]
+    s = float(scale) if scale is not None else 1.0 / d ** 0.5
+    q, was16 = widen_f16(q)
+    planes = jax.tree.leaves(cache)
+    planes[0], _ = widen_f16(planes[0])
+    out = _run_attn(q, planes, 0, jnp.asarray(pos, jnp.int32), s,
+                    bk=planes[0].shape[4], table=table)
+    return out.astype(jnp.float16) if was16 else out
 
 
 def paged_attention(q, k_pool, v_pool, table, pos, *,
@@ -655,18 +794,9 @@ def paged_attention(q, k_pool, v_pool, table, pos, *,
     sweep streams page ``table[b, j]`` (the scalar-prefetched remap of
     the contiguous chunk index). Returns ``out [b, h, d]`` attending
     columns ``0..pos[b]`` with the contiguous kernel's exact masking
-    contract; the write is separate (:func:`paged_write_column`) so
-    the engine can schedule it against the same dispatch."""
-    d = q.shape[2]
-    s = float(scale) if scale is not None else 1.0 / d ** 0.5
-    q, was16 = widen_f16(q)
-    k_pool, _ = widen_f16(k_pool)
-    v_pool, _ = widen_f16(v_pool)
-    out = _run_attn(q, (k_pool, v_pool), jnp.asarray(pos, jnp.int32), s,
-                    bk=k_pool.shape[2], table=table)
-    if was16:
-        out = out.astype(jnp.float16)
-    return out
+    contract; the read alone, for a column that
+    :func:`paged_write_column` has landed."""
+    return _paged_read(q, _one_layer(k_pool, v_pool), table, pos, scale)
 
 
 def paged_attention_quantized(q, k_q, k_s, v_q, v_s, table, pos, *,
@@ -678,14 +808,8 @@ def paged_attention_quantized(q, k_q, k_s, v_q, v_s, table, pos, *,
     exactly like the contiguous quantized sweep."""
     if kind not in KV_QMAX:
         raise ValueError(f"unknown quantized-KV kind {kind!r}")
-    d = q.shape[2]
-    s = float(scale) if scale is not None else 1.0 / d ** 0.5
-    q, was16 = widen_f16(q)
-    out = _run_attn(q, (k_q, k_s, v_q, v_s), jnp.asarray(pos, jnp.int32),
-                    s, bk=k_q.shape[2], table=table)
-    if was16:
-        out = out.astype(jnp.float16)
-    return out
+    return _paged_read(q, _one_layer(k_q, v_q, k_s, v_s), table, pos,
+                       scale)
 
 
 def decode_attention_quantized(q, k_new, v_new, k_q, k_scale, v_q,
@@ -694,17 +818,9 @@ def decode_attention_quantized(q, k_new, v_new, k_q, k_scale, v_q,
                                block_k: Optional[int] = None):
     """:func:`decode_attention` over the quantized cache layout: K/V
     stored as ``kind`` (``"int8"``/``"fp8"``) ``[b, h, S, d]`` with
-    per-head, per-slot, per-position fp32 scales ``[b, h, S]``. The
-    incoming ``k_new``/``v_new [b, h, d]`` rows are quantized
-    (:func:`quantize_kv_rows` — bit-identical to the XLA fallback and
-    bulk prefill) and written as one quantized + one scale column at
-    each row's ``pos``; the split-K sweep reads the narrow cache and
-    folds the scales into the fp32 scores/probabilities per chunk, so
-    the steady-decode HBM read traffic shrinks with the storage width.
+    per-head, per-slot, per-position fp32 scales ``[b, h, S]``.
     Returns ``(out [b, h, d], k_q, k_scale, v_q, v_scale)``; masking
-    semantics identical to :func:`decode_attention` (positions past a
-    row's ``pos`` are exact softmax zeros — stale quantized garbage,
-    NaN bit patterns included, never leaks)."""
+    and quantization as :func:`stacked_decode_attention` states them."""
     if q.ndim != 3 or k_q.ndim != 4:
         raise ValueError(
             f"expected q [b, h, d] and quantized caches [b, h, S, d], "
@@ -715,19 +831,9 @@ def decode_attention_quantized(q, k_new, v_new, k_q, k_scale, v_q,
         raise ValueError(
             f"cache shapes {k_q.shape} / {k_scale.shape} inconsistent "
             f"with q {q.shape}")
-    if pos.shape != (b,):
-        raise ValueError(f"pos must be [{b}], got {pos.shape}")
     if kind not in KV_QMAX:
         raise ValueError(f"unknown quantized-KV kind {kind!r}")
-    s = float(scale) if scale is not None else 1.0 / d ** 0.5
-    q, was16 = widen_f16(q)
-    pos = jnp.asarray(pos, jnp.int32)
-    k_q, k_scale, v_q, v_scale = _write_column_planes(
-        _quantize_pair(k_new, v_new, kind),
-        [k_q, k_scale, v_q, v_scale], pos)
-    # the fp32 scale rows put the chunk on the lane dimension too
-    bk = _fit_block_k(block_k or _DEFAULT_BLOCK_K, sk, _LANES)
-    out = _run_attn(q, (k_q, k_scale, v_q, v_scale), pos, s, bk=bk)
-    if was16:
-        out = out.astype(jnp.float16)
-    return out, k_q, k_scale, v_q, v_scale
+    out, cache = stacked_decode_attention(
+        q, k_new, v_new, _one_layer(k_q, v_q, k_scale, v_scale), 0, pos,
+        kind=kind, scale=scale, block_k=block_k)
+    return (out, *_layer_planes(cache))

@@ -40,26 +40,18 @@ from jax.sharding import PartitionSpec as P
 import numpy as np
 
 from apex_tpu.kernels import (
-    decode_attention,
-    decode_attention_quantized,
     flash_attention,
     flash_attention_bsh,
     layer_norm,
 )
 from apex_tpu.kernels.decode_attention import (
-    cache_write_columns as _cache_write_columns,
-    cache_write_columns_quant as _cache_write_columns_quant,
     cache_write_columns_xla as _cache_write_columns_xla,
     kv_storage_dtype as _kv_storage_dtype,
-    paged_attention as _paged_attention,
-    paged_attention_quantized as _paged_attention_quantized,
     paged_gather_xla as _paged_gather_xla,
-    paged_write_column as _paged_write_column,
-    paged_write_column_quant as _paged_write_column_quant,
-    paged_write_columns as _paged_write_columns,
-    paged_write_columns_quant as _paged_write_columns_quant,
     paged_write_columns_xla as _paged_write_columns_xla,
     quantize_kv_rows as _quantize_kv_rows_impl,
+    stacked_decode_attention as _stacked_decode_attention,
+    stacked_write_columns as _stacked_write_columns,
 )
 from apex_tpu.kernels.blockwise_attention import blockwise_attention
 from apex_tpu.mesh.topology import AXIS_CP, AXIS_DP, AXIS_EP, AXIS_PP, AXIS_TP
@@ -1277,7 +1269,10 @@ def init_cache(cfg: GPTConfig, params, batch: int,
     ``compute_dtype`` — or, under a quantized ``cfg.kv_cache_dtype``,
     the ``{"kv": int8/fp8 [same shape], "scale": fp32 [..., max_len]}``
     pytree (every cache consumer is pytree-agnostic; see
-    :func:`cache_specs` for the matching PartitionSpecs)."""
+    :func:`cache_specs` for the matching PartitionSpecs). All layers
+    are ONE array: :func:`decode_step` carries it whole through its
+    layer scan and the decode kernels address it by layer index, so a
+    decoded token moves its own column's windows and nothing else."""
     qkv_k = params["layers"]["attn"]["qkv"]["kernel"]  # [L, h, 3, hl]
     l_local = qkv_k.shape[0]
     heads_local = qkv_k.shape[-1] // cfg.head_dim
@@ -1335,52 +1330,64 @@ def _decode_attn_impl(cfg: GPTConfig, s_max: int) -> str:
 
 
 @jax.named_scope("apex.decode.cache_slice")
-def _cache_planes(kv, quant: bool):
-    """One layer's cache taken apart: ``(k, v, k_scale, v_scale)``, the
-    scales None unless ``kv`` is the quantized ``{"kv", "scale"}``
-    pytree."""
+def _cache_planes(cache, layer, quant: bool):
+    """Layer ``layer`` of the stacked cache sliced out and taken apart:
+    ``(k, v, k_scale, v_scale)``, the scales None unless ``cache`` is
+    the quantized ``{"kv", "scale"}`` pytree. The XLA fallback's way
+    in (and the verify forward's materialised read); the decode kernels
+    address the stacked cache by layer and never call this."""
+    take = lambda c: lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
     if quant:
-        return kv["kv"][0], kv["kv"][1], kv["scale"][0], kv["scale"][1]
+        kv, scale = take(cache["kv"]), take(cache["scale"])
+        return kv[0], kv[1], scale[0], scale[1]
+    kv = take(cache)
     return kv[0], kv[1], None, None
 
 
 @jax.named_scope("apex.decode.cache_stack")
-def _stack_planes(k, v, k_scale=None, v_scale=None):
-    """:func:`_cache_planes` undone: the layer's cache in the layout it
-    came in."""
-    kv = jnp.stack([k, v])
+def _stack_planes(cache, layer, k, v, k_scale=None, v_scale=None):
+    """:func:`_cache_planes` undone: the rewritten planes put back as
+    layer ``layer`` of the stacked cache (XLA fallback only)."""
+    put = lambda c, k, v: lax.dynamic_update_index_in_dim(
+        c, jnp.stack([k, v]), layer, 0)
     if k_scale is None:
-        return kv
-    return {"kv": kv, "scale": jnp.stack([k_scale, v_scale])}
+        return put(cache, k, v)
+    return {"kv": put(cache["kv"], k, v),
+            "scale": put(cache["scale"], k_scale, v_scale)}
 
 
-def _decode_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos):
+def _layer_indices(cache):
+    """``0 .. L_local - 1``: the layer scan's index into the stacked
+    cache it carries."""
+    return jnp.arange(jax.tree.leaves(cache)[0].shape[0], dtype=jnp.int32)
+
+
+def _decode_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos):
     """The decode-attention core shared by both cache layouts: write
-    this token's K/V at ``pos`` and attend ``q`` over ``0..pos`` —
-    returns ``(ctx [b, heads, d], new_kv)`` with ``new_kv`` in the
-    SAME layout ``kv`` came in (array ``[2, b, hl, S, d]``, or the
-    quantized ``{"kv", "scale"}`` pytree). Dispatches on
-    :func:`_decode_attn_impl`; under a quantized layout the kernel
-    path quantizes the incoming row and folds the scales in per split-K
-    chunk, while the XLA fallback quantizes/one-hot-writes both planes
-    and dequantizes the materialised cache before the score einsum
-    (same semantics, CPU-testable)."""
+    this token's K/V at ``pos`` of layer ``layer`` and attend ``q``
+    over ``0..pos`` — returns ``(ctx [b, heads, d], cache)`` with
+    ``cache`` the whole stacked cache in the layout it came in (array
+    ``[L, 2, b, hl, S, d]``, or the quantized ``{"kv", "scale"}``
+    pytree). Dispatches on :func:`_decode_attn_impl`. The kernel path
+    hands the kernels the stacked cache and the layer index: the column
+    lands in place and no layer is sliced out or stacked back; under a
+    quantized layout it quantizes the incoming row and folds the scales
+    in per split-K chunk. The XLA fallback slices the layer out
+    (:func:`_cache_planes`), quantizes/one-hot-writes both planes,
+    dequantizes the materialised cache before the score einsum, and
+    puts the layer back (:func:`_stack_planes`) — same semantics,
+    CPU-testable."""
     b, heads, d = q.shape
     kind = _kv_cache_dtype(cfg)
     quant = kind != "compute"
-    k_in, v_in, ks_in, vs_in = _cache_planes(kv, quant)
-    s_max = k_in.shape[2]
+    s_max = jax.tree.leaves(cache)[0].shape[4]
     if _decode_attn_impl(cfg, s_max) == "kernel":
         posv = (jnp.full((b,), pos, jnp.int32) if pos.ndim == 0
                 else pos)
-        if quant:
-            ctx, kq, ks, vq, vs = decode_attention_quantized(
-                q, k_new, v_new, k_in, ks_in, v_in, vs_in, posv,
-                scale=1.0 / np.sqrt(d), kind=kind)
-            return ctx, _stack_planes(kq, vq, ks, vs)
-        ctx, k_cache, v_cache = decode_attention(
-            q, k_new, v_new, k_in, v_in, posv, scale=1.0 / np.sqrt(d))
-        return ctx, _stack_planes(k_cache, v_cache)
+        return _stacked_decode_attention(
+            q, k_new, v_new, cache, layer, posv,
+            kind=kind if quant else None, scale=1.0 / np.sqrt(d))
+    k_in, v_in, ks_in, vs_in = _cache_planes(cache, layer, quant)
     if quant:
         # quantize the incoming rows ONCE (bit-identical to the kernel
         # and prefill quantizers), then write both planes
@@ -1402,14 +1409,15 @@ def _decode_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos):
     if quant:
         k_scale = upd(ks_in, k_s)
         v_scale = upd(vs_in, v_s)
-        new_kv = _stack_planes(k_cache, v_cache, k_scale, v_scale)
+        cache = _stack_planes(cache, layer, k_cache, v_cache, k_scale,
+                              v_scale)
         # dequantize for the materialised-scores read (semantically the
         # per-chunk dequant the kernel does in VMEM; off-TPU this is
         # the correctness backbone, not the fast path)
         k_cache = dequantize_kv(k_cache, k_scale, cfg.compute_dtype)
         v_cache = dequantize_kv(v_cache, v_scale, cfg.compute_dtype)
     else:
-        new_kv = _stack_planes(k_cache, v_cache)
+        cache = _stack_planes(cache, layer, k_cache, v_cache)
     # scale folded into q BEFORE the einsum: the unscaled dot
     # product overflows fp16's 65504 range (same guard as the
     # training path's compute-dtype branch). Keep in lockstep with
@@ -1420,44 +1428,35 @@ def _decode_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos):
         "bhd,bhsd->bhs", q, k_cache).astype(jnp.float32)
     scores = jnp.where(valid, scores, -1e30)
     p_attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhs,bhsd->bhd", p_attn, v_cache), new_kv
+    return jnp.einsum("bhs,bhsd->bhd", p_attn, v_cache), cache
 
 
-def _paged_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos, table):
-    """:func:`_decode_attend` over the PAGED cache layout: ``kv`` is
-    the per-layer page-pool slice (``[2, num_pages, hl, P, d]`` array,
-    or the quantized ``{"kv", "scale"}`` pytree of the same family)
-    and ``table [b, max_pages] int32`` maps each row's logical horizon
-    chunk onto a physical page. The write lands at ``(table[b, pos //
-    P], pos % P)``; the read sweeps the remapped pages. Under the
-    kernel impl both ride scalar-prefetched index maps
-    (:func:`apex_tpu.kernels.paged_attention`); the XLA fallback
-    writes through the one-hot page scatter and GATHERS the row-
-    contiguous view, then applies the EXACT contiguous score
-    expression — same bytes, same einsum shapes, so a paged row's
-    logits are bit-identical to the contiguous cache's (the paged ==
-    contiguous stream oracle)."""
+def _paged_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos,
+                  table):
+    """:func:`_decode_attend` over the PAGED cache layout: ``cache`` is
+    the stacked page pool (``[L, 2, num_pages, hl, P, d]`` array, or
+    the quantized ``{"kv", "scale"}`` pytree of the same family) and
+    ``table [b, max_pages] int32`` maps each row's logical horizon
+    chunk onto a physical page. The write lands at ``(layer, table[b,
+    pos // P], pos % P)``; the read sweeps the remapped pages of that
+    layer. Under the kernel impl both ride scalar-prefetched index maps
+    on the stacked pool, in place
+    (:func:`apex_tpu.kernels.stacked_decode_attention`); the XLA
+    fallback slices the layer's pool out, writes through the one-hot
+    page scatter and GATHERS the row-contiguous view, then applies the
+    EXACT contiguous score expression — same bytes, same einsum shapes,
+    so a paged row's logits are bit-identical to the contiguous cache's
+    (the paged == contiguous stream oracle)."""
     b, heads, d = q.shape
     kind = _kv_cache_dtype(cfg)
     quant = kind != "compute"
-    k_in, v_in, ks_in, vs_in = _cache_planes(kv, quant)
-    p_sz = k_in.shape[2]
-    s_max = table.shape[1] * p_sz
+    s_max = table.shape[1] * jax.tree.leaves(cache)[0].shape[4]
     posv = (jnp.full((b,), pos, jnp.int32) if pos.ndim == 0 else pos)
     if _decode_attn_impl(cfg, s_max) == "kernel":
-        if quant:
-            kq, ks, vq, vs = _paged_write_column_quant(
-                k_new, v_new, k_in, ks_in, v_in, vs_in, table, posv,
-                kind)
-            ctx = _paged_attention_quantized(
-                q, kq, ks, vq, vs, table, posv, kind=kind,
-                scale=1.0 / np.sqrt(d))
-            return ctx, _stack_planes(kq, vq, ks, vs)
-        kp, vp = _paged_write_column(k_new, v_new, k_in, v_in, table,
-                                     posv)
-        ctx = _paged_attention(q, kp, vp, table, posv,
-                               scale=1.0 / np.sqrt(d))
-        return ctx, _stack_planes(kp, vp)
+        return _stacked_decode_attention(
+            q, k_new, v_new, cache, layer, posv, table=table,
+            kind=kind if quant else None, scale=1.0 / np.sqrt(d))
+    k_in, v_in, ks_in, vs_in = _cache_planes(cache, layer, quant)
     if quant:
         k_new, k_s = quantize_kv_rows(k_new, kind)
         v_new, v_s = quantize_kv_rows(v_new, kind)
@@ -1468,7 +1467,7 @@ def _paged_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos, table):
                                        posv)
         vsp = _paged_write_columns_xla(vs_in, v_s[:, :, None], table,
                                        posv)
-        new_kv = _stack_planes(kp, vp, ksp, vsp)
+        cache = _stack_planes(cache, layer, kp, vp, ksp, vsp)
         k_cache = dequantize_kv(_paged_gather_xla(kp, table),
                                 _paged_gather_xla(ksp, table),
                                 cfg.compute_dtype)
@@ -1476,7 +1475,7 @@ def _paged_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos, table):
                                 _paged_gather_xla(vsp, table),
                                 cfg.compute_dtype)
     else:
-        new_kv = _stack_planes(kp, vp)
+        cache = _stack_planes(cache, layer, kp, vp)
         k_cache = _paged_gather_xla(kp, table)
         v_cache = _paged_gather_xla(vp, table)
     valid = (jnp.arange(s_max)[None] <= posv[:, None])[:, None]
@@ -1486,26 +1485,28 @@ def _paged_attend(cfg: GPTConfig, q, k_new, v_new, kv, pos, table):
         "bhd,bhsd->bhs", q, k_cache).astype(jnp.float32)
     scores = jnp.where(valid, scores, -1e30)
     p_attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhs,bhsd->bhd", p_attn, v_cache), new_kv
+    return jnp.einsum("bhs,bhsd->bhd", p_attn, v_cache), cache
 
 
-def _decode_layer(cfg: GPTConfig, p, x, kv, pos, table=None,
+def _decode_layer(cfg: GPTConfig, p, x, cache, layer, pos, table=None,
                   lora=None):
-    """One layer for one token: x [b, hidden], kv [2, b, hl, S, d] (or
-    the quantized ``{"kv", "scale"}`` pytree of the same shape family;
-    under a paged cache — ``table`` given — the per-layer page-pool
-    slice ``[2, num_pages, hl, P, d]``).
+    """Layer ``layer`` for one token: x [b, hidden] against the WHOLE
+    stacked cache ``[L, 2, b, hl, S, d]`` (or the quantized ``{"kv",
+    "scale"}`` pytree of the same shape family; under a paged cache —
+    ``table`` given — the stacked page pool ``[L, 2, num_pages, hl, P,
+    d]``); ``p`` is that layer's parameters. Returns ``(x, cache)``.
 
     ``pos`` is the write/attend position — a scalar (whole batch at one
     position: generate/beam) or a ``[b]`` vector (per-slot positions:
     the continuous-batching engine). The two forms are value-identical
     per row. Attention dispatches on :func:`_decode_attn_impl`: the
-    Pallas flash-decode kernel writes the new K/V column in place and
-    sweeps the horizon with an online (out, lse) merge, while the XLA
-    path writes by one-hot select under vector ``pos`` (a batched
-    ``dynamic_update_slice`` at per-row offsets is not expressible —
-    the full-cache rewrite the kernel exists to remove) and masks per
-    row."""
+    Pallas flash-decode kernels take the stacked cache and the layer
+    index, write the new K/V column in place and sweep the layer's
+    horizon with an online (out, lse) merge, while the XLA path slices
+    the layer out, writes by one-hot select under vector ``pos`` (a
+    batched ``dynamic_update_slice`` at per-row offsets is not
+    expressible — the full-cache rewrite the kernel exists to remove),
+    masks per row and puts the layer back."""
     with jax.named_scope("apex.attn"):
         xa = _layer_norm(cfg, x, p["ln1"]["scale"], p["ln1"]["bias"])
         d = cfg.head_dim
@@ -1517,11 +1518,11 @@ def _decode_layer(cfg: GPTConfig, p, x, kv, pos, table=None,
             for t in _qkv_project(cfg, p["attn"]["qkv"], xa, lora=lq))
         with jax.named_scope("apex.decode.attn"):
             if table is None:
-                ctx, new_kv = _decode_attend(cfg, q, k_new, v_new, kv,
-                                             pos)
+                ctx, cache = _decode_attend(cfg, q, k_new, v_new, cache,
+                                            layer, pos)
             else:
-                ctx, new_kv = _paged_attend(cfg, q, k_new, v_new, kv,
-                                            pos, table)
+                ctx, cache = _paged_attend(cfg, q, k_new, v_new, cache,
+                                           layer, pos, table)
         out = ctx.reshape(b, hl)
         attn = row_parallel_linear(
             out, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
@@ -1538,7 +1539,7 @@ def _decode_layer(cfg: GPTConfig, p, x, kv, pos, table=None,
             y, _ = moe_mod.moe_ffn(_moe_cfg(cfg), p["moe"], xb)  # aux unused
         else:
             y = _mlp(cfg, p["mlp"], xb, lora=lora)
-        return x + y, new_kv
+        return x + y, cache
 
 
 @jax.named_scope("apex.lm_head")
@@ -1604,30 +1605,24 @@ def decode_step(cfg: GPTConfig, params, cache, token, pos, table=None,
         x = (emb[:, 0] + pos_e.astype(cfg.compute_dtype)).astype(
             cfg.compute_dtype)
 
-    if lora is None:
-        def body(carry, inp):
-            layer_p, kv = inp
-            y, kv = _decode_layer(cfg, _cast_layer(cfg, layer_p), carry,
-                                  kv, pos, table)
-            return y, kv
+    pool, ids, scale = lora if lora is not None else (None, None, None)
 
-        xs = (params["layers"], cache)
-    else:
-        pool, ids, scale = lora
+    def body(carry, inp):
+        x, cache = carry
+        layer_p, layer, page = inp
+        return _decode_layer(
+            cfg, _cast_layer(cfg, layer_p), x, cache, layer, pos, table,
+            lora=None if page is None else (page, ids, scale)), None
 
-        def body(carry, inp):
-            layer_p, kv, page = inp
-            y, kv = _decode_layer(cfg, _cast_layer(cfg, layer_p), carry,
-                                  kv, pos, table,
-                                  lora=(page, ids, scale))
-            return y, kv
-
-        xs = (params["layers"], cache, pool)
-    # the scan's own slicing of the stacked params and cache, and the
-    # stacking of the layers' caches it returns, carry this scope alone
+    # the cache rides the scan's CARRY, whole: the kernels address it
+    # by layer index and write in place, so no layer's cache is sliced
+    # out or stacked back. Only the stacked parameters (and the LoRA
+    # pool) are sliced per layer, and that slicing carries this scope
+    # alone
+    xs = (params["layers"], _layer_indices(cache), pool)
     with jax.named_scope("apex.decode.layers"):
-        x, new_cache = lax.scan(body, x, xs)
-    return _lm_head(cfg, params, x), new_cache
+        (x, cache), _ = lax.scan(body, (x, cache), xs)
+    return _lm_head(cfg, params, x), cache
 
 
 #: sentinel in per-slot ``eos`` vectors: no stop token for this row
@@ -1775,61 +1770,45 @@ def ngram_drafts(hist, tok, k: int):
     return jnp.stack(out, axis=1)
 
 
-def _paged_attend_multi(cfg: GPTConfig, q, k_new, v_new, kv, pos,
-                        table):
+def _paged_attend_multi(cfg: GPTConfig, q, k_new, v_new, cache, layer,
+                        pos, table):
     """:func:`_decode_attend_multi` over the paged layout: all T K/V
     columns land through the paged multi-column write (Pallas
-    scalar-prefetch remap under the kernel impl, one-hot page scatter
-    under XLA — over-horizon lanes clamp/drop into masked-garbage
-    cells exactly like the contiguous pair), then the T query rows
-    attend the GATHERED row-contiguous view with the contiguous verify
-    path's exact materialised-scores expression — the paged spec ==
-    contiguous spec parity stands on the gathered bytes being
-    identical."""
+    scalar-prefetch remap on the stacked pool, in place, under the
+    kernel impl; one-hot page scatter on the sliced-out layer under
+    XLA — over-horizon lanes clamp/drop into masked-garbage cells
+    exactly like the contiguous pair), then the T query rows attend the
+    GATHERED row-contiguous view of the layer's pool with the
+    contiguous verify path's exact materialised-scores expression — the
+    paged spec == contiguous spec parity stands on the gathered bytes
+    being identical."""
     b, heads, t, d = q.shape
     kind = _kv_cache_dtype(cfg)
     quant = kind != "compute"
-    k_in, v_in, ks_in, vs_in = _cache_planes(kv, quant)
-    p_sz = k_in.shape[2]
-    s_max = table.shape[1] * p_sz
-    use_kernel = _decode_attn_impl(cfg, s_max) == "kernel"
-    if use_kernel:
-        if quant:
-            kq, ks, vq, vs = _paged_write_columns_quant(
-                k_new, v_new, k_in, ks_in, v_in, vs_in, table, pos, kind)
-            new_kv = _stack_planes(kq, vq, ks, vs)
-            k_cache = dequantize_kv(_paged_gather_xla(kq, table),
-                                    _paged_gather_xla(ks, table),
-                                    cfg.compute_dtype)
-            v_cache = dequantize_kv(_paged_gather_xla(vq, table),
-                                    _paged_gather_xla(vs, table),
-                                    cfg.compute_dtype)
-        else:
-            kp, vp = _paged_write_columns(k_new, v_new, k_in, v_in,
-                                          table, pos)
-            new_kv = _stack_planes(kp, vp)
-            k_cache = _paged_gather_xla(kp, table)
-            v_cache = _paged_gather_xla(vp, table)
+    s_max = table.shape[1] * jax.tree.leaves(cache)[0].shape[4]
+    if _decode_attn_impl(cfg, s_max) == "kernel":
+        cache = _stacked_write_columns(
+            k_new, v_new, cache, layer, pos, table=table,
+            kind=kind if quant else None)
+        kp, vp, ksp, vsp = _cache_planes(cache, layer, quant)
     else:
+        k_in, v_in, ks_in, vs_in = _cache_planes(cache, layer, quant)
+        ksp = vsp = None
         if quant:
             k_new, k_s = quantize_kv_rows(k_new, kind)
             v_new, v_s = quantize_kv_rows(v_new, kind)
-        kp = _paged_write_columns_xla(k_in, k_new, table, pos)
-        vp = _paged_write_columns_xla(v_in, v_new, table, pos)
-        if quant:
             ksp = _paged_write_columns_xla(ks_in, k_s, table, pos)
             vsp = _paged_write_columns_xla(vs_in, v_s, table, pos)
-            new_kv = _stack_planes(kp, vp, ksp, vsp)
-            k_cache = dequantize_kv(_paged_gather_xla(kp, table),
-                                    _paged_gather_xla(ksp, table),
-                                    cfg.compute_dtype)
-            v_cache = dequantize_kv(_paged_gather_xla(vp, table),
-                                    _paged_gather_xla(vsp, table),
-                                    cfg.compute_dtype)
-        else:
-            new_kv = _stack_planes(kp, vp)
-            k_cache = _paged_gather_xla(kp, table)
-            v_cache = _paged_gather_xla(vp, table)
+        kp = _paged_write_columns_xla(k_in, k_new, table, pos)
+        vp = _paged_write_columns_xla(v_in, v_new, table, pos)
+        cache = _stack_planes(cache, layer, kp, vp, ksp, vsp)
+    k_cache = _paged_gather_xla(kp, table)
+    v_cache = _paged_gather_xla(vp, table)
+    if quant:
+        k_cache = dequantize_kv(k_cache, _paged_gather_xla(ksp, table),
+                                cfg.compute_dtype)
+        v_cache = dequantize_kv(v_cache, _paged_gather_xla(vsp, table),
+                                cfg.compute_dtype)
     # the contiguous _decode_attend_multi read expressions VERBATIM
     valid = (jnp.arange(s_max)[None, None]
              <= (pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None])
@@ -1839,56 +1818,52 @@ def _paged_attend_multi(cfg: GPTConfig, q, k_new, v_new, kv, pos,
         "bhtd,bhsd->bhts", q, k_cache).astype(jnp.float32)
     scores = jnp.where(valid[:, None], scores, -1e30)
     p_attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhts,bhsd->bhtd", p_attn, v_cache), new_kv
+    return jnp.einsum("bhts,bhsd->bhtd", p_attn, v_cache), cache
 
 
-def _decode_attend_multi(cfg: GPTConfig, q, k_new, v_new, kv, pos):
+def _decode_attend_multi(cfg: GPTConfig, q, k_new, v_new, cache, layer,
+                         pos):
     """:func:`_decode_attend` for ``T`` tokens per row at positions
     ``pos[b] .. pos[b] + T - 1`` — the speculative verify forward's
     attention core. ``q/k_new/v_new [b, heads, T, d]``; writes all T
-    K/V columns (multi-column masked write — over-horizon lanes are
-    dropped/clamped into the masked-garbage region, see
-    :func:`apex_tpu.kernels.cache_write_columns_xla`), then attends
-    each query row ``t`` over cache columns ``0 .. pos[b] + t`` with
-    the SAME materialised-scores expression as the plain XLA decode
-    path — per-row values bit-identical to T sequential
+    K/V columns of layer ``layer`` (multi-column masked write —
+    over-horizon lanes are dropped/clamped into the masked-garbage
+    region, see :func:`apex_tpu.kernels.cache_write_columns_xla`), then
+    attends each query row ``t`` over cache columns ``0 .. pos[b] + t``
+    with the SAME materialised-scores expression as the plain XLA
+    decode path — per-row values bit-identical to T sequential
     :func:`_decode_attend` steps (the causal-exactness argument of
     :func:`prefill_at`, applied to the cache horizon), which is what
-    the greedy spec == plain oracle stands on. The kernel impl uses
-    the Pallas multi-column write (one window-write pass per lane, in
-    place) but keeps the materialised read: T is tiny (draft k + 1)
-    and a T-row split-K sweep is future work (docs/DESIGN.md)."""
+    the greedy spec == plain oracle stands on. The kernel impl lands
+    the columns in the stacked cache in place (one window-write pass
+    per lane) but keeps the materialised read, over the layer sliced
+    out of the carry: T is tiny (draft k + 1) and a T-row split-K
+    sweep is future work (docs/DESIGN.md)."""
     b, heads, t, d = q.shape
     kind = _kv_cache_dtype(cfg)
     quant = kind != "compute"
-    k_in, v_in, ks_in, vs_in = _cache_planes(kv, quant)
-    s_max = k_in.shape[2]
-    use_kernel = _decode_attn_impl(cfg, s_max) == "kernel"
-    if use_kernel:
-        if quant:
-            kq, ks, vq, vs = _cache_write_columns_quant(
-                k_new, v_new, k_in, ks_in, v_in, vs_in, pos, kind)
-            new_kv = _stack_planes(kq, vq, ks, vs)
-            k_cache = dequantize_kv(kq, ks, cfg.compute_dtype)
-            v_cache = dequantize_kv(vq, vs, cfg.compute_dtype)
-        else:
-            k_cache, v_cache = _cache_write_columns(
-                k_new, v_new, k_in, v_in, pos)
-            new_kv = _stack_planes(k_cache, v_cache)
+    s_max = jax.tree.leaves(cache)[0].shape[4]
+    if _decode_attn_impl(cfg, s_max) == "kernel":
+        cache = _stacked_write_columns(
+            k_new, v_new, cache, layer, pos,
+            kind=kind if quant else None)
+        k_cache, v_cache, k_scale, v_scale = _cache_planes(
+            cache, layer, quant)
     else:
+        k_in, v_in, ks_in, vs_in = _cache_planes(cache, layer, quant)
+        k_scale = v_scale = None
         if quant:
             k_new, k_s = quantize_kv_rows(k_new, kind)
             v_new, v_s = quantize_kv_rows(v_new, kind)
-        k_cache = _cache_write_columns_xla(k_in, k_new, pos)
-        v_cache = _cache_write_columns_xla(v_in, v_new, pos)
-        if quant:
             k_scale = _cache_write_columns_xla(ks_in, k_s, pos)
             v_scale = _cache_write_columns_xla(vs_in, v_s, pos)
-            new_kv = _stack_planes(k_cache, v_cache, k_scale, v_scale)
-            k_cache = dequantize_kv(k_cache, k_scale, cfg.compute_dtype)
-            v_cache = dequantize_kv(v_cache, v_scale, cfg.compute_dtype)
-        else:
-            new_kv = _stack_planes(k_cache, v_cache)
+        k_cache = _cache_write_columns_xla(k_in, k_new, pos)
+        v_cache = _cache_write_columns_xla(v_in, v_new, pos)
+        cache = _stack_planes(cache, layer, k_cache, v_cache, k_scale,
+                              v_scale)
+    if quant:
+        k_cache = dequantize_kv(k_cache, k_scale, cfg.compute_dtype)
+        v_cache = dequantize_kv(v_cache, v_scale, cfg.compute_dtype)
     # row t attends over 0 .. pos + t (its own just-written column
     # included, like the plain path); later verify columns are masked
     # to exact softmax zeros. This expression MUST stay in lockstep
@@ -1911,10 +1886,10 @@ def _decode_attend_multi(cfg: GPTConfig, q, k_new, v_new, kv, pos):
         "bhtd,bhsd->bhts", q, k_cache).astype(jnp.float32)
     scores = jnp.where(valid[:, None], scores, -1e30)
     p_attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhts,bhsd->bhtd", p_attn, v_cache), new_kv
+    return jnp.einsum("bhts,bhsd->bhtd", p_attn, v_cache), cache
 
 
-def _verify_layer(cfg: GPTConfig, p, x, kv, pos, table=None,
+def _verify_layer(cfg: GPTConfig, p, x, cache, layer, pos, table=None,
                   lora=None):
     """:func:`_decode_layer` for ``T`` tokens per row: ``x [b, T,
     hidden]`` at positions ``pos[b] + t``. Projections/LN/MLP are
@@ -1932,11 +1907,11 @@ def _verify_layer(cfg: GPTConfig, p, x, kv, pos, table=None,
             for z in _qkv_project(cfg, p["attn"]["qkv"], xa, lora=lq))
         with jax.named_scope("apex.decode.attn"):
             if table is None:
-                ctx, new_kv = _decode_attend_multi(cfg, q, k_new, v_new,
-                                                   kv, pos)
+                ctx, cache = _decode_attend_multi(cfg, q, k_new, v_new,
+                                                  cache, layer, pos)
             else:
-                ctx, new_kv = _paged_attend_multi(cfg, q, k_new, v_new,
-                                                  kv, pos, table)
+                ctx, cache = _paged_attend_multi(
+                    cfg, q, k_new, v_new, cache, layer, pos, table)
         out = jnp.transpose(ctx, (0, 2, 1, 3)).reshape(b, t, hl)
         attn = row_parallel_linear(
             out, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
@@ -1949,7 +1924,7 @@ def _verify_layer(cfg: GPTConfig, p, x, kv, pos, table=None,
         x = x + attn
     with jax.named_scope("apex.mlp"):
         xb = _layer_norm(cfg, x, p["ln2"]["scale"], p["ln2"]["bias"])
-        return x + _mlp(cfg, p["mlp"], xb, lora=lora), new_kv
+        return x + _mlp(cfg, p["mlp"], xb, lora=lora), cache
 
 
 def decode_verify(cfg: GPTConfig, params, cache, tokens, pos,
@@ -2005,29 +1980,21 @@ def decode_verify(cfg: GPTConfig, params, cache, tokens, pos,
         x = (emb + pos_e.astype(cfg.compute_dtype)).astype(
             cfg.compute_dtype)
 
-    if lora is None:
-        def body(carry, inp):
-            layer_p, kv = inp
-            y, kv = _verify_layer(cfg, _cast_layer(cfg, layer_p), carry,
-                                  kv, pos, table)
-            return y, kv
+    pool, ids, scale = lora if lora is not None else (None, None, None)
 
-        xs = (params["layers"], cache)
-    else:
-        pool, ids, scale = lora
+    def body(carry, inp):
+        x, cache = carry
+        layer_p, layer, page = inp
+        return _verify_layer(
+            cfg, _cast_layer(cfg, layer_p), x, cache, layer, pos, table,
+            lora=None if page is None else (page, ids, scale)), None
 
-        def body(carry, inp):
-            layer_p, kv, page = inp
-            y, kv = _verify_layer(cfg, _cast_layer(cfg, layer_p), carry,
-                                  kv, pos, table,
-                                  lora=(page, ids, scale))
-            return y, kv
-
-        xs = (params["layers"], cache, pool)
+    # the cache in the carry, as in decode_step
+    xs = (params["layers"], _layer_indices(cache), pool)
     with jax.named_scope("apex.decode.layers"):
-        x, new_cache = lax.scan(body, x, xs)
+        (x, cache), _ = lax.scan(body, (x, cache), xs)
     lg = _lm_head(cfg, params, x.reshape(b * t, cfg.hidden_size))
-    return lg.reshape(b, t, -1), new_cache
+    return lg.reshape(b, t, -1), cache
 
 
 def decode_steps_spec(cfg: GPTConfig, params, cache, state, n: int, *,

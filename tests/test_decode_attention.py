@@ -5,7 +5,10 @@ scores XLA decode path with per-dtype tolerances — both standalone
 (kernel vs fp32 numpy reference) and integrated (a full ``decode_step``
 with ``decode_attn_impl="kernel"`` vs ``"xla"``), plus the one-column
 cache-write contract: every cache byte outside the written column is
-bit-identical to the input."""
+bit-identical to the input. The layer-indexed (stacked-cache) forms are
+held to the per-layer calls for every layer, and a greedy and a sampled
+``generate`` stream are pinned to what the slice-and-stack scan of
+PR 24 emitted."""
 
 import dataclasses
 
@@ -18,8 +21,19 @@ from jax.sharding import PartitionSpec as P
 from apex_tpu import mesh as mx
 from apex_tpu.kernels import decode_attention
 from apex_tpu.kernels.decode_attention import (
+    cache_write_columns,
+    cache_write_columns_quant,
     decode_attention_quantized,
+    kv_storage_dtype,
+    paged_attention,
+    paged_attention_quantized,
+    paged_write_column,
+    paged_write_column_quant,
+    paged_write_columns,
+    paged_write_columns_quant,
     quantize_kv_rows,
+    stacked_decode_attention,
+    stacked_write_columns,
 )
 from apex_tpu.models import gpt
 from apex_tpu.transformer.testing import standalone_gpt_config
@@ -249,3 +263,172 @@ def test_decode_attention_validation():
     assert gpt._decode_attn_impl(standalone_gpt_config(), 4096) == "xla"
     assert gpt._decode_attn_impl(
         standalone_gpt_config(compute_dtype=jnp.float16), 4096) == "xla"
+
+
+# ---------------------------------------------------------------------------
+# the layer-indexed kernels on the stacked cache
+# ---------------------------------------------------------------------------
+
+_L, _B, _H, _S, _D, _PAGE = 3, 3, 2, 32, 32, 8
+
+
+def _stacked_case(layout, kind):
+    """A random stacked cache (or page pool + block tables) of ``_L``
+    layers in the storage of ``kind``, with one step's rows."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+    mk = lambda k, shp: (jax.random.normal(k, shp) * 0.5).astype(dtype)
+    rows = _B * _S // _PAGE + 4 if layout == "paged" else _B
+    horizon = _PAGE if layout == "paged" else _S
+    raw = mk(ks[0], (_L, 2, rows, _H, horizon, _D))
+    if kind == "bf16":
+        cache = raw
+    else:
+        q, scale = quantize_kv_rows(raw, kind)
+        cache = {"kv": q, "scale": scale}
+    table = None
+    if layout == "paged":
+        # each row owns _S // _PAGE distinct pages, out of order
+        perm = jax.random.permutation(ks[1], rows)
+        table = perm[:_B * (_S // _PAGE)].reshape(_B, -1).astype(jnp.int32)
+    return dict(
+        cache=cache, table=table, kind=None if kind == "bf16" else kind,
+        q=mk(ks[2], (_B, _H, _D)), k_new=mk(ks[3], (_B, _H, _D)),
+        v_new=mk(ks[4], (_B, _H, _D)),
+        cols=mk(ks[5], (2, _B, _H, 3, _D)),
+        pos=jnp.asarray([5, 0, _S - 4], jnp.int32))
+
+
+def _layer_of(cache, layer):
+    """``(k, v)`` or ``(k, k_scale, v, v_scale)`` of one layer."""
+    if isinstance(cache, dict):
+        kv, sc = cache["kv"][layer], cache["scale"][layer]
+        return kv[0], sc[0], kv[1], sc[1]
+    return cache[layer, 0], cache[layer, 1]
+
+
+def _per_layer_decode(c, planes):
+    """The per-layer calls' ``(out, *planes)`` for one step."""
+    q, k_new, v_new, pos, table, kind = (
+        c[n] for n in ("q", "k_new", "v_new", "pos", "table", "kind"))
+    if table is None:
+        if kind:
+            return decode_attention_quantized(q, k_new, v_new, *planes,
+                                              pos, kind=kind)
+        return decode_attention(q, k_new, v_new, *planes, pos)
+    if kind:
+        planes = paged_write_column_quant(k_new, v_new, *planes, table,
+                                          pos, kind)
+        return (paged_attention_quantized(q, *planes, table, pos,
+                                          kind=kind), *planes)
+    planes = paged_write_column(k_new, v_new, *planes, table, pos)
+    return (paged_attention(q, *planes, table, pos), *planes)
+
+
+def _per_layer_columns(c, planes):
+    k_new, v_new = c["cols"]
+    pos, table, kind = c["pos"], c["table"], c["kind"]
+    if table is None:
+        if kind:
+            return cache_write_columns_quant(k_new, v_new, *planes, pos,
+                                             kind)
+        return cache_write_columns(k_new, v_new, *planes, pos)
+    if kind:
+        return paged_write_columns_quant(k_new, v_new, *planes, table,
+                                         pos, kind)
+    return paged_write_columns(k_new, v_new, *planes, table, pos)
+
+
+def _assert_only_layer_moved(got, before, layer, want_planes):
+    """Layer ``layer`` of ``got`` holds ``want_planes``; every other
+    layer's bytes are those of ``before``."""
+    for g, w in zip(_layer_of(got, layer), want_planes):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+    for g, b in zip(jax.tree.leaves(got), jax.tree.leaves(before)):
+        others = np.arange(_L) != layer
+        np.testing.assert_array_equal(
+            np.asarray(g)[others].view(np.uint8),
+            np.asarray(b)[others].view(np.uint8))
+
+
+@pytest.mark.parametrize("layer", range(_L))
+@pytest.mark.parametrize("kind", ["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_stacked_kernels_match_per_layer_calls(layout, kind, layer):
+    """The write and the read kernel addressed by layer index into the
+    stacked cache give, for every layer, exactly what the per-layer
+    calls give on that layer's planes — the one-column step and the
+    T-column write — and leave every other layer bit-identical. The
+    layer is a traced scalar, as the model's scan hands it over."""
+    c = _stacked_case(layout, kind)
+    planes = _layer_of(c["cache"], layer)
+    want_out, *want_planes = _per_layer_decode(c, planes)
+    out, cache = jax.jit(
+        lambda cache, layer: stacked_decode_attention(
+            c["q"], c["k_new"], c["v_new"], cache, layer, c["pos"],
+            table=c["table"], kind=c["kind"]))(c["cache"],
+                                               jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(want_out, np.float32))
+    _assert_only_layer_moved(cache, c["cache"], layer, want_planes)
+    if c["kind"]:
+        assert cache["kv"].dtype == kv_storage_dtype(c["kind"])
+
+    cache = jax.jit(
+        lambda cache, layer: stacked_write_columns(
+            *c["cols"], cache, layer, c["pos"], table=c["table"],
+            kind=c["kind"]))(c["cache"], jnp.int32(layer))
+    _assert_only_layer_moved(cache, c["cache"], layer,
+                             _per_layer_columns(c, planes))
+
+
+def test_stacked_decode_attention_validation():
+    z3 = jnp.zeros((_B, _H, _D))
+    cache = jnp.zeros((_L, 2, _B, _H, _S, _D))
+    pos = jnp.zeros((_B,), jnp.int32)
+    with pytest.raises(ValueError, match="stacked cache"):
+        stacked_decode_attention(z3, z3, z3, cache[0], 0, pos)
+    with pytest.raises(ValueError, match="inconsistent"):
+        stacked_decode_attention(z3, z3, z3, cache[:, :, :2], 0, pos)
+    with pytest.raises(ValueError, match="pos must be"):
+        stacked_decode_attention(z3, z3, z3, cache, 0, pos[:2])
+    with pytest.raises(ValueError, match="unknown quantized-KV kind"):
+        stacked_decode_attention(z3, z3, z3, cache, 0, pos, kind="int4")
+
+
+#: what PR 24's tree (the layer scan that sliced each layer's cache out
+#: and stacked it back) emitted for `_stream` below, 3 rows x 16 steps;
+#: the same under either impl and under the int8 cache
+_PARENT_STREAM = {
+    "greedy": [[64] * 16, [34] * 16, [14] * 14 + [58, 58]],
+    "sampled": [
+        [74, 19, 54, 18, 76, 34, 43, 83, 28, 63, 54, 74, 91, 56, 76, 94],
+        [82, 59, 65, 73, 27, 55, 94, 73, 83, 33, 11, 54, 44, 11, 95, 47],
+        [8, 9, 85, 72, 34, 21, 44, 25, 62, 49, 11, 86, 31, 66, 85, 33]],
+}
+
+
+@pytest.mark.parametrize("draw", ["greedy", "sampled"])
+@pytest.mark.parametrize("impl", ["kernel", "xla"])
+@pytest.mark.parametrize("kv", ["auto", "int8"])
+def test_decode_stream_matches_parent(devices8, kv, impl, draw):
+    """16 steps of ``generate`` (one ``decode_steps`` scan with the
+    cache in the layer scan's carry) emit, token for token, what the
+    slice-and-stack scan of PR 24 emitted — under the kernels and
+    under the XLA fallback, greedy and sampled. Addressing the cache in
+    place changes no logit."""
+    cfg = dataclasses.replace(
+        standalone_gpt_config(vocab_size=96, seq_len=32,
+                              compute_dtype=jnp.float32),
+        decode_attn_impl=impl, kv_cache_dtype=kv)
+    params = gpt.init(cfg, jax.random.PRNGKey(0))
+    prompt = jax.random.randint(jax.random.PRNGKey(1), (3, 5), 0, 96)
+    sample = (dict(temperature=1.0, key=jax.random.PRNGKey(7))
+              if draw == "sampled" else {})
+    out = jax.jit(jax.shard_map(
+        lambda p, t: gpt.generate(cfg, p, t, 16, **sample),
+        mesh=mx.build_mesh(tp=1, devices=devices8[:1]),
+        in_specs=(gpt.param_specs(cfg), P(None, None)),
+        out_specs=P(None, None), check_vma=False))(params, prompt)
+    assert np.asarray(out).tolist() == _PARENT_STREAM[draw]

@@ -490,3 +490,91 @@ def test_decode_attn_impl_predicate(monkeypatch):
     with pytest.raises(ValueError, match="kv_cache_dtype"):
         gpt._kv_cache_dtype(
             dataclasses.replace(base, kv_cache_dtype="int4"))
+
+
+# ---------------------------------------------------------------------------
+# the decode scan's shape: the cache is carried, never sliced and stacked
+# ---------------------------------------------------------------------------
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (tuple, list)) else (v,)):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _walk(jaxpr):
+    """Every equation under ``jaxpr``, kernels' bodies left out (their
+    operands are blocks, not the cache)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in _sub_jaxprs(eqn):
+                yield from _walk(sub)
+
+
+def _layer_scans(jaxpr, n_layers):
+    return [e for e in _walk(jaxpr) if e.primitive.name == "scan"
+            and e.params["length"] == n_layers
+            and "decode.layers" in str(e.source_info.name_stack)]
+
+
+@pytest.mark.parametrize("kv", ["auto", "int8"])
+@pytest.mark.parametrize("impl", ["kernel", "xla"])
+@pytest.mark.parametrize("fn", ["decode_steps", "decode_steps_spec"])
+def test_decode_scan_carries_the_cache(fn, impl, kv):
+    """The layer scan of ``decode_step`` / ``decode_verify`` has the
+    whole stacked cache in its CARRY and not among its ``xs`` / ``ys``;
+    on the kernel path of ``decode_step`` its body has no
+    ``dynamic_slice`` / ``concatenate`` / ``dynamic_update_slice`` on
+    anything as large as one layer's cache (the verify forward keeps
+    its materialised read of the layer; the XLA fallback slices the
+    layer out and puts it back, by design)."""
+    n_layers, slots, horizon = 3, 4, 32
+    cfg = _cfg(seq_len=horizon, num_layers=n_layers, decode_attn_impl=impl,
+               kv_cache_dtype=kv)
+    params = jax.eval_shape(lambda: gpt.init(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(
+        lambda p: gpt.init_cache(cfg, p, slots, horizon), params)
+    vec = lambda dt: jax.ShapeDtypeStruct((slots,), dt)
+    state = {"tok": vec(jnp.int32), "pos": vec(jnp.int32),
+             "remaining": vec(jnp.int32), "done": vec(jnp.bool_),
+             "eos": vec(jnp.int32),
+             "hist": jax.ShapeDtypeStruct((slots, 8), jnp.int32)}
+    greedy = lambda logits, pos: jnp.argmax(logits, -1).astype(jnp.int32)
+    if fn == "decode_steps":
+        run = lambda p, c, s: gpt.decode_steps(cfg, p, c, s, 2,
+                                               draw_fn=greedy)[:2]
+    else:
+        run = lambda p, c, s: gpt.decode_steps_spec(
+            cfg, p, c, s, 2, spec_k=2, draw_fn=greedy)[:2]
+    state_spec = {k: P() for k in state}
+    jaxpr = jax.make_jaxpr(jax.shard_map(
+        run, mesh=mx.build_mesh(tp=1, devices=jax.devices()[:1]),
+        in_specs=(gpt.param_specs(cfg), gpt.cache_specs(cfg), state_spec),
+        out_specs=(gpt.cache_specs(cfg), state_spec),
+        check_vma=False))(params, cache, state).jaxpr
+    scans = _layer_scans(jaxpr, n_layers)
+    assert len(scans) == 1, [str(e.source_info.name_stack) for e in scans]
+    scan = scans[0]
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    shapes = lambda vs: [tuple(v.aval.shape) for v in vs]
+    cache_shapes = [tuple(x.shape) for x in jax.tree.leaves(cache)]
+    carried = shapes(scan.invars[n_consts:n_consts + n_carry])
+    scanned = (shapes(scan.invars[n_consts + n_carry:])
+               + shapes(scan.outvars[n_carry:]))
+    for shape in cache_shapes:
+        assert shape in carried, (shape, carried)
+        assert shape not in scanned, (shape, scanned)
+    layer_elems = int(np.prod(cache_shapes[0][1:]))
+    big = [e.primitive.name for e in _walk(scan.params["jaxpr"].jaxpr)
+           if e.primitive.name in ("dynamic_slice", "concatenate",
+                                   "dynamic_update_slice")
+           and max(int(np.prod(v.aval.shape))
+                   for v in (*e.invars, *e.outvars)
+                   if hasattr(v.aval, "shape")) >= layer_elems]
+    if impl == "kernel" and fn == "decode_steps":
+        assert not big, big
+    else:
+        assert big   # the walk does see such operations where they are

@@ -94,11 +94,8 @@ def test_train_step_is_the_program_the_readers_look_for(train_paths):
                if p.startswith("jit("))
 
 
-@pytest.fixture(scope="module")
-def engine_paths(devices8):
-    """op_name paths of a tiny engine's decode-step program and of one
-    of its admission programs."""
-    cfg = standalone_gpt_config(vocab_size=96, seq_len=64)
+def _engine_paths(devices8, **model):
+    cfg = standalone_gpt_config(vocab_size=96, seq_len=64, **model)
     mesh = mx.build_mesh(tp=1, devices=devices8[:1])
     ecfg = EngineConfig(slots=2, max_prompt_len=8, max_seq_len=24,
                         decode_chunk=2)
@@ -120,6 +117,20 @@ def engine_paths(devices8):
         return {"step": _paths(step), "admit": _paths(admit)}
 
 
+@pytest.fixture(scope="module")
+def engine_paths(devices8):
+    """op_name paths of a tiny engine's decode-step program and of one
+    of its admission programs (off the TPU: the XLA decode path)."""
+    return _engine_paths(devices8)
+
+
+@pytest.fixture(scope="module")
+def kernel_engine_paths(devices8):
+    """The same with the decode kernels (interpreted here), the path
+    every serving cell of the benchmark runs."""
+    return _engine_paths(devices8, decode_attn_impl="kernel")
+
+
 @pytest.mark.parametrize("program,region", [
     ("step", "apex.embed"), ("step", "apex.attn"), ("step", "apex.mlp"),
     ("step", "apex.lm_head"), ("step", "apex.sample"),
@@ -131,6 +142,20 @@ def engine_paths(devices8):
 ])
 def test_engine_program_regions(engine_paths, program, region):
     assert region in _regions(engine_paths[program], backward=False)
+
+
+@pytest.mark.parametrize("region,there", [
+    ("apex.decode.layers", True), ("apex.decode.attn", True),
+    ("apex.mlp", True), ("apex.sample", True),
+    ("apex.decode.cache_slice", False),
+    ("apex.decode.cache_stack", False)])
+def test_kernel_step_program_regions(kernel_engine_paths, region, there):
+    """On the kernel path the layer scan carries the cache and the
+    kernels address it by layer: `apex.decode.layers` is the scan's
+    slicing of the stacked weights, and the two scopes of the XLA
+    fallback's slice-out / put-back name nothing."""
+    regions = _regions(kernel_engine_paths["step"], backward=False)
+    assert (region in regions) == there
 
 
 def test_engine_programs_are_the_ones_the_readers_look_for(engine_paths):

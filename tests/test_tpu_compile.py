@@ -1,0 +1,151 @@
+"""Compile-only checks for a described v5e — no chip, nothing runs.
+
+What the CPU backbone cannot see: whether Mosaic accepts the decode
+kernels' blocks at the model's real widths, and what the TPU compiler
+does with the resident cache at a program's entry and exit. The TPU
+compiler is installed beside the CPU backend and compiles for a
+``v5e:2x2`` topology that is described and not attached; a machine
+where it cannot be described skips the file. A compile that passes is
+not a chip run.
+
+The topology is described inside a fixture, never at import (one
+process holds the TPU library at a time), and every such test lives in
+this one file.
+"""
+
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+from apex_tpu import mesh as mx
+from apex_tpu.kernels import _utils as kernel_utils
+from apex_tpu.models import gpt
+from apex_tpu.serving.engine import Engine, EngineConfig
+from apex_tpu.transformer.testing import standalone_gpt_config
+
+# the module, not the function the package re-exports under its name
+da = importlib.import_module("apex_tpu.kernels.decode_attention")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as env:
+        # what the TPU library asks of a host that has no TPU
+        env.setenv("TPU_LOG_DIR", "disabled")
+        env.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+        env.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+        try:
+            yield topologies.get_topology_desc(platform="tpu",
+                                               topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Kernels lower for Mosaic instead of the interpreter (this
+    process's default backend is the CPU), and no described-device
+    program enters the persistent compile cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    not_interpreted = lambda: False
+    monkeypatch.setattr(da, "use_interpret", not_interpreted)
+    monkeypatch.setattr(kernel_utils, "use_interpret", not_interpreted)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("kind", [None, "int8", "fp8"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_stacked_decode_kernels_compile_for_v5e(topo, mosaic, layout, kind):
+    """GPT-2's head shapes (16 heads of 64) at a horizon of 1024: the
+    layer-indexed write and read kernels, and the T-column write, on a
+    stacked cache / page pool in each storage."""
+    layers, b, h, s_max, d, page = 2, 8, 16, 1024, 64, 128
+    one = SingleDeviceSharding(topo.devices[0])
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    rows, horizon = (b * s_max // page, page) if layout == "paged" \
+        else (b, s_max)
+    shape = (layers, 2, rows, h, horizon, d)
+    cache = arr(shape, jnp.bfloat16)
+    if kind:
+        cache = {"kv": arr(shape, da.kv_storage_dtype(kind)),
+                 "scale": arr(shape[:-1], jnp.float32)}
+    table = arr((b, s_max // page), jnp.int32) \
+        if layout == "paged" else None
+    row = arr((b, h, d), jnp.bfloat16)
+
+    def step(cache, layer, q, k_new, v_new, cols, pos, table):
+        out, cache = da.stacked_decode_attention(
+            q, k_new, v_new, cache, layer, pos, table=table, kind=kind)
+        return out, da.stacked_write_columns(
+            cols, cols, cache, layer, pos, table=table, kind=kind)
+
+    text = jax.jit(step, donate_argnums=0).lower(
+        cache, arr((), jnp.int32), row, row, row,
+        arr((b, h, 3, d), jnp.bfloat16), arr((b,), jnp.int32), table
+    ).compile().as_text()
+    calls = lambda name: re.findall(
+        rf"^\s*%{name}[.\d]* = .* custom-call\(", text, re.M)
+    assert len(calls("decode_attn_write")) == 4
+    assert len(calls("decode_attn_read")) == 1
+
+
+def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
+    """The engine's step program, compiled for the chip: the layer loop
+    and the step loop around it hold the cache in place — no
+    instruction anywhere yields an array of one layer's cache, and the
+    only whole-cache copies are the device-layout pair at the entry
+    computation's two ends (the resident cache's default layout puts
+    the positions on the lanes, Mosaic's operand is row-major)."""
+
+    class PlanEngine(Engine):
+        """Programs built and never run: nothing can be placed on a
+        described device."""
+
+        def _build(self):
+            super()._build()
+            self.init_program = self._init
+            self._init = lambda params: (None, None)
+
+    cfg = standalone_gpt_config(vocab_size=96, seq_len=256,
+                                hidden_size=256, num_heads=4,
+                                num_layers=3, compute_dtype=jnp.bfloat16)
+    assert cfg.head_dim == 64
+    ecfg = EngineConfig(slots=8, max_prompt_len=64, max_seq_len=256,
+                        decode_chunk=2)
+    mesh = mx.build_mesh(tp=1, devices=list(topo.devices)[:1])
+    params = jax.tree.map(
+        lambda s, sp: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, sp)),
+        jax.eval_shape(lambda: gpt.init(cfg, jax.random.PRNGKey(0))),
+        gpt.param_specs(cfg))
+    eng = PlanEngine(cfg, params, mesh, ecfg)
+    cache, state = jax.eval_shape(eng.init_program, params)
+    text = eng._step_variants[ecfg.decode_chunk].lower(
+        params, cache, state,
+        jax.ShapeDtypeStruct((ecfg.slots, cfg.vocab_size), jnp.bool_)
+    ).compile().as_text()
+    whole = ",".join(map(str, cache.shape))
+    layer = ",".join(map(str, cache.shape[1:]))
+    yields = lambda dims: [
+        ln.strip()[:160] for ln in text.splitlines()
+        if re.search(rf"^\s*(ROOT )?%\S+ = \w+\[{dims}\]", ln)
+        and not re.search(r" (parameter|get-tuple-element|bitcast)\(", ln)]
+    assert not yields(layer), yields(layer)[:3]
+    entry = text[text.index("\nENTRY "):]
+    made = yields(whole)
+    copies = [ln for ln in made if " copy(" in ln]
+    assert len(copies) <= 2 and all(ln in entry for ln in copies), copies
+    # beside them: the aliased write kernel, once per layer-loop body
+    assert all(" copy(" in ln or "decode_attn_write" in ln
+               for ln in made), made[:3]
