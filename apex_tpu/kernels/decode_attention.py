@@ -30,7 +30,15 @@ composed by :func:`decode_attention`:
   vector-``pos`` semantics exactly: garbage cache entries past a row's
   position contribute exact softmax zeros. K and V chunks are blocks
   of plane 0 and plane 1 of the same stacked operand at the prefetched
-  layer.
+  layer. The grid is ``(b, h // hb, chunks)``: ``hb`` heads of one
+  batch row a grid step (as many as a VMEM budget holds; all of them
+  at GPT-2's 16 x 64), their scores and statistics dense ``(hb, bk)``
+  tiles. It fetches only what a step attends: the index maps clamp the
+  chunk to the row's fill (``min(j, pos[b] // block_k)``), so a grid
+  step past the row's last chunk names the block already resident and
+  the pipeline copies nothing, and a row the caller marks dead (a done
+  slot) names the block the row before it left, computes nothing and
+  comes out as zeros.
 
 The stacked forms (:func:`stacked_decode_attention`,
 :func:`stacked_write_columns`) are what the model calls; the per-layer
@@ -334,19 +342,93 @@ def cache_write_columns_quant(k_new, v_new, k_q, k_s, v_q, v_s, pos,
 
 
 # ---------------------------------------------------------------------------
-# split-K read: one query row against its masked cache horizon
+# split-K read: a block of heads of one row against the chunks of the
+# horizon that the row attends
 # ---------------------------------------------------------------------------
 
+#: VMEM the read kernel's double-buffered K and V blocks may take (of
+#: the 16 MiB Mosaic scopes a kernel by default): it sets how many
+#: heads ride one grid step
+_KV_VMEM_BUDGET = 4 << 20
+
+
+def decode_block_k(horizon: int, storage_dtype, *, quantized: bool = False,
+                   block_k: Optional[int] = None) -> int:
+    """Positions in one split-K chunk of the read kernel over a
+    contiguous cache of ``horizon`` positions stored as
+    ``storage_dtype`` (a paged pool's chunk is its page). THE rule:
+    the kernel calls it, and so does whoever counts the chunks a step
+    needs (the scheduler's ``decode.chunks_*`` counts)."""
+    # fp32 scale rows put the chunk on the lane dimension too
+    return _fit_block_k(block_k or _DEFAULT_BLOCK_K, horizon,
+                        _LANES if quantized else _sublane_tile(storage_dtype))
+
+
+def _heads_per_step(h: int, d: int, bk: int, dtype, quant: bool) -> int:
+    """Heads one grid step of the read takes: the largest divisor of
+    ``h`` whose K and V blocks, double-buffered and as laid out on the
+    tiles (head dim padded to the lanes), fit :data:`_KV_VMEM_BUDGET`;
+    the fp32 scale blocks of a quantized cache ride along."""
+    per_head = 4 * round_up(bk, _sublane_tile(dtype)) * round_up(
+        d, _LANES) * jnp.dtype(dtype).itemsize
+    if quant:
+        per_head += 4 * round_up(bk, _LANES) * 4
+    fit = max(1, _KV_VMEM_BUDGET // per_head)
+    return max(n for n in range(1, h + 1) if h % n == 0 and n <= fit)
+
+
+def _fetch_table(pos, live, bk: int, groups: int, chunks: int):
+    """Which blocks each grid row of the read names, ``[2, b] int32``:
+    ``row`` and ``pin``. A live row sweeps its own chunks (``row[i] ==
+    i``, ``pin[i] == -1``). A dead row names ONE block through all its
+    grid steps — head group ``pin // chunks``, chunk ``pin % chunks``
+    of row ``row[i]`` — and it is the block already resident: the last
+    one the live row before it fetched, or, for dead rows that lead
+    the batch, the first one the first live row will fetch. So a dead
+    row moves no bytes. ``live`` None: every row is live."""
+    b = pos.shape[0]
+    idx = jnp.arange(b, dtype=jnp.int32)
+    if live is None:
+        return jnp.stack([idx, jnp.full_like(idx, -1)])
+    # masked [b, b] reductions, not cummax / argmax / take: this runs
+    # once per layer call inside the model's scan, where each of those
+    # is a device operation of its own
+    rows = jnp.where(live, idx, -1)[None]
+    prev = jnp.max(jnp.where(idx[None] <= idx[:, None], rows, -1),
+                   axis=1)                         # live row at or before
+    first = jnp.min(jnp.where(live, idx, b)) % b       # 0 where none is
+    row = jnp.where(prev >= 0, prev, first)
+    left = (groups - 1) * chunks + jnp.max(
+        jnp.where(idx[None] == row[:, None], pos[None] // bk, 0), axis=1)
+    pin = jnp.where(live, -1, jnp.where(prev >= 0, left, 0))
+    return jnp.stack([row, pin])
+
+
+def _block_index(g, j, pos, row, pin, bk: int, chunks: int):
+    """``(row, head group, chunk)`` of the K / V block that grid step
+    ``(i, g, j)`` names, from row ``i``'s ``pos`` and its
+    :func:`_fetch_table` entries. The chunk is clamped to the row's
+    fill: steps past its last chunk name the block already resident,
+    and the pipeline skips the copy."""
+    live = pin < 0
+    c = jnp.where(live, jnp.minimum(j, pos // bk), pin % chunks)
+    return row, jnp.where(live, g, pin // chunks), c
+
+
 def _attn_kernel(*refs, n_scalar, quant, scale, bk, smax):
-    """Grid ``(b, h, chunks)``: one (batch, head) query row swept over
-    its horizon in ``bk``-position chunks. ``quant`` adds the two fp32
-    scale-row refs of the int8/fp8 layout."""
+    """Grid ``(b, h // hb, chunks)``: ``hb`` heads of one batch row
+    swept over the row's horizon in ``bk``-position chunks, scores and
+    statistics as dense ``(hb, bk)`` / ``(hb, lanes)`` tiles. ``quant``
+    adds the two fp32 scale-block refs of the int8/fp8 layout. A dead
+    row comes with ``pos == -1``: no chunk is at or before it, so it
+    does no arithmetic and writes zeros."""
     pos_ref = refs[1]
     if quant:
         (q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref,
          l_ref) = refs[n_scalar:]
     else:
         q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs[n_scalar:]
+    hb, d = acc_ref.shape
     j = pl.program_id(2)        # split-K chunk of the horizon
     nk = pl.num_programs(2)
     pos = pos_ref[pl.program_id(0)]
@@ -358,33 +440,37 @@ def _attn_kernel(*refs, n_scalar, quant, scale, bk, smax):
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     # chunks entirely past the row's position contribute nothing (the
-    # decode analogue of the causal block skip)
+    # decode analogue of the causal block skip); their grid steps name
+    # the block already resident, so they fetch nothing either
     @pl.when(j * bk <= pos)
     def _block():
-        q = q_ref[0, 0]                                   # (1, d)
-        k = k_ref[0, 0]                                   # (bk, d)
-        v = v_ref[0, 0]
+        q = q_ref[0, 0]                                   # (hb, d)
         if quant:
-            # int8/fp8 chunk straight from HBM; the per-column scale
-            # folds into the SCORE (q·(k_int·s) == (q·k_int)·s) so the
-            # chunk is never materialised dequantized
-            q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (1, bk)
-        if quant:
-            s = s * ks_ref[0, 0]
-        s = s * scale
+            q = q.astype(jnp.float32)
+        head = lax.broadcasted_iota(jnp.int32, (hb, 1), 0)
         col = lax.broadcasted_iota(jnp.int32, (1, bk), 1) + j * bk
         valid = (col <= pos) & (col < smax)
         # the same mask down the sublanes (Mosaic cannot transpose i1)
         row = lax.broadcasted_iota(jnp.int32, (bk, 1), 0) + j * bk
         valid_rows = (row <= pos) & (row < smax)
-        s = jnp.where(valid, s, _NEG)
-        # masked V rows can be horizon padding (NaN in interpret mode,
-        # arbitrary garbage on chip, NaN bit patterns of stale fp8):
-        # zero them so 0·garbage can't poison the accumulator dot
-        v = jnp.where(valid_rows, v, 0.0).astype(v.dtype)
+        # every head's query against head n's chunk, row n kept: M = hb
+        # costs the MXU what M = 1 does, and the scores come out as one
+        # dense (hb, bk) tile instead of hb one-row tiles
+        s = jnp.zeros((hb, bk), jnp.float32)
+        for n in range(hb):
+            k = k_ref[0, n]                               # (bk, d)
+            if quant:
+                # int8/fp8 chunk straight from HBM; the per-column
+                # scale folds into the SCORE (q·(k_int·s) ==
+                # (q·k_int)·s) so the chunk is never materialised
+                # dequantized
+                k = k.astype(jnp.float32)
+            s = jnp.where(head == n, jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32), s)
+        if quant:
+            s = s * ks_ref[0, 0]
+        s = jnp.where(valid, s * scale, _NEG)
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
@@ -396,9 +482,20 @@ def _attn_kernel(*refs, n_scalar, quant, scale, bk, smax):
             # the V scale folds into p the same way (Σ p_j·(v_j·s_j)
             # == Σ (p_j·s_j)·v_j); masked scale columns are zeroed too
             p = p * jnp.where(valid, vs_ref[0, 0], 0.0)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        pv = jnp.zeros((hb, d), jnp.float32)
+        for n in range(hb):
+            v = v_ref[0, n]                               # (bk, d)
+            if quant:
+                v = v.astype(jnp.float32)
+            # masked V rows can be horizon padding (NaN in interpret
+            # mode, arbitrary garbage on chip, NaN bit patterns of
+            # stale fp8): zero them so 0·garbage can't poison the
+            # accumulator dot
+            v = jnp.where(valid_rows, v, 0.0).astype(v.dtype)
+            pv = jnp.where(head == n, jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32), pv)
+        acc_ref[:] = acc_ref[:] * corr + pv
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
     @pl.when(j == nk - 1)
@@ -407,82 +504,93 @@ def _attn_kernel(*refs, n_scalar, quant, scale, bk, smax):
                        ).astype(o_ref.dtype)
 
 
-def _run_attn(q, planes, layer, pos, scale, *, bk, table=None):
+def _run_attn(q, planes, layer, pos, scale, *, bk, table=None, live=None):
     """Sweep ``q [b, h, d]`` over layer ``layer`` of ``planes``:
     ``[kv]`` or the quantized ``[kv, scale]``, stacked contiguous ``[L,
     2, b, h, S(, d)]`` in ``bk``-position chunks or — ``table [b,
     max_pages]`` given — page pools ``[L, 2, num_pages, h, P(, d)]``
     one page per chunk (``bk == P``; chunk ``j`` of row ``b`` streams
     page ``table[b, j]``). The K and the V chunk are blocks of plane 0
-    and plane 1 of the one ``kv`` operand, read where it lies.
+    and plane 1 of the one ``kv`` operand, read where it lies, ``hb``
+    heads at a time (:func:`_heads_per_step`). Only the chunks a live
+    row attends are fetched (:func:`_block_index`); rows that ``live
+    [b] bool`` marks dead fetch and compute nothing and come out as
+    zeros.
 
-    The fp32 scales ride with positions on the lanes, ``[.., h, 1, S]``
-    — a relayout, not a reshape, on a tiled device layout — so that one
-    layer's scale planes (a sixteenth of its int8/fp8 bytes at head
-    size 64) are sliced out and relaid here; the storage planes are
-    not."""
+    One layer's fp32 scale planes (a sixteenth of its int8/fp8 bytes
+    at head size 64) are sliced out for the read, heads on the
+    sublanes and positions on the lanes as they lie; the storage
+    planes are not."""
     b, h, d = q.shape
     kv = planes[0]
     quant = len(planes) == 2
     paged = table is not None
     mp = table.shape[1] if paged else 0
     smax = mp * bk if paged else kv.shape[4]
+    chunks = mp if paged else -(-smax // bk)
+    hb = _heads_per_step(h, d, bk, kv.dtype, quant)
+    groups = h // hb
 
-    def chunk(i, j, tbl_ref):
-        # (leading, position-chunk) block index of chunk j of row i
+    def block(i, g, j, pos_ref, fetch_ref, tbl_ref):
+        # (leading, head-group, position-chunk) block index
+        row, g, c = _block_index(g, j, pos_ref[i], fetch_ref[0, i],
+                                 fetch_ref[1, i], bk, chunks)
         if paged:
-            return tbl_ref[0][i * mp + j], 0
-        return i, j
+            return tbl_ref[0][row * mp + c], g, 0
+        return row, g, c
 
     def data_map(plane):
-        def index(i, g, j, layer_ref, pos_ref, *tbl_ref):
-            lead, c = chunk(i, j, tbl_ref)
+        def index(i, g, j, layer_ref, pos_ref, fetch_ref, *tbl_ref):
+            lead, g, c = block(i, g, j, pos_ref, fetch_ref, tbl_ref)
             return layer_ref[0], plane, lead, g, c, 0
         return index
 
     def scale_map(plane):
-        def index(i, g, j, layer_ref, pos_ref, *tbl_ref):
-            lead, c = chunk(i, j, tbl_ref)
+        def index(i, g, j, layer_ref, pos_ref, fetch_ref, *tbl_ref):
+            lead, g, c = block(i, g, j, pos_ref, fetch_ref, tbl_ref)
             return plane, lead, g, 0, c
         return index
 
-    row_spec = pl.BlockSpec((1, 1, 1, d), lambda i, g, j, *_: (i, g, 0, 0))
+    row_spec = pl.BlockSpec((1, 1, hb, d), lambda i, g, j, *_: (i, g, 0, 0))
     operands, specs = [], []
     if quant:
-        scale_rows = jnp.expand_dims(lax.dynamic_index_in_dim(
-            planes[1], jnp.asarray(layer, jnp.int32), 0, keepdims=False),
-            3)                                   # [2, rows, h, 1, S]
+        sc = lax.dynamic_index_in_dim(
+            planes[1], jnp.asarray(layer, jnp.int32), 0, keepdims=False)
+        scale_rows = sc.reshape(sc.shape[:2] + (groups, hb, sc.shape[3]))
     for plane in (0, 1):
         operands.append(kv)
-        specs.append(pl.BlockSpec((None, None, 1, 1, bk, d),
+        specs.append(pl.BlockSpec((None, None, 1, hb, bk, d),
                                   data_map(plane)))
         if quant:
             operands.append(scale_rows)
-            specs.append(pl.BlockSpec((None, 1, 1, 1, bk),
+            specs.append(pl.BlockSpec((None, 1, 1, hb, bk),
                                       scale_map(plane)))
-    scalars = [_layer_scalar(layer), pos]
+    if live is not None:
+        pos = jnp.where(live, pos, -1)
+    scalars = [_layer_scalar(layer), pos,
+               _fetch_table(pos, live, bk, groups, chunks)]
     if paged:
         scalars.append(jnp.asarray(table, jnp.int32).reshape(-1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b, h, mp if paged else -(-smax // bk)),
+        grid=(b, groups, chunks),
         in_specs=[row_spec] + specs,
         out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, _LANES), jnp.float32),
-            pltpu.VMEM((1, _LANES), jnp.float32),
+            pltpu.VMEM((hb, d), jnp.float32),
+            pltpu.VMEM((hb, _LANES), jnp.float32),
+            pltpu.VMEM((hb, _LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_attn_kernel, n_scalar=len(scalars),
                           quant=quant, scale=scale, bk=bk, smax=smax),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, groups, hb, d), q.dtype),
         name="decode_attn_read",
         interpret=use_interpret(),
-    )(*scalars, q[:, :, None], *operands)
-    return out[:, :, 0]
+    )(*scalars, q.reshape(b, groups, hb, d), *operands)
+    return out.reshape(b, h, d)
 
 
 # ---------------------------------------------------------------------------
@@ -490,7 +598,8 @@ def _run_attn(q, planes, layer, pos, scale, *, bk, table=None):
 # ---------------------------------------------------------------------------
 
 def stacked_decode_attention(q, k_new, v_new, cache, layer, pos, *,
-                             table=None, kind: Optional[str] = None,
+                             table=None, live=None,
+                             kind: Optional[str] = None,
                              scale: Optional[float] = None,
                              block_k: Optional[int] = None):
     """One decode step of attention for every (batch, head) row, in
@@ -504,8 +613,12 @@ def stacked_decode_attention(q, k_new, v_new, cache, layer, pos, *,
     quantized ``{"kv": storage, "scale": fp32 [..., S]}`` pair of
     either; ``layer`` an int32 scalar (traced or not); ``pos`` ``[b]
     int32`` each row's write/attend position (``0 <= pos[i] < S``;
-    ``gpt.decode_step`` guarantees this by freezing done slots).
-    Returns ``(out [b, h, d], cache)``.
+    ``gpt.decode_step`` guarantees this by freezing done slots);
+    ``live`` optional ``[b] bool``, False for a row whose output the
+    caller discards (a done slot): its column is still written at its
+    frozen ``pos``, but the read fetches none of its history, does no
+    arithmetic for it and returns zeros in its row. Returns ``(out [b,
+    h, d], cache)``.
 
     The cache holds the new column at ``(layer, pos)``: the write
     kernel aliases the whole stacked cache input→output and moves only
@@ -542,6 +655,8 @@ def stacked_decode_attention(q, k_new, v_new, cache, layer, pos, *,
             f"cache shape {kv.shape} inconsistent with q {q.shape}")
     if pos.shape != (b,):
         raise ValueError(f"pos must be [{b}], got {pos.shape}")
+    if live is not None and live.shape != (b,):
+        raise ValueError(f"live must be [{b}], got {live.shape}")
     s = float(scale) if scale is not None else 1.0 / d ** 0.5
     q, was16 = widen_f16(q)
     cache16 = kv.dtype == jnp.float16
@@ -552,10 +667,10 @@ def stacked_decode_attention(q, k_new, v_new, cache, layer, pos, *,
     if table is not None:
         bk = kv.shape[4]
     else:
-        # fp32 scale rows put the chunk on the lane dimension too
-        bk = _fit_block_k(block_k or _DEFAULT_BLOCK_K, kv.shape[4],
-                          _LANES if kind else _sublane_tile(kv.dtype))
-    out = _run_attn(q, planes, layer, pos, s, bk=bk, table=table)
+        bk = decode_block_k(kv.shape[4], kv.dtype, quantized=bool(kind),
+                            block_k=block_k)
+    out = _run_attn(q, planes, layer, pos, s, bk=bk, table=table,
+                    live=live)
     if was16:
         out = out.astype(jnp.float16)
     if cache16:

@@ -46,6 +46,7 @@ from apex_tpu.kernels import (
 )
 from apex_tpu.kernels.decode_attention import (
     cache_write_columns_xla as _cache_write_columns_xla,
+    decode_block_k as _decode_block_k,
     kv_storage_dtype as _kv_storage_dtype,
     paged_gather_xla as _paged_gather_xla,
     paged_write_columns_xla as _paged_write_columns_xla,
@@ -1285,6 +1286,18 @@ def init_cache(cfg: GPTConfig, params, batch: int,
             "scale": jnp.zeros(shape[:-1], jnp.float32)}
 
 
+def decode_read_chunk(cfg: GPTConfig, s_max: int) -> int:
+    """Positions in one chunk of the decode read kernel's sweep over a
+    contiguous cache of ``s_max`` positions in ``cfg``'s storage (a
+    paged cache's chunk is its page): the kernels' own rule, for
+    whoever counts the chunks a step needs."""
+    kind = _kv_cache_dtype(cfg)
+    quant = kind != "compute"
+    return _decode_block_k(
+        s_max, _kv_storage_dtype(kind) if quant else cfg.compute_dtype,
+        quantized=quant)
+
+
 def cache_specs(cfg: GPTConfig):
     """PartitionSpecs matching :func:`init_cache`'s structure (heads are
     the tp-sharded dim; the quantized scale plane shards the same
@@ -1362,7 +1375,8 @@ def _layer_indices(cache):
     return jnp.arange(jax.tree.leaves(cache)[0].shape[0], dtype=jnp.int32)
 
 
-def _decode_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos):
+def _decode_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos,
+                   live=None):
     """The decode-attention core shared by both cache layouts: write
     this token's K/V at ``pos`` of layer ``layer`` and attend ``q``
     over ``0..pos`` — returns ``(ctx [b, heads, d], cache)`` with
@@ -1372,10 +1386,12 @@ def _decode_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos):
     hands the kernels the stacked cache and the layer index: the column
     lands in place and no layer is sliced out or stacked back; under a
     quantized layout it quantizes the incoming row and folds the scales
-    in per split-K chunk. The XLA fallback slices the layer out
-    (:func:`_cache_planes`), quantizes/one-hot-writes both planes,
-    dequantizes the materialised cache before the score einsum, and
-    puts the layer back (:func:`_stack_planes`) — same semantics,
+    in per split-K chunk, and rows that ``live [b] bool`` marks dead
+    read nothing and come out as zeros. The XLA fallback slices the
+    layer out (:func:`_cache_planes`), quantizes/one-hot-writes both
+    planes, dequantizes the materialised cache before the score einsum,
+    and puts the layer back (:func:`_stack_planes`) — same semantics
+    for live rows (it computes every row and ignores ``live``),
     CPU-testable."""
     b, heads, d = q.shape
     kind = _kv_cache_dtype(cfg)
@@ -1385,7 +1401,7 @@ def _decode_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos):
         posv = (jnp.full((b,), pos, jnp.int32) if pos.ndim == 0
                 else pos)
         return _stacked_decode_attention(
-            q, k_new, v_new, cache, layer, posv,
+            q, k_new, v_new, cache, layer, posv, live=live,
             kind=kind if quant else None, scale=1.0 / np.sqrt(d))
     k_in, v_in, ks_in, vs_in = _cache_planes(cache, layer, quant)
     if quant:
@@ -1432,7 +1448,7 @@ def _decode_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos):
 
 
 def _paged_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos,
-                  table):
+                  table, live=None):
     """:func:`_decode_attend` over the PAGED cache layout: ``cache`` is
     the stacked page pool (``[L, 2, num_pages, hl, P, d]`` array, or
     the quantized ``{"kv", "scale"}`` pytree of the same family) and
@@ -1454,7 +1470,7 @@ def _paged_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos,
     posv = (jnp.full((b,), pos, jnp.int32) if pos.ndim == 0 else pos)
     if _decode_attn_impl(cfg, s_max) == "kernel":
         return _stacked_decode_attention(
-            q, k_new, v_new, cache, layer, posv, table=table,
+            q, k_new, v_new, cache, layer, posv, table=table, live=live,
             kind=kind if quant else None, scale=1.0 / np.sqrt(d))
     k_in, v_in, ks_in, vs_in = _cache_planes(cache, layer, quant)
     if quant:
@@ -1489,7 +1505,7 @@ def _paged_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos,
 
 
 def _decode_layer(cfg: GPTConfig, p, x, cache, layer, pos, table=None,
-                  lora=None):
+                  lora=None, live=None):
     """Layer ``layer`` for one token: x [b, hidden] against the WHOLE
     stacked cache ``[L, 2, b, hl, S, d]`` (or the quantized ``{"kv",
     "scale"}`` pytree of the same shape family; under a paged cache —
@@ -1519,10 +1535,10 @@ def _decode_layer(cfg: GPTConfig, p, x, cache, layer, pos, table=None,
         with jax.named_scope("apex.decode.attn"):
             if table is None:
                 ctx, cache = _decode_attend(cfg, q, k_new, v_new, cache,
-                                            layer, pos)
+                                            layer, pos, live)
             else:
                 ctx, cache = _paged_attend(cfg, q, k_new, v_new, cache,
-                                           layer, pos, table)
+                                           layer, pos, table, live)
         out = ctx.reshape(b, hl)
         attn = row_parallel_linear(
             out, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
@@ -1557,7 +1573,7 @@ def _lm_head(cfg: GPTConfig, params, h):
 
 
 def decode_step(cfg: GPTConfig, params, cache, token, pos, table=None,
-                lora=None):
+                lora=None, live=None):
     """One decoding step: ``token [b] int32`` at position ``pos`` →
     (full-vocab fp32 logits ``[b, vocab]``, updated cache).
 
@@ -1581,6 +1597,12 @@ def decode_step(cfg: GPTConfig, params, cache, token, pos, table=None,
     delta at every dense seam; ids are DATA like the page table, so one
     compiled program serves every tenant mix, and id 0 (the pinned
     all-zero row) leaves base rows numerically exact.
+
+    ``live`` (optional ``[b] bool``) marks the rows whose logits the
+    caller will use. A dead row's K/V column is still written at its
+    ``pos``, but the decode kernels read none of its history and its
+    logits are those of a zero attention context: discard them. The
+    XLA fallback computes every row regardless.
 
     Sequence parallelism is stripped: decode has no sequence dim, and the
     SP gather/scatter would misread the batch dim as one.
@@ -1612,7 +1634,8 @@ def decode_step(cfg: GPTConfig, params, cache, token, pos, table=None,
         layer_p, layer, page = inp
         return _decode_layer(
             cfg, _cast_layer(cfg, layer_p), x, cache, layer, pos, table,
-            lora=None if page is None else (page, ids, scale)), None
+            lora=None if page is None else (page, ids, scale),
+            live=live), None
 
     # the cache rides the scan's CARRY, whole: the kernels address it
     # by layer index and write in place, so no layer's cache is sliced
@@ -1675,8 +1698,9 @@ def decode_steps(cfg: GPTConfig, params, cache, state, n: int, *,
 
     def body(carry, _):
         cache, st = carry
+        live = ~st["done"]
         logits, cache = decode_step(
-            cfg, params, cache, st["tok"], st["pos"], table, lora)
+            cfg, params, cache, st["tok"], st["pos"], table, lora, live)
         with jax.named_scope("apex.sample"):
             if draw_fn is None:
                 nxt = _sampling.draw_slots(
@@ -1687,7 +1711,6 @@ def decode_steps(cfg: GPTConfig, params, cache, state, n: int, *,
             lp = jnp.take_along_axis(
                 jax.nn.log_softmax(logits, axis=-1), nxt[:, None], axis=1
             )[:, 0]
-        live = ~st["done"]
         emit = jnp.where(live, nxt, pad)
         lp = jnp.where(live, lp, jnp.float32(0.0))
         remaining = st["remaining"] - live.astype(jnp.int32)

@@ -633,6 +633,10 @@ class Engine:
                     f"static)")
         self.cfg = cfg
         self.engine_cfg = ecfg
+        #: positions in one chunk of the decode read kernel's sweep of
+        #: a slot's horizon (a page, under the paged cache)
+        self.read_chunk = ecfg.page_size or gpt.decode_read_chunk(
+            cfg, ecfg.max_seq_len)
         self._mesh = mesh
         self._params = params
         self._sentinel = None  # lazily via recompile_sentinel()

@@ -1896,18 +1896,43 @@ class Scheduler:
             return False
         if not self._inflight:
             return True
-        # price each in-flight chunk at its max emission — decode_chunk
-        # for plain chunks, decode_chunk*(spec_k+1) for speculative
-        # ones (conservative: a spec chunk may emit fewer, in which
-        # case the next tick's fetch corrects the estimate)
+        cols = self._inflight_cols()
+        return any(
+            len(act.tokens) + cols.get(slot, 0) < act.request.max_tokens
+            for slot, act in self.active.items())
+
+    def _inflight_cols(self) -> Dict[int, int]:
+        """Columns already in flight for each active slot, every
+        in-flight chunk priced at its max emission — decode_chunk for
+        plain chunks, decode_chunk*(spec_k+1) for speculative ones
+        (conservative: a spec chunk may emit fewer, in which case the
+        next tick's fetch corrects the estimate)."""
         cols: Dict[int, int] = {}
         for handle, snapshot, _, _, _ in self._inflight:
             for slot, act in snapshot.items():
                 if self.active.get(slot) is act:
                     cols[slot] = cols.get(slot, 0) + handle.ncols
-        return any(
-            len(act.tokens) + cols.get(slot, 0) < act.request.max_tokens
-            for slot, act in self.active.items())
+        return cols
+
+    def _count_decode_chunks(self) -> None:
+        """Decode-read counts into the recorder, at a dispatch: the
+        chunks of the horizon that the live slots' fills need at the
+        chunk's first step (``pos // read_chunk + 1`` each, ``pos`` the
+        host's view: prompt + tokens emitted or in flight - 1), and the
+        chunks the read kernel's grid holds (slots x chunks of the
+        horizon). Their ratio is the share of the grid that fetches
+        anything."""
+        eng = self.engine
+        bk = eng.read_chunk
+        last = eng.engine_cfg.max_seq_len - 1
+        cols = self._inflight_cols()
+        count = self.spans.count
+        count("decode.chunks_needed", sum(
+            min(len(act.request.prompt) + len(act.tokens)
+                + cols.get(slot, 0) - 1, last) // bk + 1
+            for slot, act in self.active.items()))
+        count("decode.chunks_grid",
+              eng.engine_cfg.slots * (last // bk + 1))
 
     def _exclusion_cause(self) -> Optional[str]:
         """THE per-slot exclusion conditions, as a cause: a
@@ -2002,6 +2027,8 @@ class Scheduler:
                 point = None
         else:
             step_kw["spec"] = self._use_spec()
+        if self.spans is not None:
+            self._count_decode_chunks()
         try:
             # the host-side cost of getting the chunk onto the device —
             # the half of the old engine.step section the pipeline

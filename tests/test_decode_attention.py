@@ -11,6 +11,7 @@ held to the per-layer calls for every layer, and a greedy and a sampled
 PR 24 emitted."""
 
 import dataclasses
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +38,10 @@ from apex_tpu.kernels.decode_attention import (
 )
 from apex_tpu.models import gpt
 from apex_tpu.transformer.testing import standalone_gpt_config
+
+# the module, not the function the package re-exports under its name
+decode_attention_mod = importlib.import_module(
+    "apex_tpu.kernels.decode_attention")
 
 _TOL = {
     jnp.float32: dict(rtol=2e-5, atol=2e-5),
@@ -272,15 +277,16 @@ def test_decode_attention_validation():
 _L, _B, _H, _S, _D, _PAGE = 3, 3, 2, 32, 32, 8
 
 
-def _stacked_case(layout, kind):
+def _stacked_case(layout, kind, b=_B, h=_H, s=_S, d=_D, page=_PAGE,
+                  pos=None):
     """A random stacked cache (or page pool + block tables) of ``_L``
     layers in the storage of ``kind``, with one step's rows."""
     ks = jax.random.split(jax.random.PRNGKey(3), 6)
     dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
     mk = lambda k, shp: (jax.random.normal(k, shp) * 0.5).astype(dtype)
-    rows = _B * _S // _PAGE + 4 if layout == "paged" else _B
-    horizon = _PAGE if layout == "paged" else _S
-    raw = mk(ks[0], (_L, 2, rows, _H, horizon, _D))
+    rows = b * s // page + 4 if layout == "paged" else b
+    horizon = page if layout == "paged" else s
+    raw = mk(ks[0], (_L, 2, rows, h, horizon, d))
     if kind == "bf16":
         cache = raw
     else:
@@ -288,15 +294,15 @@ def _stacked_case(layout, kind):
         cache = {"kv": q, "scale": scale}
     table = None
     if layout == "paged":
-        # each row owns _S // _PAGE distinct pages, out of order
+        # each row owns s // page distinct pages, out of order
         perm = jax.random.permutation(ks[1], rows)
-        table = perm[:_B * (_S // _PAGE)].reshape(_B, -1).astype(jnp.int32)
+        table = perm[:b * (s // page)].reshape(b, -1).astype(jnp.int32)
     return dict(
         cache=cache, table=table, kind=None if kind == "bf16" else kind,
-        q=mk(ks[2], (_B, _H, _D)), k_new=mk(ks[3], (_B, _H, _D)),
-        v_new=mk(ks[4], (_B, _H, _D)),
-        cols=mk(ks[5], (2, _B, _H, 3, _D)),
-        pos=jnp.asarray([5, 0, _S - 4], jnp.int32))
+        q=mk(ks[2], (b, h, d)), k_new=mk(ks[3], (b, h, d)),
+        v_new=mk(ks[4], (b, h, d)),
+        cols=mk(ks[5], (2, b, h, 3, d)),
+        pos=jnp.asarray([5, 0, s - 4] if pos is None else pos, jnp.int32))
 
 
 def _layer_of(cache, layer):
@@ -383,6 +389,160 @@ def test_stacked_kernels_match_per_layer_calls(layout, kind, layer):
                              _per_layer_columns(c, planes))
 
 
+# ---------------------------------------------------------------------------
+# the read's grid: heads per step, chunks clamped to the fill, dead rows
+# ---------------------------------------------------------------------------
+
+_RB, _RH, _RD, _RBK = 5, 4, 32, 128
+
+_LIVE = {
+    "every_row": None,
+    "dead_first": [False, False, True, True, True],
+    "dead_last": [True, True, True, False, False],
+    "dead_adjacent": [True, False, False, True, True],
+    "all_but_one": [False, False, False, True, False],
+}
+
+
+def _read_case(layout, kind):
+    """One step over a cache whose horizon is two read chunks
+    (contiguous: ``_RBK`` positions each) or four (paged: pages of 8),
+    rows sitting at the chunk edges: 0, ``bk - 1``, ``bk``, ``S - 1``
+    and one in between."""
+    bk, s = (8, 32) if layout == "paged" else (_RBK, 2 * _RBK)
+    return dict(_stacked_case(layout, kind, b=_RB, h=_RH, s=s, d=_RD,
+                              page=8, pos=[0, bk - 1, bk, s - 1, bk + 3]),
+                bk=bk)
+
+
+def _xla_step(c, layer):
+    """The step's fp32 output through the module's ``*_xla`` write and
+    gather helpers and a materialised softmax."""
+    pos, table, kind = c["pos"], c["table"], c["kind"]
+    news = [c["k_new"][:, :, None], c["v_new"][:, :, None]]
+    planes = _layer_of(c["cache"], layer)
+    if kind:
+        quant = [quantize_kv_rows(n, kind) for n in news]
+        news = [quant[0][0], quant[0][1], quant[1][0], quant[1][1]]
+    if table is None:
+        planes = [decode_attention_mod.cache_write_columns_xla(p, n, pos)
+                  for p, n in zip(planes, news)]
+    else:
+        planes = [decode_attention_mod.paged_gather_xla(
+            decode_attention_mod.paged_write_columns_xla(p, n, table, pos),
+            table) for p, n in zip(planes, news)]
+    planes = [np.asarray(p, np.float32) for p in planes]
+    if kind:
+        k, v = (planes[0] * planes[1][..., None],
+                planes[2] * planes[3][..., None])
+    else:
+        k, v = planes
+    q = np.asarray(c["q"], np.float32)
+    s = np.einsum("bhd,bhsd->bhs", q, k) / np.sqrt(_RD)
+    valid = np.arange(k.shape[2])[None, None] <= np.asarray(pos)[:, None,
+                                                                 None]
+    s = np.where(valid, s, -1e30)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bhs,bhsd->bhd", p, v)
+
+
+def _assert_read_matches(c, *lives, layer=1):
+    """Under each liveness of ``lives`` (None: ``live`` not given), live
+    rows match the XLA reference at the file's tolerances and dead rows
+    are exact zeros; under a mask, live rows and the cache are bit for
+    bit what a step with every row live gives (a dead row's column is
+    still written)."""
+    step = jax.jit(lambda cache, layer, live=None: stacked_decode_attention(
+        c["q"], c["k_new"], c["v_new"], cache, layer, c["pos"],
+        table=c["table"], kind=c["kind"], live=live, block_k=_RBK))
+    want = _xla_step(c, layer)
+    tol = _QTOL[c["kind"]] if c["kind"] else _TOL[jnp.bfloat16]
+
+    def run(live):
+        out, cache = step(c["cache"], jnp.int32(layer), live)
+        out = np.asarray(out, np.float32)
+        assert np.isfinite(out).all()
+        alive = np.ones(_RB, bool) if live is None else np.asarray(live)
+        np.testing.assert_allclose(out[alive], want[alive], **tol)
+        np.testing.assert_array_equal(out[~alive], 0.0)
+        return out, cache, alive
+
+    full = None
+    for live in lives:
+        if live is None:
+            run(None)
+            continue
+        # one program serves every mask: the all-live one is a mask too
+        full = full or run(jnp.ones(_RB, bool))
+        out, cache, alive = run(jnp.asarray(live))
+        np.testing.assert_array_equal(out[alive], full[0][alive])
+        for g, w in zip(jax.tree.leaves(cache), jax.tree.leaves(full[1])):
+            np.testing.assert_array_equal(np.asarray(g).view(np.uint8),
+                                          np.asarray(w).view(np.uint8))
+
+
+@pytest.mark.parametrize("live", list(_LIVE))
+@pytest.mark.parametrize("kind", ["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_read_at_chunk_edges_and_with_dead_rows(layout, kind, live):
+    """All six variants with rows at every chunk edge, and the rows'
+    liveness: dead rows first, last, adjacent, all but one."""
+    _assert_read_matches(_read_case(layout, kind), _LIVE[live])
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_read_with_heads_split_over_grid_steps(monkeypatch, layout, kind):
+    """A VMEM budget that holds two of the four heads: the grid gains
+    a head-group dimension and a dead row pins its last group."""
+    c = _read_case(layout, kind)
+    kv = jax.tree.leaves(c["cache"])[0]
+    fits = lambda: decode_attention_mod._heads_per_step(
+        _RH, _RD, c["bk"], kv.dtype, bool(c["kind"]))
+    for budget in (1 << n for n in range(10, 24)):
+        monkeypatch.setattr(decode_attention_mod, "_KV_VMEM_BUDGET", budget)
+        if fits() >= 2:
+            break
+    assert fits() == 2
+    _assert_read_matches(c, _LIVE["dead_adjacent"], _LIVE["dead_first"])
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+@pytest.mark.parametrize("live", list(_LIVE) + ["none"])
+def test_grid_walk_fetches_only_the_chunks_live_rows_need(live, groups):
+    """The index maps alone, walked over the grid in Python: the
+    blocks change ``groups * sum(pos // bk + 1 over live rows)`` times
+    (a step that names the block already resident copies nothing), a
+    live row's blocks are its own chunks in order, and a dead row
+    names the block the row before it left."""
+    bk, chunks = 16, 4
+    pos = np.asarray([0, 15, 16, 63, 35], np.int32)
+    alive = (np.zeros(5, bool) if live == "none" else
+             np.ones(5, bool) if _LIVE[live] is None else
+             np.asarray(_LIVE[live]))
+    given = None if live != "none" and _LIVE[live] is None else \
+        jnp.asarray(alive)
+    pos_k = np.where(alive, pos, -1)
+    row, pin = np.asarray(decode_attention_mod._fetch_table(
+        jnp.asarray(pos_k), given, bk, groups, chunks))
+    walk = []
+    for i in range(len(pos)):
+        for g in range(groups):
+            for j in range(chunks):
+                block = tuple(int(x) for x in
+                              decode_attention_mod._block_index(
+                                  g, j, int(pos_k[i]), int(row[i]),
+                                  int(pin[i]), bk, chunks))
+                if alive[i]:
+                    assert block == (i, g, min(j, pos[i] // bk))
+                if not walk or walk[-1] != block:
+                    walk.append(block)
+    needed = groups * int(sum(p // bk + 1 for p in pos[alive]))
+    assert len(walk) == max(needed, 1)
+    assert len(set(walk)) == len(walk)      # nothing is fetched twice
+
+
 def test_stacked_decode_attention_validation():
     z3 = jnp.zeros((_B, _H, _D))
     cache = jnp.zeros((_L, 2, _B, _H, _S, _D))
@@ -393,6 +553,9 @@ def test_stacked_decode_attention_validation():
         stacked_decode_attention(z3, z3, z3, cache[:, :, :2], 0, pos)
     with pytest.raises(ValueError, match="pos must be"):
         stacked_decode_attention(z3, z3, z3, cache, 0, pos[:2])
+    with pytest.raises(ValueError, match="live must be"):
+        stacked_decode_attention(z3, z3, z3, cache, 0, pos,
+                                 live=jnp.ones((2,), bool))
     with pytest.raises(ValueError, match="unknown quantized-KV kind"):
         stacked_decode_attention(z3, z3, z3, cache, 0, pos, kind="int4")
 

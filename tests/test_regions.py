@@ -311,6 +311,54 @@ def test_prefill_counts_sum_to_what_was_admitted(span_rows, tiny_engine):
     assert len(admits) <= total["prefill.dispatches"] <= len(prompts)
 
 
+def test_the_count_vocabulary_is_what_a_served_script_records(span_rows):
+    """Every count a run records is one of the six names docs/API.md
+    lists, and a run that admits and decodes records all six."""
+    assert {e[2] for e in span_rows[0] if e[0] == 2} == {
+        "prefill.tokens_real", "prefill.tokens_padded", "prefill.rows",
+        "prefill.dispatches", "decode.chunks_needed", "decode.chunks_grid"}
+
+
+def test_decode_chunk_counts_of_the_served_script(span_rows, tiny_engine):
+    """One pair of counts a decode dispatch; the grid's count is slots
+    x chunks of the horizon every time, and what the fills need never
+    passes it. The tiny engine's horizon is one chunk, so a dispatch
+    needs as many chunks as it has live slots: at most both."""
+    rows = span_rows[0]
+    needed = [e[3] for e in rows if e[2] == "decode.chunks_needed"]
+    grid = [e[3] for e in rows if e[2] == "decode.chunks_grid"]
+    dispatches = [e for e in rows if e[0] == 1 and e[2] == "engine.dispatch"]
+    assert len(needed) == len(grid) == len(dispatches) > 0
+    ecfg = tiny_engine.engine_cfg
+    assert tiny_engine.read_chunk == ecfg.max_seq_len
+    assert set(grid) == {ecfg.slots}
+    assert all(1 <= n <= g for n, g in zip(needed, grid))
+
+
+def test_decode_chunks_needed_equals_the_hand_count(devices8):
+    """Two requests on a paged engine whose read chunk is a page of 8:
+    prompts of 3 and 7 tokens, four tokens each, two a chunk. At the
+    first dispatch each slot holds its prompt and one token (positions
+    3 and 7: one chunk each); at the second two more (5 and 9: one and
+    two). The grid is 2 slots x 3 pages both times."""
+    cfg = standalone_gpt_config(vocab_size=96, seq_len=64)
+    mesh = mx.build_mesh(tp=1, devices=devices8[:1])
+    spans = SpanRecorder()
+    with Engine(cfg, gpt.init(cfg, jax.random.PRNGKey(0)), mesh,
+                EngineConfig(slots=2, max_prompt_len=8, max_seq_len=24,
+                             decode_chunk=2, page_size=8)) as eng:
+        assert eng.read_chunk == 8
+        sched = Scheduler(eng, clock=_Clock(), spans=spans)
+        sched.submit(Request("a", [1, 2, 3], max_tokens=4))
+        sched.submit(Request("b", [4, 5, 6, 7, 8, 9, 10], max_tokens=4))
+        sched.run_until_idle()
+        assert set(sched.completions) == {"a", "b"}
+    counts = [(e[2], e[3]) for e in spans.events()
+              if e[0] == 2 and e[2].startswith("decode.")]
+    assert counts == [("decode.chunks_needed", 2), ("decode.chunks_grid", 6),
+                      ("decode.chunks_needed", 3), ("decode.chunks_grid", 6)]
+
+
 def test_without_a_recorder_nothing_is_annotated(tiny_engine, monkeypatch):
     from apex_tpu import profiler
 

@@ -64,13 +64,28 @@ def mosaic(monkeypatch):
     compilation_cache.reset_cache()
 
 
+def _read_grids(jaxpr):
+    """The grid of every ``decode_attn_read`` kernel in ``jaxpr``,
+    loops and calls included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and \
+                "decode_attn_read" in str(eqn.params.get("name")):
+            found.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _read_grids(sub)
+    return found
+
+
 @pytest.mark.parametrize("kind", [None, "int8", "fp8"])
 @pytest.mark.parametrize("layout", ["contiguous", "paged"])
 def test_stacked_decode_kernels_compile_for_v5e(topo, mosaic, layout, kind):
-    """GPT-2's head shapes (16 heads of 64) at a horizon of 1024: the
-    layer-indexed write and read kernels, and the T-column write, on a
-    stacked cache / page pool in each storage."""
-    layers, b, h, s_max, d, page = 2, 8, 16, 1024, 64, 128
+    """GPT-2's head shapes (16 heads of 64) at a horizon of 1024 and
+    the serving cells' 40 slots: the layer-indexed write and read
+    kernels, and the T-column write, on a stacked cache / page pool in
+    each storage; the read's grid is rows x head groups x chunks, all
+    16 heads in one group."""
+    layers, b, h, s_max, d, page = 2, 40, 16, 1024, 64, 128
     one = SingleDeviceSharding(topo.devices[0])
     arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
     rows, horizon = (b * s_max // page, page) if layout == "paged" \
@@ -84,16 +99,21 @@ def test_stacked_decode_kernels_compile_for_v5e(topo, mosaic, layout, kind):
         if layout == "paged" else None
     row = arr((b, h, d), jnp.bfloat16)
 
-    def step(cache, layer, q, k_new, v_new, cols, pos, table):
+    def step(cache, layer, q, k_new, v_new, cols, pos, live, table):
         out, cache = da.stacked_decode_attention(
-            q, k_new, v_new, cache, layer, pos, table=table, kind=kind)
+            q, k_new, v_new, cache, layer, pos, table=table, live=live,
+            kind=kind)
         return out, da.stacked_write_columns(
             cols, cols, cache, layer, pos, table=table, kind=kind)
 
-    text = jax.jit(step, donate_argnums=0).lower(
+    traced = jax.jit(step, donate_argnums=0).trace(
         cache, arr((), jnp.int32), row, row, row,
-        arr((b, h, 3, d), jnp.bfloat16), arr((b,), jnp.int32), table
-    ).compile().as_text()
+        arr((b, h, 3, d), jnp.bfloat16), arr((b,), jnp.int32),
+        arr((b,), jnp.bool_), table)
+    bk = page if layout == "paged" else da.decode_block_k(
+        s_max, jax.tree.leaves(cache)[0].dtype, quantized=bool(kind))
+    assert _read_grids(traced.jaxpr.jaxpr) == [(b, 1, s_max // bk)]
+    text = traced.lower().compile().as_text()
     calls = lambda name: re.findall(
         rf"^\s*%{name}[.\d]* = .* custom-call\(", text, re.M)
     assert len(calls("decode_attn_write")) == 4
@@ -131,10 +151,14 @@ def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
         gpt.param_specs(cfg))
     eng = PlanEngine(cfg, params, mesh, ecfg)
     cache, state = jax.eval_shape(eng.init_program, params)
-    text = eng._step_variants[ecfg.decode_chunk].lower(
+    traced = eng._step_variants[ecfg.decode_chunk].trace(
         params, cache, state,
-        jax.ShapeDtypeStruct((ecfg.slots, cfg.vocab_size), jnp.bool_)
-    ).compile().as_text()
+        jax.ShapeDtypeStruct((ecfg.slots, cfg.vocab_size), jnp.bool_))
+    # one read kernel in the program: slots x one group of all four
+    # heads x the chunks of the horizon
+    assert _read_grids(traced.jaxpr.jaxpr) == [
+        (ecfg.slots, 1, -(-ecfg.max_seq_len // eng.read_chunk))]
+    text = traced.lower().compile().as_text()
     whole = ",".join(map(str, cache.shape))
     layer = ",".join(map(str, cache.shape[1:]))
     yields = lambda dims: [
