@@ -468,44 +468,24 @@ def _qkv_project(cfg: GPTConfig, p, x, *, sequence_parallel=False,
         for i, o in enumerate(outs))
 
 
-def _attention(cfg: GPTConfig, p, h, *, return_kv: bool = False,
-               lora=None):
-    """h: [b, s(_local under SP), hidden] → same shape. With
-    ``return_kv`` also returns the per-head (k, v) ``[b, heads_local, s,
-    head_dim]`` — the cache entries bulk prefill captures — so the
-    projection/layout logic stays single-sourced. ``lora`` is the
-    per-layer ``(page, ids, scale)`` adapter bundle (serving prefill
-    only): qkv slabs and the output projection gain their per-row
-    low-rank deltas."""
-    sp = cfg.sequence_parallel
-    lq = None if lora is None else (lora[0]["qkv"],) + lora[1:]
-    q, k, v = _qkv_project(cfg, p["qkv"], h, sequence_parallel=sp,
-                           lora=lq)
-    b, s, hl = q.shape           # [b, s_full, h_local] each
-    d = cfg.head_dim
-    heads_local = hl // d
-    out = _attention_ctx(cfg, q, k, v, heads_local)
-    proj = row_parallel_linear(
-        out, p["proj"]["kernel"], p["proj"]["bias"], axis=cfg.axis,
-        sequence_parallel=sp, sequence_dim=1,
-    )
-    if lora is not None:
-        page, ids, scale = lora
-        proj = proj + _lora_delta(out, page["proj"]["a"],
-                                  page["proj"]["b"], ids, scale,
-                                  axis=cfg.axis)
-    if return_kv:
-        split = lambda t: jnp.transpose(
-            t.reshape(b, s, heads_local, d), (0, 2, 1, 3))
-        return proj, (split(k), split(v))
-    return proj
+def _split_heads(x, d: int):
+    """``[b, s, heads * d]`` → head-major ``[b, heads, s, d]``."""
+    b, s, hl = x.shape
+    return jnp.transpose(x.reshape(b, s, hl // d, d), (0, 2, 1, 3))
+
+
+def _merge_heads(x):
+    """:func:`_split_heads` undone: ``[b, heads, s, d]`` → ``[b, s,
+    heads * d]``."""
+    b, heads, s, d = x.shape
+    return jnp.transpose(x, (0, 2, 1, 3)).reshape(b, s, heads * d)
 
 
 def _attention_ctx(cfg: GPTConfig, q, k, v, heads_local: int):
     """Core attention from the projected ``q/k/v [b, s, hidden_local]``
     slabs to the pre-projection context ``[b, s, hidden_local]`` — the
     impl/layout dispatch shared by training and bulk prefill."""
-    b, s, hl = q.shape
+    _, s, hl = q.shape
     d = hl // heads_local
     impl = cfg.attn_impl
     if impl == "auto":
@@ -548,9 +528,7 @@ def _attention_ctx(cfg: GPTConfig, q, k, v, heads_local: int):
         out = flash_attention_bsh(
             q, k, v, num_heads=heads_local, causal=cfg.causal)
         return out  # [b, s, hidden_local]
-    # [b, heads_local, s, d] each
-    q, k, v = (jnp.transpose(t.reshape(b, s, heads_local, d), (0, 2, 1, 3))
-               for t in (q, k, v))
+    q, k, v = (_split_heads(t, d) for t in (q, k, v))
     if cfg.context_parallel:
         out = ring_attention(q, k, v, axis=cfg.cp_axis, causal=cfg.causal,
                              zigzag=cfg.cp_zigzag)
@@ -565,7 +543,7 @@ def _attention_ctx(cfg: GPTConfig, q, k, v, heads_local: int):
                 lax.broadcasted_iota(jnp.int32, (s, s), 1))
         p_attn = _xla_attn_probs(cfg, q, k, tri)
         out = jnp.einsum("bhqk,bhkd->bhqd", p_attn, v)
-    return jnp.transpose(out, (0, 2, 1, 3)).reshape(b, s, heads_local * d)
+    return _merge_heads(out)
 
 
 def _xla_attn_probs(cfg: GPTConfig, q, k, mask):
@@ -650,39 +628,78 @@ def _moe_cfg(cfg: GPTConfig) -> moe_mod.MoEConfig:
         dispatch=cfg.moe_dispatch)
 
 
-def _block(cfg: GPTConfig, p, h, *, return_kv: bool = False,
-           lora=None):
-    """One transformer layer; returns ``(h, aux)`` — aux is the MoE
-    load-balance term, 0 for the dense MLP — plus the attention (k, v)
-    when ``return_kv`` (bulk prefill's cache capture). ``lora`` is the
-    per-layer ``(page, ids, scale)`` adapter bundle (serving prefill
-    only — training never threads it)."""
-    with jax.named_scope("apex.attn"):
-        x = _layer_norm(cfg, h, p["ln1"]["scale"], p["ln1"]["bias"])
-        attn = _attention(cfg, p["attn"], x, return_kv=return_kv,
-                          lora=lora)
-        kv = None
-        if return_kv:
-            attn, kv = attn
-        h = h + attn
-    if cfg.num_experts and cfg.sequence_parallel:
+def _ffn(cfg: GPTConfig, p, x, lora=None):
+    """The feed-forward half of a layer on the second LayerNorm's
+    output ``x [..., hidden]`` → ``(y, aux)``: the dense MLP (``aux``
+    0), or with ``num_experts`` the expert FFN over the tokens
+    flattened to ``[n, hidden]`` (``aux`` its load-balance term)."""
+    if not cfg.num_experts:
+        return _mlp(cfg, p["mlp"], x, lora=lora), jnp.float32(0.0)
+    if cfg.sequence_parallel:
         raise ValueError(
             "num_experts > 0 does not compose with sequence_parallel "
             "(MoE routes over full-h activations); shard the batch "
             "over ep instead")
+    y, aux = moe_mod.moe_ffn(
+        _moe_cfg(cfg), p["moe"], x.reshape(-1, x.shape[-1]))
+    return y.reshape(x.shape), aux
+
+
+def _layer(cfg: GPTConfig, p, x, attend, *, lora=None):
+    """THE transformer layer, for every entry point: LayerNorm → fused
+    QKV → ``attend`` → output projection → residual → LayerNorm →
+    feed-forward → residual, on ``x [b, hidden]`` (one decoded token)
+    or ``[b, s, hidden]``. Returns ``(x, aux, carried)``.
+
+    ``attend(q, k, v)`` is what differs between entry points: it takes
+    the projected ``[..., h_local]`` slabs and returns the
+    pre-projection context in the same layout, plus whatever its caller
+    carries out of the layer (cold prefill's per-head ``(k, v)``, the
+    updated cache, the tail's K/V) — the reshapes to heads are its own.
+    ``lora`` is the per-layer ``(page, ids, scale)`` adapter bundle
+    (serving only; training never threads it): the four dense seams
+    gain their per-row low-rank deltas. ``aux`` is the MoE load-balance
+    term, 0 for the dense MLP."""
+    sp = cfg.sequence_parallel
+    with jax.named_scope("apex.attn"):
+        y = _layer_norm(cfg, x, p["ln1"]["scale"], p["ln1"]["bias"])
+        lq = None if lora is None else (lora[0]["qkv"],) + lora[1:]
+        q, k, v = _qkv_project(cfg, p["attn"]["qkv"], y,
+                               sequence_parallel=sp, lora=lq)
+        ctx, carried = attend(q, k, v)
+        attn = row_parallel_linear(
+            ctx, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
+            axis=cfg.axis, sequence_parallel=sp, sequence_dim=1)
+        if lora is not None:
+            page, ids, scale = lora
+            attn = attn + _lora_delta(ctx, page["proj"]["a"],
+                                      page["proj"]["b"], ids, scale,
+                                      axis=cfg.axis)
+        x = x + attn
     with jax.named_scope("apex.mlp"):
-        x = _layer_norm(cfg, h, p["ln2"]["scale"], p["ln2"]["bias"])
-        if cfg.num_experts:
-            b, s, hd = x.shape
-            y, aux = moe_mod.moe_ffn(
-                _moe_cfg(cfg), p["moe"], x.reshape(b * s, hd))
-            h = h + y.reshape(b, s, hd)
-        else:
-            h, aux = (h + _mlp(cfg, p["mlp"], x, lora=lora),
-                      jnp.float32(0.0))
-    if return_kv:
-        return h, aux, kv
-    return h, aux
+        y = _layer_norm(cfg, x, p["ln2"]["scale"], p["ln2"]["bias"])
+        y, aux = _ffn(cfg, p, y, lora=lora)
+        return x + y, aux, carried
+
+
+def _block(cfg: GPTConfig, p, h, *, return_kv: bool = False,
+           lora=None):
+    """:func:`_layer` over a whole sequence ``h [b, s(_local under SP),
+    hidden]`` with the training-path attention (:func:`_attention_ctx`)
+    — training, the pipeline, the BERT encoder and cold prefill.
+    Returns ``(h, aux)``, plus the per-head ``(k, v) [b, heads_local,
+    s, head_dim]`` when ``return_kv`` (the cache entries bulk prefill
+    captures)."""
+    d = cfg.head_dim
+
+    def attend(q, k, v):         # [b, s_full, h_local] each
+        ctx = _attention_ctx(cfg, q, k, v, q.shape[-1] // d)
+        if not return_kv:
+            return ctx, None
+        return ctx, (_split_heads(k, d), _split_heads(v, d))
+
+    h, aux, kv = _layer(cfg, p, h, attend, lora=lora)
+    return (h, aux, kv) if return_kv else (h, aux)
 
 
 def _cp_slice(cfg: GPTConfig, x, dim: int):
@@ -1113,15 +1130,10 @@ def _lora_delta(x, a, b, ids, scale, *, axis: Optional[str] = None):
     ag = jnp.take(a, ids, axis=0)          # [B, r, din]
     bg = jnp.take(b, ids, axis=0)          # [B, r, dout]
     sc = jnp.asarray(scale, x.dtype)
-    if x.ndim == 2:
-        u = jnp.einsum("bh,brh->br", x, ag)
-        if axis is not None:
-            u = lax.psum(u, axis)
-        return jnp.einsum("br,brH->bH", u, bg) * sc
-    u = jnp.einsum("bth,brh->btr", x, ag)
+    u = jnp.einsum("b...h,brh->b...r", x, ag)
     if axis is not None:
         u = lax.psum(u, axis)
-    return jnp.einsum("btr,brH->btH", u, bg) * sc
+    return jnp.einsum("b...r,brH->b...H", u, bg) * sc
 
 
 def init_lora_pool(cfg: GPTConfig, params, n_adapters: int, rank: int):
@@ -1375,187 +1387,200 @@ def _layer_indices(cache):
     return jnp.arange(jax.tree.leaves(cache)[0].shape[0], dtype=jnp.int32)
 
 
-def _decode_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos,
-                   live=None):
-    """The decode-attention core shared by both cache layouts: write
-    this token's K/V at ``pos`` of layer ``layer`` and attend ``q``
-    over ``0..pos`` — returns ``(ctx [b, heads, d], cache)`` with
-    ``cache`` the whole stacked cache in the layout it came in (array
-    ``[L, 2, b, hl, S, d]``, or the quantized ``{"kv", "scale"}``
-    pytree). Dispatches on :func:`_decode_attn_impl`. The kernel path
-    hands the kernels the stacked cache and the layer index: the column
-    lands in place and no layer is sliced out or stacked back; under a
-    quantized layout it quantizes the incoming row and folds the scales
-    in per split-K chunk, and rows that ``live [b] bool`` marks dead
-    read nothing and come out as zeros. The XLA fallback slices the
-    layer out (:func:`_cache_planes`), quantizes/one-hot-writes both
-    planes, dequantizes the materialised cache before the score einsum,
-    and puts the layer back (:func:`_stack_planes`) — same semantics
-    for live rows (it computes every row and ignores ``live``),
-    CPU-testable."""
-    b, heads, d = q.shape
+def _cache_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos,
+                  table=None, live=None):
+    """THE cache-attention core, an ``attend`` of :func:`_layer`: write
+    this forward's K/V columns into layer ``layer`` of the stacked
+    cache and attend ``q`` over everything up to them. ``q/k_new/v_new``
+    are the projected slabs ``[b, h_local]`` (one column at ``pos`` —
+    a decode step) or ``[b, T, h_local]`` (columns ``pos[b] .. pos[b] +
+    T - 1``, row ``t`` attending ``0 .. pos[b] + t`` — the speculative
+    verify forward). Returns ``(ctx, cache)``: the context in ``q``'s
+    layout and the whole cache in the layout it came in — array ``[L,
+    2, b, hl, S, d]`` or the quantized ``{"kv", "scale"}`` pytree; with
+    ``table [b, max_pages] int32`` the stacked page pool ``[L, 2,
+    num_pages, hl, P, d]``, each row's logical horizon mapped onto
+    physical pages (the write lands at ``(layer, table[b, pos // P],
+    pos % P)``).
+
+    ``pos`` is a scalar (whole batch at one position: generate/beam,
+    one column only) or a ``[b]`` vector (per-slot positions: the
+    serving engine); the two are value-identical per row. Over-horizon
+    columns of a T-column write are dropped or clamped into
+    masked-garbage cells (:func:`apex_tpu.kernels.cache_write_columns_xla`).
+
+    Dispatches on :func:`_decode_attn_impl`. The kernels take the
+    stacked cache and the layer index: the columns land in place (one
+    window write a lane, quantized on the way under a quantized
+    storage) and no layer is sliced out or stacked back. One column is
+    then read by the split-K sweep with its online (out, lse) merge,
+    scales folded in per chunk, and rows that ``live [b] bool`` marks
+    dead read nothing and come out as zeros. T columns keep the
+    materialised read over the layer sliced out of the carry: T is tiny
+    (draft k + 1) and a T-row split-K sweep is future work
+    (docs/DESIGN.md). The XLA fallback — the CPU-testable backbone,
+    same semantics for live rows (it computes every row and ignores
+    ``live``) — slices the layer out (:func:`_cache_planes`), quantizes
+    the incoming rows once (the quantizer the kernels and prefill use),
+    writes every plane by one-hot select (a batched
+    ``dynamic_update_slice`` at per-row offsets is not expressible —
+    the full-cache rewrite the kernel exists to remove), puts the layer
+    back (:func:`_stack_planes`), and reads the row-contiguous view
+    (gathered through ``table`` when paged, dequantized when
+    quantized): a paged row reads the same bytes through the same
+    einsum as a contiguous one, which is what the paged == contiguous
+    stream oracle stands on."""
+    d = cfg.head_dim
+    b, hl = q.shape[0], q.shape[-1]
+    one = q.ndim == 2
+    if one:
+        heads = lambda z: z.reshape(b, hl // d, d)
+        cols = lambda z: z[:, :, None]
+    else:
+        t = q.shape[1]
+        heads = lambda z: _split_heads(z, d)
+        cols = lambda z: z
+    q, k_new, v_new = heads(q), heads(k_new), heads(v_new)
     kind = _kv_cache_dtype(cfg)
     quant = kind != "compute"
-    s_max = jax.tree.leaves(cache)[0].shape[4]
-    if _decode_attn_impl(cfg, s_max) == "kernel":
-        posv = (jnp.full((b,), pos, jnp.int32) if pos.ndim == 0
-                else pos)
-        return _stacked_decode_attention(
-            q, k_new, v_new, cache, layer, posv, live=live,
-            kind=kind if quant else None, scale=1.0 / np.sqrt(d))
-    k_in, v_in, ks_in, vs_in = _cache_planes(cache, layer, quant)
-    if quant:
-        # quantize the incoming rows ONCE (bit-identical to the kernel
-        # and prefill quantizers), then write both planes
-        k_new, k_s = quantize_kv_rows(k_new, kind)
-        v_new, v_s = quantize_kv_rows(v_new, kind)
-    if pos.ndim == 0:
-        upd = lambda c, n: lax.dynamic_update_slice_in_dim(
-            c, n[:, :, None].astype(c.dtype), pos, axis=2)
-        valid = (jnp.arange(s_max) <= pos)[None, None]        # [1, 1, S]
-    else:
-        hit4 = (jnp.arange(s_max)[None]
-                == pos[:, None])[:, None, :, None]
-        upd = lambda c, n: jnp.where(
-            hit4[..., 0] if c.ndim == 3 else hit4,
-            n[:, :, None].astype(c.dtype), c)
-        valid = (jnp.arange(s_max)[None] <= pos[:, None])[:, None]
-    k_cache = upd(k_in, k_new)
-    v_cache = upd(v_in, v_new)
-    if quant:
-        k_scale = upd(ks_in, k_s)
-        v_scale = upd(vs_in, v_s)
-        cache = _stack_planes(cache, layer, k_cache, v_cache, k_scale,
-                              v_scale)
-        # dequantize for the materialised-scores read (semantically the
-        # per-chunk dequant the kernel does in VMEM; off-TPU this is
-        # the correctness backbone, not the fast path)
-        k_cache = dequantize_kv(k_cache, k_scale, cfg.compute_dtype)
-        v_cache = dequantize_kv(v_cache, v_scale, cfg.compute_dtype)
-    else:
-        cache = _stack_planes(cache, layer, k_cache, v_cache)
-    # scale folded into q BEFORE the einsum: the unscaled dot
-    # product overflows fp16's 65504 range (same guard as the
-    # training path's compute-dtype branch). Keep in lockstep with
-    # _decode_attend_multi's read — the spec == plain parity oracle
-    # depends on the two expressions staying per-element identical
-    q = q * jnp.asarray(1.0 / np.sqrt(d), q.dtype)
-    scores = jnp.einsum(
-        "bhd,bhsd->bhs", q, k_cache).astype(jnp.float32)
-    scores = jnp.where(valid, scores, -1e30)
-    p_attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhs,bhsd->bhd", p_attn, v_cache), cache
-
-
-def _paged_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos,
-                  table, live=None):
-    """:func:`_decode_attend` over the PAGED cache layout: ``cache`` is
-    the stacked page pool (``[L, 2, num_pages, hl, P, d]`` array, or
-    the quantized ``{"kv", "scale"}`` pytree of the same family) and
-    ``table [b, max_pages] int32`` maps each row's logical horizon
-    chunk onto a physical page. The write lands at ``(layer, table[b,
-    pos // P], pos % P)``; the read sweeps the remapped pages of that
-    layer. Under the kernel impl both ride scalar-prefetched index maps
-    on the stacked pool, in place
-    (:func:`apex_tpu.kernels.stacked_decode_attention`); the XLA
-    fallback slices the layer's pool out, writes through the one-hot
-    page scatter and GATHERS the row-contiguous view, then applies the
-    EXACT contiguous score expression — same bytes, same einsum shapes,
-    so a paged row's logits are bit-identical to the contiguous cache's
-    (the paged == contiguous stream oracle)."""
-    b, heads, d = q.shape
-    kind = _kv_cache_dtype(cfg)
-    quant = kind != "compute"
-    s_max = table.shape[1] * jax.tree.leaves(cache)[0].shape[4]
-    posv = (jnp.full((b,), pos, jnp.int32) if pos.ndim == 0 else pos)
-    if _decode_attn_impl(cfg, s_max) == "kernel":
-        return _stacked_decode_attention(
-            q, k_new, v_new, cache, layer, posv, table=table, live=live,
-            kind=kind if quant else None, scale=1.0 / np.sqrt(d))
-    k_in, v_in, ks_in, vs_in = _cache_planes(cache, layer, quant)
-    if quant:
-        k_new, k_s = quantize_kv_rows(k_new, kind)
-        v_new, v_s = quantize_kv_rows(v_new, kind)
-    kp = _paged_write_columns_xla(k_in, k_new[:, :, None], table, posv)
-    vp = _paged_write_columns_xla(v_in, v_new[:, :, None], table, posv)
-    if quant:
-        ksp = _paged_write_columns_xla(ks_in, k_s[:, :, None], table,
-                                       posv)
-        vsp = _paged_write_columns_xla(vs_in, v_s[:, :, None], table,
-                                       posv)
-        cache = _stack_planes(cache, layer, kp, vp, ksp, vsp)
-        k_cache = dequantize_kv(_paged_gather_xla(kp, table),
-                                _paged_gather_xla(ksp, table),
-                                cfg.compute_dtype)
-        v_cache = dequantize_kv(_paged_gather_xla(vp, table),
-                                _paged_gather_xla(vsp, table),
-                                cfg.compute_dtype)
-    else:
-        cache = _stack_planes(cache, layer, kp, vp)
-        k_cache = _paged_gather_xla(kp, table)
-        v_cache = _paged_gather_xla(vp, table)
-    valid = (jnp.arange(s_max)[None] <= posv[:, None])[:, None]
-    # the contiguous XLA branch's expressions VERBATIM (bit-parity)
-    q = q * jnp.asarray(1.0 / np.sqrt(d), q.dtype)
-    scores = jnp.einsum(
-        "bhd,bhsd->bhs", q, k_cache).astype(jnp.float32)
-    scores = jnp.where(valid, scores, -1e30)
-    p_attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhs,bhsd->bhd", p_attn, v_cache), cache
-
-
-def _decode_layer(cfg: GPTConfig, p, x, cache, layer, pos, table=None,
-                  lora=None, live=None):
-    """Layer ``layer`` for one token: x [b, hidden] against the WHOLE
-    stacked cache ``[L, 2, b, hl, S, d]`` (or the quantized ``{"kv",
-    "scale"}`` pytree of the same shape family; under a paged cache —
-    ``table`` given — the stacked page pool ``[L, 2, num_pages, hl, P,
-    d]``); ``p`` is that layer's parameters. Returns ``(x, cache)``.
-
-    ``pos`` is the write/attend position — a scalar (whole batch at one
-    position: generate/beam) or a ``[b]`` vector (per-slot positions:
-    the continuous-batching engine). The two forms are value-identical
-    per row. Attention dispatches on :func:`_decode_attn_impl`: the
-    Pallas flash-decode kernels take the stacked cache and the layer
-    index, write the new K/V column in place and sweep the layer's
-    horizon with an online (out, lse) merge, while the XLA path slices
-    the layer out, writes by one-hot select under vector ``pos`` (a
-    batched ``dynamic_update_slice`` at per-row offsets is not
-    expressible — the full-cache rewrite the kernel exists to remove),
-    masks per row and puts the layer back."""
-    with jax.named_scope("apex.attn"):
-        xa = _layer_norm(cfg, x, p["ln1"]["scale"], p["ln1"]["bias"])
-        d = cfg.head_dim
-        b = xa.shape[0]
-        hl = p["attn"]["qkv"]["kernel"].shape[-1]
-        lq = None if lora is None else (lora[0]["qkv"],) + lora[1:]
-        q, k_new, v_new = (
-            t.reshape(b, hl // d, d)
-            for t in _qkv_project(cfg, p["attn"]["qkv"], xa, lora=lq))
-        with jax.named_scope("apex.decode.attn"):
-            if table is None:
-                ctx, cache = _decode_attend(cfg, q, k_new, v_new, cache,
-                                            layer, pos, live)
-            else:
-                ctx, cache = _paged_attend(cfg, q, k_new, v_new, cache,
-                                           layer, pos, table, live)
-        out = ctx.reshape(b, hl)
-        attn = row_parallel_linear(
-            out, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
-            axis=cfg.axis)
-        if lora is not None:
-            page, ids, scale = lora
-            attn = attn + _lora_delta(out, page["proj"]["a"],
-                                      page["proj"]["b"], ids, scale,
-                                      axis=cfg.axis)
-        x = x + attn
-    with jax.named_scope("apex.mlp"):
-        xb = _layer_norm(cfg, x, p["ln2"]["scale"], p["ln2"]["bias"])
-        if cfg.num_experts:
-            y, _ = moe_mod.moe_ffn(_moe_cfg(cfg), p["moe"], xb)  # aux unused
+    store = kind if quant else None     # what the kernels quantize to
+    span = jax.tree.leaves(cache)[0].shape[4]
+    s_max = span if table is None else table.shape[1] * span
+    kernel = _decode_attn_impl(cfg, s_max) == "kernel"
+    with jax.named_scope("apex.decode.attn"):
+        if pos.ndim == 0 and (kernel or table is not None):
+            # the kernels and the page table address row by row
+            pos = jnp.full((b,), pos, jnp.int32)
+        if kernel and one:
+            ctx, cache = _stacked_decode_attention(
+                q, k_new, v_new, cache, layer, pos, table=table,
+                live=live, kind=store, scale=1.0 / np.sqrt(d))
         else:
-            y = _mlp(cfg, p["mlp"], xb, lora=lora)
-        return x + y, cache
+            if kernel:
+                cache = _stacked_write_columns(
+                    k_new, v_new, cache, layer, pos, table=table,
+                    kind=store)
+                k_all, v_all, k_sc, v_sc = _cache_planes(
+                    cache, layer, quant)
+            else:
+                k_all, v_all, k_sc, v_sc = _cache_planes(
+                    cache, layer, quant)
+                if table is not None:
+                    write = lambda c, n: _paged_write_columns_xla(
+                        c, n, table, pos)
+                elif pos.ndim:
+                    write = lambda c, n: _cache_write_columns_xla(
+                        c, n, pos)
+                else:
+                    # the whole batch at one position: a slice write
+                    write = lambda c, n: lax.dynamic_update_slice_in_dim(
+                        c, n.astype(c.dtype), pos, axis=2)
+                if quant:
+                    k_new, k_new_sc = quantize_kv_rows(k_new, kind)
+                    v_new, v_new_sc = quantize_kv_rows(v_new, kind)
+                    k_sc = write(k_sc, cols(k_new_sc))
+                    v_sc = write(v_sc, cols(v_new_sc))
+                k_all = write(k_all, cols(k_new))
+                v_all = write(v_all, cols(v_new))
+                cache = _stack_planes(cache, layer, k_all, v_all, k_sc,
+                                      v_sc)
+            if table is not None:
+                k_all = _paged_gather_xla(k_all, table)
+                v_all = _paged_gather_xla(v_all, table)
+                if quant:
+                    k_sc = _paged_gather_xla(k_sc, table)
+                    v_sc = _paged_gather_xla(v_sc, table)
+            if quant:
+                k_all = dequantize_kv(k_all, k_sc, cfg.compute_dtype)
+                v_all = dequantize_kv(v_all, v_sc, cfg.compute_dtype)
+            # the materialised read: each query attends every cache
+            # column up to its own just-written one, and later ones are
+            # exact softmax zeros. The scale is folded into q BEFORE
+            # the einsum (the unscaled dot product overflows fp16's
+            # 65504 range). One column keeps its gemv and T columns
+            # their gemm: reading one column as T = 1 would change the
+            # plain path's compiled program and every stream pinned on
+            # it. The two reduce in different orders (~1e-7 relative
+            # off-TPU), so spec == plain holds to about an ulp and is
+            # margin-dependent in the stream — docs/DESIGN.md "Serving
+            # round 7", dead end (4)
+            if one:
+                valid = (jnp.arange(s_max)[None]
+                         <= pos.reshape(-1)[:, None])      # [b | 1, S]
+            else:
+                valid = (jnp.arange(s_max)[None, None] <= (
+                    pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+                )[:, :, None])                             # [b, T, S]
+            q = q * jnp.asarray(1.0 / np.sqrt(d), q.dtype)
+            scores = jnp.einsum(
+                "bhd,bhsd->bhs" if one else "bhtd,bhsd->bhts", q, k_all)
+            scores = jnp.where(valid[:, None],
+                               scores.astype(jnp.float32), -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            ctx = jnp.einsum(
+                "bhs,bhsd->bhd" if one else "bhts,bhsd->bhtd", probs,
+                v_all)
+    return (ctx.reshape(b, hl) if one else _merge_heads(ctx)), cache
+
+
+@jax.named_scope("apex.embed")
+def _embed_at(cfg: GPTConfig, params, tokens, pos):
+    """Entry activation of the cached forwards: ``tokens [b]`` (one
+    decoded token a row → ``[b, hidden]``) or ``[b, T]`` (→ ``[b, T,
+    hidden]``, column ``t`` at position ``pos + t``). ``pos`` is where
+    each row starts: a static int (every row at the same known
+    position: a slice of the position table), a traced scalar, or a
+    per-row ``[b]`` vector."""
+    one = tokens.ndim == 1
+    table = params["embedding"]["word"]["table"].astype(cfg.compute_dtype)
+    emb = vocab_parallel_embedding(
+        tokens[:, None] if one else tokens, table, axis=cfg.axis)
+    rows = params["embedding"]["position"]
+    if isinstance(pos, int):
+        pos_e = rows[pos:pos + tokens.shape[1]][None]
+    elif not one:
+        # over-horizon lanes (a near-budget row drafting past its last
+        # position) clamp their index — their logits are discarded by
+        # the accept logic, never emitted
+        pos_e = jnp.take(rows, jnp.minimum(
+            pos[:, None] + jnp.arange(tokens.shape[1],
+                                      dtype=jnp.int32)[None],
+            cfg.seq_len - 1), axis=0)
+    elif pos.ndim == 0:
+        pos_e = lax.dynamic_index_in_dim(rows, pos, 0, keepdims=False)
+    else:
+        pos_e = jnp.take(rows, pos, axis=0)
+    if one:
+        emb = emb[:, 0]
+    return (emb + pos_e.astype(cfg.compute_dtype)).astype(
+        cfg.compute_dtype)
+
+
+def _scan_cached_layers(cfg: GPTConfig, params, x, cache, pos, table,
+                        lora, live=None):
+    """``x`` through every layer against the cache
+    (:func:`_cache_attend`) → ``(x, cache)``. The cache rides the
+    scan's CARRY, whole: the kernels address it by layer index and
+    write in place, so no layer's cache is sliced out or stacked back.
+    Only the stacked parameters (and the LoRA pool, an ``xs`` leaf that
+    may be None) are sliced per layer, and that slicing carries the
+    scope ``apex.decode.layers`` alone."""
+    pool, ids, scale = lora if lora is not None else (None, None, None)
+
+    def body(carry, inp):
+        x, cache = carry
+        layer_p, layer, page = inp
+        x, _, cache = _layer(
+            cfg, _cast_layer(cfg, layer_p), x,
+            lambda q, k, v: _cache_attend(cfg, q, k, v, cache, layer, pos,
+                                          table, live),
+            lora=None if page is None else (page, ids, scale))
+        return (x, cache), None
+
+    xs = (params["layers"], _layer_indices(cache), pool)
+    with jax.named_scope("apex.decode.layers"):
+        (x, cache), _ = lax.scan(body, (x, cache), xs)
+    return x, cache
 
 
 @jax.named_scope("apex.lm_head")
@@ -1604,47 +1629,15 @@ def decode_step(cfg: GPTConfig, params, cache, token, pos, table=None,
     logits are those of a zero attention context: discard them. The
     XLA fallback computes every row regardless.
 
-    Sequence parallelism is stripped: decode has no sequence dim, and the
-    SP gather/scatter would misread the batch dim as one.
+    The sequence shardings are stripped (:func:`_decode_entry_cfg`):
+    decode has no sequence dim, and the SP gather/scatter would misread
+    the batch dim as one.
     """
-    if not cfg.causal:
-        raise ValueError(
-            "decoding is autoregressive; causal=False (the bidirectional "
-            "encoder mode) has no incremental-decode semantics")
-    if cfg.sequence_parallel:
-        cfg = dataclasses.replace(cfg, sequence_parallel=False)
+    cfg = _decode_entry_cfg(cfg, 1)
     pos = jnp.asarray(pos, jnp.int32)
-    with jax.named_scope("apex.embed"):
-        emb_t = params["embedding"]["word"]["table"].astype(
-            cfg.compute_dtype)
-        emb = vocab_parallel_embedding(token[:, None], emb_t,
-                                       axis=cfg.axis)
-        if pos.ndim == 0:
-            pos_e = lax.dynamic_index_in_dim(
-                params["embedding"]["position"], pos, 0, keepdims=False)
-        else:
-            pos_e = jnp.take(params["embedding"]["position"], pos, axis=0)
-        x = (emb[:, 0] + pos_e.astype(cfg.compute_dtype)).astype(
-            cfg.compute_dtype)
-
-    pool, ids, scale = lora if lora is not None else (None, None, None)
-
-    def body(carry, inp):
-        x, cache = carry
-        layer_p, layer, page = inp
-        return _decode_layer(
-            cfg, _cast_layer(cfg, layer_p), x, cache, layer, pos, table,
-            lora=None if page is None else (page, ids, scale),
-            live=live), None
-
-    # the cache rides the scan's CARRY, whole: the kernels address it
-    # by layer index and write in place, so no layer's cache is sliced
-    # out or stacked back. Only the stacked parameters (and the LoRA
-    # pool) are sliced per layer, and that slicing carries this scope
-    # alone
-    xs = (params["layers"], _layer_indices(cache), pool)
-    with jax.named_scope("apex.decode.layers"):
-        (x, cache), _ = lax.scan(body, (x, cache), xs)
+    x, cache = _scan_cached_layers(
+        cfg, params, _embed_at(cfg, params, token, pos), cache, pos,
+        table, lora, live)
     return _lm_head(cfg, params, x), cache
 
 
@@ -1793,163 +1786,6 @@ def ngram_drafts(hist, tok, k: int):
     return jnp.stack(out, axis=1)
 
 
-def _paged_attend_multi(cfg: GPTConfig, q, k_new, v_new, cache, layer,
-                        pos, table):
-    """:func:`_decode_attend_multi` over the paged layout: all T K/V
-    columns land through the paged multi-column write (Pallas
-    scalar-prefetch remap on the stacked pool, in place, under the
-    kernel impl; one-hot page scatter on the sliced-out layer under
-    XLA — over-horizon lanes clamp/drop into masked-garbage cells
-    exactly like the contiguous pair), then the T query rows attend the
-    GATHERED row-contiguous view of the layer's pool with the
-    contiguous verify path's exact materialised-scores expression — the
-    paged spec == contiguous spec parity stands on the gathered bytes
-    being identical."""
-    b, heads, t, d = q.shape
-    kind = _kv_cache_dtype(cfg)
-    quant = kind != "compute"
-    s_max = table.shape[1] * jax.tree.leaves(cache)[0].shape[4]
-    if _decode_attn_impl(cfg, s_max) == "kernel":
-        cache = _stacked_write_columns(
-            k_new, v_new, cache, layer, pos, table=table,
-            kind=kind if quant else None)
-        kp, vp, ksp, vsp = _cache_planes(cache, layer, quant)
-    else:
-        k_in, v_in, ks_in, vs_in = _cache_planes(cache, layer, quant)
-        ksp = vsp = None
-        if quant:
-            k_new, k_s = quantize_kv_rows(k_new, kind)
-            v_new, v_s = quantize_kv_rows(v_new, kind)
-            ksp = _paged_write_columns_xla(ks_in, k_s, table, pos)
-            vsp = _paged_write_columns_xla(vs_in, v_s, table, pos)
-        kp = _paged_write_columns_xla(k_in, k_new, table, pos)
-        vp = _paged_write_columns_xla(v_in, v_new, table, pos)
-        cache = _stack_planes(cache, layer, kp, vp, ksp, vsp)
-    k_cache = _paged_gather_xla(kp, table)
-    v_cache = _paged_gather_xla(vp, table)
-    if quant:
-        k_cache = dequantize_kv(k_cache, _paged_gather_xla(ksp, table),
-                                cfg.compute_dtype)
-        v_cache = dequantize_kv(v_cache, _paged_gather_xla(vsp, table),
-                                cfg.compute_dtype)
-    # the contiguous _decode_attend_multi read expressions VERBATIM
-    valid = (jnp.arange(s_max)[None, None]
-             <= (pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None])
-             [:, :, None])                        # [b, T, S]
-    q = q * jnp.asarray(1.0 / np.sqrt(d), q.dtype)
-    scores = jnp.einsum(
-        "bhtd,bhsd->bhts", q, k_cache).astype(jnp.float32)
-    scores = jnp.where(valid[:, None], scores, -1e30)
-    p_attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhts,bhsd->bhtd", p_attn, v_cache), cache
-
-
-def _decode_attend_multi(cfg: GPTConfig, q, k_new, v_new, cache, layer,
-                         pos):
-    """:func:`_decode_attend` for ``T`` tokens per row at positions
-    ``pos[b] .. pos[b] + T - 1`` — the speculative verify forward's
-    attention core. ``q/k_new/v_new [b, heads, T, d]``; writes all T
-    K/V columns of layer ``layer`` (multi-column masked write —
-    over-horizon lanes are dropped/clamped into the masked-garbage
-    region, see :func:`apex_tpu.kernels.cache_write_columns_xla`), then
-    attends each query row ``t`` over cache columns ``0 .. pos[b] + t``
-    with the SAME materialised-scores expression as the plain XLA
-    decode path — per-row values bit-identical to T sequential
-    :func:`_decode_attend` steps (the causal-exactness argument of
-    :func:`prefill_at`, applied to the cache horizon), which is what
-    the greedy spec == plain oracle stands on. The kernel impl lands
-    the columns in the stacked cache in place (one window-write pass
-    per lane) but keeps the materialised read, over the layer sliced
-    out of the carry: T is tiny (draft k + 1) and a T-row split-K
-    sweep is future work (docs/DESIGN.md)."""
-    b, heads, t, d = q.shape
-    kind = _kv_cache_dtype(cfg)
-    quant = kind != "compute"
-    s_max = jax.tree.leaves(cache)[0].shape[4]
-    if _decode_attn_impl(cfg, s_max) == "kernel":
-        cache = _stacked_write_columns(
-            k_new, v_new, cache, layer, pos,
-            kind=kind if quant else None)
-        k_cache, v_cache, k_scale, v_scale = _cache_planes(
-            cache, layer, quant)
-    else:
-        k_in, v_in, ks_in, vs_in = _cache_planes(cache, layer, quant)
-        k_scale = v_scale = None
-        if quant:
-            k_new, k_s = quantize_kv_rows(k_new, kind)
-            v_new, v_s = quantize_kv_rows(v_new, kind)
-            k_scale = _cache_write_columns_xla(ks_in, k_s, pos)
-            v_scale = _cache_write_columns_xla(vs_in, v_s, pos)
-        k_cache = _cache_write_columns_xla(k_in, k_new, pos)
-        v_cache = _cache_write_columns_xla(v_in, v_new, pos)
-        cache = _stack_planes(cache, layer, k_cache, v_cache, k_scale,
-                              v_scale)
-    if quant:
-        k_cache = dequantize_kv(k_cache, k_scale, cfg.compute_dtype)
-        v_cache = dequantize_kv(v_cache, v_scale, cfg.compute_dtype)
-    # row t attends over 0 .. pos + t (its own just-written column
-    # included, like the plain path); later verify columns are masked
-    # to exact softmax zeros. This expression MUST stay in lockstep
-    # with _decode_attend's XLA branch (scale folded into q in compute
-    # dtype, einsum output cast to f32, -1e30 mask, f32 softmax cast
-    # back); the einsum subscripts intentionally differ only by the T
-    # query dim (collapsing it here would change the plain path's
-    # compiled gemv and risk every pinned stream). Matching
-    # expressions is necessary but NOT sufficient for bit-parity: the
-    # T>1 gemm lowers to different reduction orders than the plain
-    # gemv (~1e-7 relative logit drift measured off-TPU), so the
-    # spec == plain stream oracle is margin-dependent — see
-    # docs/DESIGN.md "Serving round 7" dead end (4) for the caveat
-    # and the designated mitigation (tolerance in the accept-check)
-    valid = (jnp.arange(s_max)[None, None]
-             <= (pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None])
-             [:, :, None])                        # [b, T, S]
-    q = q * jnp.asarray(1.0 / np.sqrt(d), q.dtype)
-    scores = jnp.einsum(
-        "bhtd,bhsd->bhts", q, k_cache).astype(jnp.float32)
-    scores = jnp.where(valid[:, None], scores, -1e30)
-    p_attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhts,bhsd->bhtd", p_attn, v_cache), cache
-
-
-def _verify_layer(cfg: GPTConfig, p, x, cache, layer, pos, table=None,
-                  lora=None):
-    """:func:`_decode_layer` for ``T`` tokens per row: ``x [b, T,
-    hidden]`` at positions ``pos[b] + t``. Projections/LN/MLP are
-    per-position (row-independent matmuls — the :func:`prefill_extend`
-    argument), attention via :func:`_decode_attend_multi` (or its
-    paged sibling when ``table`` is given)."""
-    with jax.named_scope("apex.attn"):
-        xa = _layer_norm(cfg, x, p["ln1"]["scale"], p["ln1"]["bias"])
-        d = cfg.head_dim
-        b, t, _ = xa.shape
-        hl = p["attn"]["qkv"]["kernel"].shape[-1]
-        lq = None if lora is None else (lora[0]["qkv"],) + lora[1:]
-        q, k_new, v_new = (
-            jnp.transpose(z.reshape(b, t, hl // d, d), (0, 2, 1, 3))
-            for z in _qkv_project(cfg, p["attn"]["qkv"], xa, lora=lq))
-        with jax.named_scope("apex.decode.attn"):
-            if table is None:
-                ctx, cache = _decode_attend_multi(cfg, q, k_new, v_new,
-                                                  cache, layer, pos)
-            else:
-                ctx, cache = _paged_attend_multi(
-                    cfg, q, k_new, v_new, cache, layer, pos, table)
-        out = jnp.transpose(ctx, (0, 2, 1, 3)).reshape(b, t, hl)
-        attn = row_parallel_linear(
-            out, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
-            axis=cfg.axis)
-        if lora is not None:
-            page, ids, scale = lora
-            attn = attn + _lora_delta(out, page["proj"]["a"],
-                                      page["proj"]["b"], ids, scale,
-                                      axis=cfg.axis)
-        x = x + attn
-    with jax.named_scope("apex.mlp"):
-        xb = _layer_norm(cfg, x, p["ln2"]["scale"], p["ln2"]["bias"])
-        return x + _mlp(cfg, p["mlp"], xb, lora=lora), cache
-
-
 def decode_verify(cfg: GPTConfig, params, cache, tokens, pos,
                   table=None, lora=None):
     """The speculative verify forward: feed ``tokens [b, T] int32``
@@ -1964,7 +1800,8 @@ def decode_verify(cfg: GPTConfig, params, cache, tokens, pos,
     :func:`prefill_at` exactness argument applied to the decode
     horizon; equality is to ~1 ulp, not bitwise — the T>1 matmuls
     reduce in a different order than the plain gemv, see docs/DESIGN.md
-    "Serving round 7" dead end (4)). All T K/V columns land in the cache; a caller that
+    "Serving round 7" dead end (4)). All T K/V columns land in the
+    cache; a caller that
     accepts only a prefix leaves the rejected tail columns in place as
     masked-invalid garbage (``pos`` advances only over the accepted
     prefix, and decode masks/overwrites past-``pos`` columns — the
@@ -1974,48 +1811,17 @@ def decode_verify(cfg: GPTConfig, params, cache, tokens, pos,
     capacity depends on the routed token count, so a T-token forward
     routes differently than T single steps — divergence would be far
     beyond ulp level)."""
-    if not cfg.causal:
-        raise ValueError(
-            "decoding is autoregressive; causal=False (the bidirectional "
-            "encoder mode) has no incremental-decode semantics")
+    cfg = _decode_entry_cfg(cfg, 1)
     if cfg.num_experts:
         raise ValueError(
             "decode_verify does not support num_experts > 0 (expert "
             "capacity depends on the routed token count; a batched "
             "verify forward routes differently than sequential steps)")
-    if cfg.sequence_parallel or cfg.context_parallel:
-        cfg = dataclasses.replace(
-            cfg, sequence_parallel=False, context_parallel=False)
     pos = jnp.asarray(pos, jnp.int32)
     b, t = tokens.shape
-    with jax.named_scope("apex.embed"):
-        emb_t = params["embedding"]["word"]["table"].astype(
-            cfg.compute_dtype)
-        emb = vocab_parallel_embedding(tokens.astype(jnp.int32), emb_t,
-                                       axis=cfg.axis)
-        # over-horizon lanes (a near-budget row drafting past its last
-        # position) clamp their position-embedding index — their logits
-        # are discarded by the accept logic, never emitted
-        posn = jnp.minimum(
-            pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None],
-            cfg.seq_len - 1)
-        pos_e = jnp.take(params["embedding"]["position"], posn, axis=0)
-        x = (emb + pos_e.astype(cfg.compute_dtype)).astype(
-            cfg.compute_dtype)
-
-    pool, ids, scale = lora if lora is not None else (None, None, None)
-
-    def body(carry, inp):
-        x, cache = carry
-        layer_p, layer, page = inp
-        return _verify_layer(
-            cfg, _cast_layer(cfg, layer_p), x, cache, layer, pos, table,
-            lora=None if page is None else (page, ids, scale)), None
-
-    # the cache in the carry, as in decode_step
-    xs = (params["layers"], _layer_indices(cache), pool)
-    with jax.named_scope("apex.decode.layers"):
-        (x, cache), _ = lax.scan(body, (x, cache), xs)
+    x, cache = _scan_cached_layers(
+        cfg, params, _embed_at(cfg, params, tokens.astype(jnp.int32), pos),
+        cache, pos, table, lora)
     lg = _lm_head(cfg, params, x.reshape(b * t, cfg.hidden_size))
     return lg.reshape(b, t, -1), cache
 
@@ -2182,24 +1988,16 @@ def _prefill_states(cfg: GPTConfig, params, prompt, max_len: int,
         raise ValueError(f"prompt {p_len} exceeds cache max_len {max_len}")
     h = _embed(cfg, params, prompt.astype(jnp.int32))
 
-    if lora is None:
-        def body(carry, layer_p):
-            hh, _, kv = _block(cfg, _cast_layer(cfg, layer_p), carry,
-                               return_kv=True)
-            return hh, kv
+    pool, ids, scale = lora if lora is not None else (None, None, None)
 
-        xs = params["layers"]
-    else:
-        pool, ids, scale = lora
+    def body(carry, inp):
+        layer_p, page = inp
+        hh, _, kv = _block(
+            cfg, _cast_layer(cfg, layer_p), carry, return_kv=True,
+            lora=None if page is None else (page, ids, scale))
+        return hh, kv
 
-        def body(carry, inp):
-            layer_p, page = inp
-            hh, _, kv = _block(cfg, _cast_layer(cfg, layer_p), carry,
-                               return_kv=True,
-                               lora=(page, ids, scale))
-            return hh, kv
-
-        xs = (params["layers"], pool)
+    xs = (params["layers"], pool)
     with jax.named_scope("apex.layers"):
         h, (ks, vs) = lax.scan(body, h, xs)
     with jax.named_scope("apex.prefill.cache_insert"):
@@ -2292,12 +2090,17 @@ def prefill_extend(cfg: GPTConfig, params, prefix_kv, tail, last, *,
     prefill ALSO runs the materialised-scores attention (``attn_impl``
     resolving to "xla" — every off-TPU config, and short prompts
     on-TPU) every real position's hidden state, K/V entry, and the end
-    logits are bit-identical to a cold :func:`prefill_many` of the
-    concatenated prompt (the causal-padding-exactness argument of
-    :func:`prefill_at`, applied to a split prompt; the prefix-hit
-    oracle pins it). Under flash prefill the cold side's online-softmax
-    reduction order differs at the ulp level, so hit-vs-cold parity is
-    numerical there, not bitwise (docs/DESIGN.md "Serving round 6").
+    logits are those of a cold :func:`prefill_many` of the concatenated
+    prompt to a few ulp of the compute dtype — the same expressions
+    over the same values, but the rectangular ``[T, P + T]`` block and
+    the cold square one are differently shaped programs, and a backend
+    promises no reduction order across shapes (1.7 ulp on XLA:CPU) —
+    and the tokens decoded from them are the same (the
+    causal-padding-exactness argument of :func:`prefill_at`, applied to
+    a split prompt; the prefix-hit oracle pins the tokens). Under flash
+    prefill the cold side's online-softmax reduction order differs
+    too, so a near-tied token can flip there (docs/DESIGN.md "Serving
+    round 6").
     ``prefix_len`` is static — one compiled program per (prefix
     bucket, tail bucket), which is what keeps the serving engine's
     prefix admissions trace-stable."""
@@ -2318,14 +2121,7 @@ def prefill_extend(cfg: GPTConfig, params, prefix_kv, tail, last, *,
             "capacity depends on the routed token count; tail-only "
             "routing breaks prefix-hit == cold-prefill parity)")
     d = cfg.head_dim
-    with jax.named_scope("apex.embed"):
-        table = params["embedding"]["word"]["table"].astype(
-            cfg.compute_dtype)
-        emb = vocab_parallel_embedding(tail.astype(jnp.int32), table,
-                                       axis=cfg.axis)
-        pos_e = params["embedding"]["position"][
-            prefix_len:prefix_len + tb]
-        h = emb + pos_e[None].astype(cfg.compute_dtype)
+    h = _embed_at(cfg, params, tail.astype(jnp.int32), prefix_len)
     # static causal mask over [tail rows, prefix+tail cols]: a tail
     # query at local i (global prefix_len + i) sees the whole prefix
     # and tail columns j <= i; pad tail columns are only ever visible
@@ -2334,58 +2130,28 @@ def prefill_extend(cfg: GPTConfig, params, prefix_kv, tail, last, *,
                             prefix_len + jnp.arange(tb)])
     rowg = prefix_len + jnp.arange(tb)
     mask = (colg[None] <= rowg[:, None])[None, None]  # [1, 1, T, P+T]
+    pool, ids, scale = lora if lora is not None else (None, None, None)
 
-    def layer_body(p, pkv, carry, page, ids, scale):
-        # pkv [2, b, hl, prefix_len, d]; page = this layer's adapter
-        # pages (None = base). One body shared by the plain and
-        # adapter scans so the two can never diverge.
-        lo = None if page is None else (page, ids, scale)
-        lq = None if page is None else (page["qkv"], ids, scale)
-        with jax.named_scope("apex.attn"):
-            x = _layer_norm(cfg, carry, p["ln1"]["scale"],
-                            p["ln1"]["bias"])
-            qh, kh, vh = _qkv_project(cfg, p["attn"]["qkv"], x, lora=lq)
-            heads = qh.shape[-1] // d
-            split = lambda t: jnp.transpose(
-                t.reshape(b, tb, heads, d), (0, 2, 1, 3))
-            qs, kt, vt = split(qh), split(kh), split(vh)
+    def body(carry, inp):
+        layer_p, pkv, page = inp     # pkv [2, b, hl, prefix_len, d]
+
+        def attend(q, k, v):
+            qs, kt, vt = (_split_heads(t, d) for t in (q, k, v))
             k_full = jnp.concatenate([pkv[0], kt], axis=2)
             v_full = jnp.concatenate([pkv[1], vt], axis=2)
-            # THE shared score expression — attn_score_dtype semantics
-            # included, so hit and cold can never diverge here
+            # the cold forward's own score expression
+            # (attn_score_dtype semantics included), keys ordered
+            # prefix-then-tail: what hit == cold parity stands on
             p_attn = _xla_attn_probs(cfg, qs, k_full, mask)
             ctx = jnp.einsum("bhqk,bhkd->bhqd", p_attn, v_full)
-            out = jnp.transpose(ctx, (0, 2, 1, 3)).reshape(
-                b, tb, heads * d)
-            attn = row_parallel_linear(
-                out, p["attn"]["proj"]["kernel"],
-                p["attn"]["proj"]["bias"], axis=cfg.axis)
-            if page is not None:
-                attn = attn + _lora_delta(out, page["proj"]["a"],
-                                          page["proj"]["b"], ids, scale,
-                                          axis=cfg.axis)
-            hh = carry + attn
-        with jax.named_scope("apex.mlp"):
-            x2 = _layer_norm(cfg, hh, p["ln2"]["scale"], p["ln2"]["bias"])
-            hh = hh + _mlp(cfg, p["mlp"], x2, lora=lo)
+            return _merge_heads(ctx), (kt, vt)
+
+        hh, _, (kt, vt) = _layer(
+            cfg, _cast_layer(cfg, layer_p), carry, attend,
+            lora=None if page is None else (page, ids, scale))
         return hh, jnp.stack([kt, vt])
 
-    if lora is None:
-        def body(carry, inp):
-            layer_p, pkv = inp
-            return layer_body(_cast_layer(cfg, layer_p), pkv, carry,
-                              None, None, None)
-
-        xs = (params["layers"], prefix_kv)
-    else:
-        pool, ids, scale = lora
-
-        def body(carry, inp):
-            layer_p, pkv, page = inp
-            return layer_body(_cast_layer(cfg, layer_p), pkv, carry,
-                              page, ids, scale)
-
-        xs = (params["layers"], prefix_kv, pool)
+    xs = (params["layers"], prefix_kv, pool)
     with jax.named_scope("apex.layers"):
         h, tail_kv = lax.scan(body, h, xs)
     last = jnp.asarray(last, jnp.int32)

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from apex_tpu import mesh as mx
 from apex_tpu.amp import ScalerConfig
@@ -264,3 +265,100 @@ def test_clip_grad_norm_rejects_zero_optimizer(devices8):
         training.make_train_step(
             cfg, mesh, distributed_fused_adam(1e-3),
             ScalerConfig(enabled=False), clip_grad_norm=1.0)
+
+
+# -- one layer, one cache core ---------------------------------------------
+#: entry point -> does its trace go through the cache-attention core
+_ENTRY_POINTS = {
+    "loss": False, "pipeline_loss": False, "bert": False,
+    "prefill_many": False, "prefill_extend": False,
+    "decode_step": True, "decode_step_paged": True,
+    "decode_step_lora": True, "decode_verify": True,
+    "decode_verify_paged": True,
+}
+
+
+def _entry_point(entry, devices):
+    """``(fn, mesh, in_specs, args)``: entry point ``entry`` at the tiny
+    preset as a function for ``shard_map``, on abstract arguments."""
+    from apex_tpu.models import bert
+
+    cfg = gpt.GPTConfig(remat=True, **CFG)
+    mesh = mx.build_mesh(tp=1, devices=devices[:1])
+    params = jax.eval_shape(lambda: gpt.init(cfg, jax.random.PRNGKey(0)))
+    pspecs = gpt.param_specs(cfg)
+    b, s, page, t = 2, 32, 8, 3
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    tok, row = i32(b, 16), i32(b)
+    if entry == "loss":
+        return (lambda p, x: gpt.loss(cfg, p, x, x), mesh,
+                (pspecs, P()), (params, tok))
+    if entry == "pipeline_loss":
+        return (lambda p, x: gpt.pipeline_loss(cfg, p, x, x, n_micro=2),
+                mx.build_mesh(tp=1, pp=2, dp=1, devices=devices[:2]),
+                (gpt.param_specs(cfg, pipeline=True), P()), (params, tok))
+    if entry == "bert":
+        bcfg = bert.BertConfig(**CFG)
+        return (lambda p, x: bert.mlm_loss(bcfg, p, x, x, x), mesh,
+                (bert.param_specs(bcfg), P()),
+                (jax.eval_shape(
+                    lambda: bert.init(bcfg, jax.random.PRNGKey(0))), tok))
+    if entry == "prefill_many":
+        return (lambda p, x, last: gpt.prefill_many(
+            cfg, p, x, last, max_len=s), mesh, (pspecs, P(), P()),
+            (params, tok, row))
+    if entry == "prefill_extend":
+        prefix = jax.ShapeDtypeStruct(
+            (cfg.num_layers, 2, b, cfg.num_heads, 8, cfg.head_dim),
+            cfg.compute_dtype)
+        return (lambda p, kv, x, last: gpt.prefill_extend(
+            cfg, p, kv, x, last, prefix_len=8), mesh,
+            (pspecs, P(), P(), P()), (params, prefix, i32(b, 8), row))
+    paged = entry.endswith("_paged")
+    cache = jax.eval_shape(
+        lambda p: gpt.init_cache(cfg, p, b * s // page, page) if paged
+        else gpt.init_cache(cfg, p, b, s), params)
+    table = i32(b, s // page) if paged else None
+    if entry.startswith("decode_verify"):
+        return (lambda p, c, x, pos, tb: gpt.decode_verify(
+            cfg, p, c, x, pos, tb), mesh, (pspecs, P(), P(), P(), P()),
+            (params, cache, i32(b, t), row, table))
+    if entry == "decode_step_lora":
+        pool = jax.eval_shape(
+            lambda p: gpt.init_lora_pool(cfg, p, 3, 2), params)
+        return (lambda p, c, x, pos, pl, ids: gpt.decode_step(
+            cfg, p, c, x, pos, lora=(pl, ids, 0.5)), mesh,
+            (pspecs, P(), P(), P(), P(), P()),
+            (params, cache, row, row, pool, row))
+    return (lambda p, c, x, pos, tb: gpt.decode_step(
+        cfg, p, c, x, pos, tb), mesh, (pspecs, P(), P(), P(), P()),
+        (params, cache, row, row, table))
+
+
+@pytest.mark.parametrize("entry", sorted(_ENTRY_POINTS))
+def test_every_entry_point_runs_the_one_layer(devices8, monkeypatch, entry):
+    """``gpt._layer`` is THE transformer layer and ``gpt._cache_attend``
+    THE cache-attention core: every entry point's trace passes through
+    the first (a scan traces its body once, remat may trace it again),
+    and every cached forward — one column or T, contiguous or paged —
+    through the second. A fifth copy of the layer would leave its entry
+    point's count at zero."""
+    calls = {"_layer": 0, "_cache_attend": 0}
+
+    def counted(name):
+        real = getattr(gpt, name)
+
+        def wrapper(*args, **kwargs):
+            calls[name] += 1
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(gpt, name, wrapper)
+
+    counted("_layer")
+    counted("_cache_attend")
+    fn, mesh, in_specs, args = _entry_point(entry, devices8)
+    jax.eval_shape(jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=P(),
+        check_vma=False), *args)
+    assert calls["_layer"] >= 1
+    assert (calls["_cache_attend"] >= 1) == _ENTRY_POINTS[entry]

@@ -277,12 +277,29 @@ def test_prefix_registration_and_match(devices8):
         fresh.warmup()
 
 
+def _assert_within_ulps(got, want, why, ulps=4):
+    """``got`` within ``ulps`` units in the last place of ``want``'s
+    largest magnitude (float32, the tiny preset's compute dtype)."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    np.testing.assert_allclose(
+        got, want, rtol=0,
+        atol=ulps * np.finfo(np.float32).eps * np.abs(want).max(),
+        err_msg=why)
+
+
 def test_prefill_extend_matches_cold_compute_scores(devices8):
     """attn_score_dtype="compute" parity: prefill_extend shares THE
     materialised-scores expression with the cold path
-    (gpt._xla_attn_probs), so the end logits and tail K/V are
-    bit-identical to a cold prefill_many under BOTH score-dtype
-    branches."""
+    (gpt._xla_attn_probs), so under BOTH score-dtype branches the end
+    logits pick the same token as a cold prefill_many, and they and the
+    tail K/V match it to a few ulp. Not bitwise: the two are
+    differently shaped programs, and the backend promises no reduction
+    order across shapes."""
+    why = ("prefill_extend attends a rectangular [T, P + T] score block "
+           "and cold prefill a square [S, S] one; XLA:CPU reduces the two "
+           "in different orders (measured 1.7 ulp of the largest value), "
+           "so hit == cold holds to a few ulp of the compute dtype, not "
+           "bitwise: attn_score_dtype=")
     for sd in ("f32", "compute"):
         cfg = _cfg(seq_len=32, attn_score_dtype=sd)
         params = gpt.init(cfg, jax.random.PRNGKey(0))
@@ -311,11 +328,11 @@ def test_prefill_extend_matches_cold_compute_scores(devices8):
                        P(None, None, None, "tp", None, None),
                        P(None, None)), check_vma=False))(params, toks)
         np.testing.assert_array_equal(
-            np.asarray(hit_lg), np.asarray(cold_lg), err_msg=sd)
-        np.testing.assert_array_equal(
-            np.asarray(tail_kv[:, :, :, :, :2], np.float32),
-            np.asarray(cold_cache[:, :, :, :, 8:10], np.float32),
-            err_msg=sd)
+            np.argmax(np.asarray(hit_lg), -1),
+            np.argmax(np.asarray(cold_lg), -1), err_msg=sd)
+        _assert_within_ulps(hit_lg, cold_lg, why + sd)
+        _assert_within_ulps(tail_kv[:, :, :, :, :2],
+                            cold_cache[:, :, :, :, 8:10], why + sd)
 
 
 def test_prefix_pool_rejects_moe(devices8):

@@ -694,7 +694,8 @@ def test_admit_many_matches_single_admits(devices8):
     [k, bucket] prefill forward + one state/cache scatter — produces
     the SAME first tokens and the same subsequent decode streams as k
     single ``admit`` calls in the same order (greedy and sampled lanes,
-    mixed prompt lengths spanning buckets)."""
+    mixed prompt lengths spanning buckets), with logprobs equal to a
+    few ulp."""
     cfg = _cfg()
     params = gpt.init(cfg, jax.random.PRNGKey(0))
     mesh = mx.build_mesh(tp=1, devices=devices8[:1])
@@ -726,8 +727,17 @@ def test_admit_many_matches_single_admits(devices8):
         tb, lb, fb = eng_b.step()
         ts, ls, fs = eng_s.step()
         np.testing.assert_array_equal(tb, ts)
-        np.testing.assert_array_equal(lb, ls)
         np.testing.assert_array_equal(fb, fs)
+        # the [4, bucket] and [1, bucket] prefills are differently
+        # shaped programs, and XLA:CPU promises no reduction order
+        # across shapes: their caches, and so the logprobs decoded from
+        # them, agree to a few ulp of the compute dtype (measured 0.5
+        # ulp of the largest logprob), not bitwise
+        np.testing.assert_allclose(
+            lb, ls, rtol=0,
+            atol=4 * np.finfo(np.float32).eps * np.abs(ls).max(),
+            err_msg="logprobs of a batched admission vs single "
+            "admissions: the same tokens, float32 values within 4 ulp")
     # a 3-item call decomposes over the ladder largest-first: 2 + 1
     eng_b2 = Engine(cfg, params, mesh, ecfg)
     three = eng_b2.admit_many(items[:3])
