@@ -16,13 +16,12 @@ composed by :func:`decode_attention`:
   stacked cache ``[L, 2, b, h, S, d]`` and the layer is one more
   scalar-prefetch operand: the block index carries the leading
   ``(layer, plane)`` coordinates, so the model's layer scan carries the
-  cache and never slices a layer out or stacks it back. A cache
-  position is one ROW of a ``(sublane, lane)`` tile and Mosaic moves
-  whole tiles, so each grid step reads the one tile-high window that
-  holds ``pos[b]`` in the K and the V plane of that layer (8 rows f32 /
-  16 bf16 / 32 int8, fp8 — the block index is ``pos[b] // tile``),
-  replaces row ``pos[b] % tile`` and writes the window back; the rest
-  of the cache — every other layer included — is never touched;
+  cache and never slices a layer out or stacks it back. Mosaic moves
+  whole tiles, so each grid step reads the one tile-aligned window
+  that holds ``pos[b]`` in the K and the V plane of that layer,
+  replaces position ``pos[b] % window`` and writes the window back;
+  the rest of the cache — every other layer included — is never
+  touched;
 - **split-K read**: flash-decode attention — the cache horizon is swept
   in ``block_k`` chunks with a running online-softmax ``(out, lse)``
   merge (the same ``m/l/acc`` update as the training flash kernel),
@@ -39,6 +38,28 @@ composed by :func:`decode_attention`:
   the pipeline copies nothing, and a row the caller marks dead (a done
   slot) names the block the row before it left, computes nothing and
   comes out as zeros.
+
+**Which way the operand lies** (:func:`_positions_on_lanes`). The
+device does not keep ``[.., S, 64]`` row-major between programs: a
+minor dimension that does not fill the 128 lanes would be padded to
+them, so it lays the array out with ``S`` on the lanes and the head
+dim on the sublanes (``{4,5,3,2,1,0}``, unpadded). Both kernels
+therefore take ``swapaxes(cache, 4, 5)`` — a bitcast of that layout,
+no bytes move — with ``(d, bk)`` K and V blocks in the read (scores
+``q (hb, d) · k (d, bk)``, values ``p (hb, bk) · v (d, bk)ᵀ``, every
+mask along the lanes) and a window of 128 positions on the lanes in
+the write; the write's aliased output is swapped back, a bitcast
+again. A cache declared row-major to Mosaic instead was relaid, all of
+it, into a copy padded to twice its size at the entry of every program
+that ran a kernel and back at its exit (PERF.md, PR 25 and PR 30).
+Where the head dim fills the lanes (128) the array does lie row-major,
+and where the span of positions does not (pages of 16) the device puts
+something else there; both keep the row-major blocks — ``(bk, d)``
+chunks, a window one sublane tile high (8 rows f32 / 16 bf16 / 32
+int8, fp8), one cache position one ROW of a tile. One algorithm, the
+orientation read from head size, span and storage; the fp32 scale
+planes ``[L, 2, b, h, S]`` lie with the positions on the lanes either
+way.
 
 The stacked forms (:func:`stacked_decode_attention`,
 :func:`stacked_write_columns`) are what the model calls; the per-layer
@@ -95,14 +116,38 @@ def _sublane_tile(dtype) -> int:
     return 8 * (4 // jnp.dtype(dtype).itemsize)
 
 
+def _positions_on_lanes(d: int, span: int, dtype) -> bool:
+    """Which way the kernels take their K/V operand: True — ``(d,
+    bk)`` blocks of ``swapaxes(kv, 4, 5)``, the positions on the lanes
+    and the head dim on the sublanes; False — row-major ``(bk, d)``
+    blocks of ``kv``. THE rule, for both kernels and
+    :func:`_heads_per_step`, from what the code can see: head size
+    ``d``, the ``span`` of positions of one row of the operand (the
+    horizon ``S``, or the page ``P`` of a pool) and the storage
+    ``dtype``.
+
+    It follows the layout the device gives the array between programs
+    (the module docstring has the why): a minor dim that does not fill
+    the 128 lanes is not left minor, so ``[.., S, 64]`` lies with ``S``
+    on the lanes — in bf16, int8, fp8 and f32 alike — and the turned
+    view is a bitcast of it. A head dim of 128 lies row-major already;
+    under a span off the lane grid (pages of 16: the device puts the
+    PAGES on the lanes) neither view is a bitcast and the row-major
+    one is kept. The head dim must also fill whole sublane tiles of the
+    storage."""
+    return (d % _LANES != 0 and span % _LANES == 0
+            and d % _sublane_tile(dtype) == 0)
+
+
 def _fit_block_k(want: int, sk: int, align: int) -> int:
     """Split-K chunk for a horizon of ``sk``: the whole horizon as one
     block when it fits ``want`` (a full-dimension block is always
     tile-legal and sweeps nothing extra), else the largest halving of
     ``want`` that doesn't over-sweep by more than a quarter (same
     policy as flash's ``_fit_block``). ``align`` is the smallest chunk
-    Mosaic accepts: the cache dtype's sublane tile, or 128 when fp32
-    scale rows ride along on the lane dimension."""
+    Mosaic accepts: the cache dtype's sublane tile, or 128 when the
+    positions lie on the lanes (fp32 scale rows riding along, or the
+    storage itself: :func:`_positions_on_lanes`)."""
     if sk <= want:
         return sk
     b = max(want, align)
@@ -118,10 +163,15 @@ def _fit_block_k(want: int, sk: int, align: int) -> int:
 
 def _write_kernel(*refs, n_scalar, windows, page):
     """Land one column per cache operand. Each operand's block is the
-    K and the V plane's tile-aligned window of ``windows[k]`` positions
-    around ``pos`` (dim 3 of the block: sublanes of a ``[2, 1, h, w,
-    d]`` data block, lanes of a ``[2, 1, h, w]`` scale block); the
-    incoming block is one position wide and broadcasts over it."""
+    K and the V plane's tile-aligned window of ``w`` positions around
+    ``pos`` (``windows[k] == (w, lanes)``). Row-major storage ``[2, 1,
+    h, w, d]`` (the window down the sublanes) and scale planes ``[2,
+    1, h, w]`` take an incoming block one position wide, which
+    broadcasts over the window. Storage with the positions on the
+    lanes, ``[2, 1, h, d, w]`` (``lanes``), takes the incoming rows as
+    ``[2, 1, d, h]`` — a head is one lane of it, spread over the
+    window's lanes — since a trailing dim of 1 would pad every row to a
+    tile."""
     n = len(windows)
     news = refs[n_scalar:n_scalar + n]
     olds = refs[n_scalar + n:n_scalar + 2 * n]
@@ -129,10 +179,19 @@ def _write_kernel(*refs, n_scalar, windows, page):
     pos = refs[1][pl.program_id(0)]
     if page:
         pos = lax.rem(pos, page)
-    for new_ref, old_ref, out_ref, w in zip(news, olds, outs, windows):
-        hit = (lax.broadcasted_iota(jnp.int32, out_ref.shape, 3)
-               == lax.rem(pos, w))
-        out_ref[...] = jnp.where(hit, new_ref[...], old_ref[...])
+    for new_ref, old_ref, out_ref, (w, lanes) in zip(news, olds, outs,
+                                                     windows):
+        if lanes:
+            hit = (lax.broadcasted_iota(jnp.int32, (1, 1, w), 2)
+                   == lax.rem(pos, w))
+            new = new_ref[:, 0]                           # (2, d, h)
+            for head in range(out_ref.shape[2]):
+                out_ref[:, 0, head] = jnp.where(
+                    hit, new[:, :, head:head + 1], old_ref[:, 0, head])
+        else:
+            hit = (lax.broadcasted_iota(jnp.int32, out_ref.shape, 3)
+                   == lax.rem(pos, w))
+            out_ref[...] = jnp.where(hit, new_ref[...], old_ref[...])
 
 
 def _layer_scalar(layer):
@@ -157,29 +216,44 @@ def _write_column_planes(news, planes, layer, pos, table=None):
     p_sz = planes[0].shape[4]
     paged = table is not None
     mp = table.shape[1] if paged else 0
-    new_specs, plane_specs, windows = [], [], []
-    for plane in planes:
+    new_ops, new_specs, plane_ops, plane_specs, windows = [], [], [], [], []
+    for new, plane in zip(news, planes):
         h = plane.shape[3]
-        if plane.ndim == 6:
-            w = min(_sublane_tile(plane.dtype), p_sz)
-            tail = (plane.shape[5],)
-        else:
+        lanes = plane.ndim == 6 and _positions_on_lanes(
+            plane.shape[5], p_sz, plane.dtype)
+        # the block past (layer, plane, row, head), the place of the
+        # positions in it, and the incoming rows' block
+        if lanes:                               # storage as [.., d, S]
+            plane = jnp.swapaxes(plane, 4, 5)
+            w, d = min(_LANES, p_sz), plane.shape[4]
+            block, at = (d, w), 1
+            new, new_block = jnp.swapaxes(new, 2, 3), (2, 1, d, h)
+        elif plane.ndim == 6:                   # storage as [.., S, d]
+            w, d = min(_sublane_tile(plane.dtype), p_sz), plane.shape[5]
+            block, at = (w, d), 0
+            new, new_block = jnp.expand_dims(new, 3), (2, 1, h, 1, d)
+        else:                                   # scale plane [.., S]
             w = min(_LANES, p_sz)
-            tail = ()
-        zeros = (0,) * len(tail)
+            block, at = (w,), 0
+            new, new_block = jnp.expand_dims(new, 3), (2, 1, h, 1)
 
-        def where(i, layer_ref, pos_ref, *tbl_ref, w=w, zeros=zeros):
+        def where(i, layer_ref, pos_ref, *tbl_ref, w=w, at=at,
+                  n=len(block)):
             row, col = i, pos_ref[i]
             if paged:
                 row = tbl_ref[0][i * mp + lax.div(col, p_sz)]
                 col = lax.rem(col, p_sz)
-            return (layer_ref[0], 0, row, 0, lax.div(col, w)) + zeros
+            tail = [0] * n
+            tail[at] = lax.div(col, w)
+            return (layer_ref[0], 0, row, 0, *tail)
 
+        new_ops.append(new.astype(plane.dtype))
         new_specs.append(pl.BlockSpec(
-            (2, 1, h, 1) + tail,
-            lambda i, *_, zeros=zeros: (0, i, 0, 0) + zeros))
-        plane_specs.append(pl.BlockSpec((None, 2, 1, h, w) + tail, where))
-        windows.append(w)
+            new_block,
+            lambda i, *_, n=len(new_block): (0, i) + (0,) * (n - 2)))
+        plane_ops.append(plane)
+        plane_specs.append(pl.BlockSpec((None, 2, 1, h) + block, where))
+        windows.append((w, lanes))
     scalars = [_layer_scalar(layer), jnp.asarray(pos, jnp.int32)]
     if paged:
         scalars.append(jnp.asarray(table, jnp.int32).reshape(-1))
@@ -190,20 +264,21 @@ def _write_column_planes(news, planes, layer, pos, table=None):
         in_specs=new_specs + plane_specs,
         out_specs=plane_specs,
     )
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(_write_kernel, n_scalar=n_scalar,
                           windows=tuple(windows),
                           page=p_sz if paged else 0),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in planes],
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype)
+                   for p in plane_ops],
         # operand order: (*scalars, *news, *planes)
         input_output_aliases={n_scalar + n + k: k for k in range(n)},
         name="decode_attn_write",
         interpret=use_interpret(),
-    )(*scalars,
-      *[jnp.expand_dims(new, 3).astype(plane.dtype)
-        for new, plane in zip(news, planes)],
-      *planes)
+    )(*scalars, *new_ops, *plane_ops)
+    # a turned operand goes back as the cache's own [.., S, d]
+    return [jnp.swapaxes(out, 4, 5) if lanes else out
+            for out, (_, lanes) in zip(outs, windows)]
 
 
 def _write_columns_planes(news, planes, layer, pos, table=None):
@@ -353,24 +428,36 @@ _KV_VMEM_BUDGET = 4 << 20
 
 
 def decode_block_k(horizon: int, storage_dtype, *, quantized: bool = False,
-                   block_k: Optional[int] = None) -> int:
+                   block_k: Optional[int] = None,
+                   head_dim: Optional[int] = None) -> int:
     """Positions in one split-K chunk of the read kernel over a
     contiguous cache of ``horizon`` positions stored as
     ``storage_dtype`` (a paged pool's chunk is its page). THE rule:
     the kernel calls it, and so does whoever counts the chunks a step
-    needs (the scheduler's ``decode.chunks_*`` counts)."""
+    needs (the scheduler's ``decode.chunks_*`` counts). The kernel
+    hands it ``head_dim`` so that a ``block_k`` of the caller's is held
+    to the lanes where the operand lies that way; the default chunk
+    needs none (a horizon with its positions on the lanes is a
+    multiple of 128, which gets the same chunk under either
+    alignment)."""
     # fp32 scale rows put the chunk on the lane dimension too
+    lanes = quantized or (head_dim is not None and _positions_on_lanes(
+        head_dim, horizon, storage_dtype))
     return _fit_block_k(block_k or _DEFAULT_BLOCK_K, horizon,
-                        _LANES if quantized else _sublane_tile(storage_dtype))
+                        _LANES if lanes else _sublane_tile(storage_dtype))
 
 
-def _heads_per_step(h: int, d: int, bk: int, dtype, quant: bool) -> int:
+def _heads_per_step(h: int, d: int, bk: int, dtype, quant: bool,
+                    lanes: bool) -> int:
     """Heads one grid step of the read takes: the largest divisor of
     ``h`` whose K and V blocks, double-buffered and as laid out on the
-    tiles (head dim padded to the lanes), fit :data:`_KV_VMEM_BUDGET`;
-    the fp32 scale blocks of a quantized cache ride along."""
-    per_head = 4 * round_up(bk, _sublane_tile(dtype)) * round_up(
-        d, _LANES) * jnp.dtype(dtype).itemsize
+    tiles — ``(d, bk)`` with the positions on the lanes (``lanes``),
+    else ``(bk, d)`` with the head dim padded to them — fit
+    :data:`_KV_VMEM_BUDGET`; the fp32 scale blocks of a quantized cache
+    ride along."""
+    rows, cols = (d, bk) if lanes else (bk, d)
+    per_head = 4 * round_up(rows, _sublane_tile(dtype)) * round_up(
+        cols, _LANES) * jnp.dtype(dtype).itemsize
     if quant:
         per_head += 4 * round_up(bk, _LANES) * 4
     fit = max(1, _KV_VMEM_BUDGET // per_head)
@@ -415,13 +502,16 @@ def _block_index(g, j, pos, row, pin, bk: int, chunks: int):
     return row, jnp.where(live, g, pin // chunks), c
 
 
-def _attn_kernel(*refs, n_scalar, quant, scale, bk, smax):
+def _attn_kernel(*refs, n_scalar, quant, lanes, scale, bk, smax):
     """Grid ``(b, h // hb, chunks)``: ``hb`` heads of one batch row
     swept over the row's horizon in ``bk``-position chunks, scores and
-    statistics as dense ``(hb, bk)`` / ``(hb, lanes)`` tiles. ``quant``
-    adds the two fp32 scale-block refs of the int8/fp8 layout. A dead
-    row comes with ``pos == -1``: no chunk is at or before it, so it
-    does no arithmetic and writes zeros."""
+    statistics as dense ``(hb, bk)`` / ``(hb, lanes)`` tiles. A head's
+    K and V chunk are ``(d, bk)`` blocks where the operand has the
+    positions on the lanes (``lanes``), ``(bk, d)`` where it is
+    row-major: the same two dots, each contracting the other way.
+    ``quant`` adds the two fp32 scale-block refs of the int8/fp8
+    layout. A dead row comes with ``pos == -1``: no chunk is at or
+    before it, so it does no arithmetic and writes zeros."""
     pos_ref = refs[1]
     if quant:
         (q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref,
@@ -450,15 +540,23 @@ def _attn_kernel(*refs, n_scalar, quant, scale, bk, smax):
         head = lax.broadcasted_iota(jnp.int32, (hb, 1), 0)
         col = lax.broadcasted_iota(jnp.int32, (1, bk), 1) + j * bk
         valid = (col <= pos) & (col < smax)
-        # the same mask down the sublanes (Mosaic cannot transpose i1)
-        row = lax.broadcasted_iota(jnp.int32, (bk, 1), 0) + j * bk
-        valid_rows = (row <= pos) & (row < smax)
+        if lanes:
+            valid_v = valid     # V's positions run along the lanes too
+        else:
+            # the same mask down the sublanes (Mosaic cannot transpose
+            # i1)
+            row = lax.broadcasted_iota(jnp.int32, (bk, 1), 0) + j * bk
+            valid_v = (row <= pos) & (row < smax)
+        # the two dots contract a chunk's head dim and its positions:
+        # dims 0 and 1 of a (d, bk) block, 1 and 0 of a (bk, d) one
+        over_d = (((1,), (0 if lanes else 1,)), ((), ()))
+        over_bk = (((1,), (1 if lanes else 0,)), ((), ()))
         # every head's query against head n's chunk, row n kept: M = hb
         # costs the MXU what M = 1 does, and the scores come out as one
         # dense (hb, bk) tile instead of hb one-row tiles
         s = jnp.zeros((hb, bk), jnp.float32)
         for n in range(hb):
-            k = k_ref[0, n]                               # (bk, d)
+            k = k_ref[0, n]                     # (d, bk), or (bk, d)
             if quant:
                 # int8/fp8 chunk straight from HBM; the per-column
                 # scale folds into the SCORE (q·(k_int·s) ==
@@ -466,8 +564,7 @@ def _attn_kernel(*refs, n_scalar, quant, scale, bk, smax):
                 # dequantized
                 k = k.astype(jnp.float32)
             s = jnp.where(head == n, jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32), s)
+                q, k, over_d, preferred_element_type=jnp.float32), s)
         if quant:
             s = s * ks_ref[0, 0]
         s = jnp.where(valid, s * scale, _NEG)
@@ -484,16 +581,16 @@ def _attn_kernel(*refs, n_scalar, quant, scale, bk, smax):
             p = p * jnp.where(valid, vs_ref[0, 0], 0.0)
         pv = jnp.zeros((hb, d), jnp.float32)
         for n in range(hb):
-            v = v_ref[0, n]                               # (bk, d)
+            v = v_ref[0, n]                     # (d, bk), or (bk, d)
             if quant:
                 v = v.astype(jnp.float32)
             # masked V rows can be horizon padding (NaN in interpret
             # mode, arbitrary garbage on chip, NaN bit patterns of
             # stale fp8): zero them so 0·garbage can't poison the
             # accumulator dot
-            v = jnp.where(valid_rows, v, 0.0).astype(v.dtype)
+            v = jnp.where(valid_v, v, 0.0).astype(v.dtype)
             pv = jnp.where(head == n, jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                p.astype(v.dtype), v, over_bk,
                 preferred_element_type=jnp.float32), pv)
         acc_ref[:] = acc_ref[:] * corr + pv
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -511,8 +608,10 @@ def _run_attn(q, planes, layer, pos, scale, *, bk, table=None, live=None):
     max_pages]`` given — page pools ``[L, 2, num_pages, h, P(, d)]``
     one page per chunk (``bk == P``; chunk ``j`` of row ``b`` streams
     page ``table[b, j]``). The K and the V chunk are blocks of plane 0
-    and plane 1 of the one ``kv`` operand, read where it lies, ``hb``
-    heads at a time (:func:`_heads_per_step`). Only the chunks a live
+    and plane 1 of the one ``kv`` operand, read where and as it lies
+    (:func:`_positions_on_lanes`: ``(d, bk)`` blocks of ``swapaxes(kv,
+    4, 5)``, or ``(bk, d)`` blocks of ``kv``), ``hb`` heads at a time
+    (:func:`_heads_per_step`). Only the chunks a live
     row attends are fetched (:func:`_block_index`); rows that ``live
     [b] bool`` marks dead fetch and compute nothing and come out as
     zeros.
@@ -528,7 +627,10 @@ def _run_attn(q, planes, layer, pos, scale, *, bk, table=None, live=None):
     mp = table.shape[1] if paged else 0
     smax = mp * bk if paged else kv.shape[4]
     chunks = mp if paged else -(-smax // bk)
-    hb = _heads_per_step(h, d, bk, kv.dtype, quant)
+    lanes = _positions_on_lanes(d, kv.shape[4], kv.dtype)
+    if lanes:
+        kv = jnp.swapaxes(kv, 4, 5)
+    hb = _heads_per_step(h, d, bk, kv.dtype, quant, lanes)
     groups = h // hb
 
     def block(i, g, j, pos_ref, fetch_ref, tbl_ref):
@@ -542,7 +644,8 @@ def _run_attn(q, planes, layer, pos, scale, *, bk, table=None, live=None):
     def data_map(plane):
         def index(i, g, j, layer_ref, pos_ref, fetch_ref, *tbl_ref):
             lead, g, c = block(i, g, j, pos_ref, fetch_ref, tbl_ref)
-            return layer_ref[0], plane, lead, g, c, 0
+            return (layer_ref[0], plane, lead, g) + (
+                (0, c) if lanes else (c, 0))
         return index
 
     def scale_map(plane):
@@ -559,8 +662,9 @@ def _run_attn(q, planes, layer, pos, scale, *, bk, table=None, live=None):
         scale_rows = sc.reshape(sc.shape[:2] + (groups, hb, sc.shape[3]))
     for plane in (0, 1):
         operands.append(kv)
-        specs.append(pl.BlockSpec((None, None, 1, hb, bk, d),
-                                  data_map(plane)))
+        specs.append(pl.BlockSpec(
+            (None, None, 1, hb) + ((d, bk) if lanes else (bk, d)),
+            data_map(plane)))
         if quant:
             operands.append(scale_rows)
             specs.append(pl.BlockSpec((None, 1, 1, hb, bk),
@@ -584,7 +688,8 @@ def _run_attn(q, planes, layer, pos, scale, *, bk, table=None, live=None):
     )
     out = pl.pallas_call(
         functools.partial(_attn_kernel, n_scalar=len(scalars),
-                          quant=quant, scale=scale, bk=bk, smax=smax),
+                          quant=quant, lanes=lanes, scale=scale, bk=bk,
+                          smax=smax),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, groups, hb, d), q.dtype),
         name="decode_attn_read",
@@ -668,7 +773,7 @@ def stacked_decode_attention(q, k_new, v_new, cache, layer, pos, *,
         bk = kv.shape[4]
     else:
         bk = decode_block_k(kv.shape[4], kv.dtype, quantized=bool(kind),
-                            block_k=block_k)
+                            block_k=block_k, head_dim=d)
     out = _run_attn(q, planes, layer, pos, s, bk=bk, table=table,
                     live=live)
     if was16:

@@ -50,6 +50,43 @@ _TOL = {
 }
 
 
+_ORIENTATIONS = {"lanes": True, "rows": False}
+
+
+@pytest.fixture(params=list(_ORIENTATIONS))
+def orientation(request, monkeypatch):
+    """Both ways the kernels take their K/V operand — positions on the
+    lanes, ``(d, bk)`` blocks of the turned cache, or row-major ``(bk,
+    d)`` — whatever the shapes' own rule would pick (the interpreter
+    has no tiles to respect). Programs are jitted through a fresh
+    lambda in every test that uses this: a trace cached under the other
+    orientation must not be handed back."""
+    lanes = _ORIENTATIONS[request.param]
+    monkeypatch.setattr(decode_attention_mod, "_positions_on_lanes",
+                        lambda d, span, dtype: lanes)
+    return lanes
+
+
+@pytest.mark.parametrize("d, span, dtype, lanes", [
+    (64, 1024, jnp.bfloat16, True),      # GPT-2 heads, the cells' horizon
+    (64, 1024, jnp.int8, True),
+    (64, 1024, jnp.float8_e4m3fn, True),
+    (64, 128, jnp.bfloat16, True),       # pages of 128
+    (80, 2048, jnp.bfloat16, True),      # GPT-3 2.7B heads
+    (128, 1024, jnp.bfloat16, False),    # the head dim fills the lanes
+    (256, 1024, jnp.bfloat16, False),
+    (64, 16, jnp.bfloat16, False),       # pages of 16
+    (64, 1000, jnp.bfloat16, False),     # a horizon off the lane grid
+    (80, 2048, jnp.int8, False),         # 80 is no multiple of int8's 32
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_orientation_rule(d, span, dtype, lanes):
+    """The one function that turns the kernels' operand: positions on
+    the lanes where the device lays the array out that way (a head dim
+    that does not fill the lanes, over a span that does) and the head
+    dim fills whole sublane tiles; row-major otherwise."""
+    assert decode_attention_mod._positions_on_lanes(d, span, dtype) is lanes
+
+
 def _reference(q, k_new, v_new, k_cache, v_cache, pos):
     """fp32 numpy: write the column, mask ``<= pos``, plain softmax."""
     q, k_new, v_new, k_cache, v_cache = (
@@ -69,7 +106,7 @@ def _reference(q, k_new, v_new, k_cache, v_cache, pos):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16])
-def test_kernel_matches_fp32_reference(dtype):
+def test_kernel_matches_fp32_reference(orientation, dtype):
     """Standalone oracle across dtypes, at a horizon that is not a
     multiple of the split-K chunk (exercises the padded tail) and with
     per-row positions spanning first/mid/last slots."""
@@ -82,7 +119,7 @@ def test_kernel_matches_fp32_reference(dtype):
     k_cache = mk(ks[3], (b, h, S, d))
     v_cache = mk(ks[4], (b, h, S, d))
     pos = jnp.asarray([2, 0, 18], jnp.int32)
-    out, kc, vc = jax.jit(decode_attention)(
+    out, kc, vc = jax.jit(lambda *a: decode_attention(*a))(
         q, k_new, v_new, k_cache, v_cache, pos)
     ref_out, ref_kc, ref_vc = _reference(
         q, k_new, v_new, k_cache, v_cache, pos)
@@ -100,7 +137,7 @@ def test_kernel_matches_fp32_reference(dtype):
         np.testing.assert_allclose(got[col], want[col], **_TOL[dtype])
 
 
-def test_kernel_masks_stale_cache_garbage():
+def test_kernel_masks_stale_cache_garbage(orientation):
     """Entries past a row's position must be exact softmax zeros: a
     cache whose masked tail holds huge garbage yields the same output
     as one holding zeros (the engine's padded-prefill contract)."""
@@ -113,7 +150,7 @@ def test_kernel_masks_stale_cache_garbage():
     v_cache = jax.random.normal(ks[4], (b, h, S, d))
     pos = jnp.asarray([3, 7], jnp.int32)
     tail = jnp.arange(S)[None, None, :, None] > pos[:, None, None, None]
-    run = jax.jit(decode_attention)
+    run = jax.jit(lambda *a: decode_attention(*a))
     out_clean, _, _ = run(
         q, k_new, v_new,
         jnp.where(tail, 0.0, k_cache), jnp.where(tail, 0.0, v_cache), pos)
@@ -171,7 +208,7 @@ _QTOL = {"int8": dict(rtol=3e-2, atol=3e-2),
 
 
 @pytest.mark.parametrize("kind", ["int8", "fp8"])
-def test_quantized_kernel_matches_fp32_reference(kind):
+def test_quantized_kernel_matches_fp32_reference(orientation, kind):
     """Quantized-cache kernel oracle: output within the quantization
     error band of the unquantized fp32 reference, and the one-column
     write contract holds on BOTH planes — outside the written column
@@ -218,7 +255,7 @@ def test_quantized_kernel_matches_fp32_reference(kind):
                                       np.asarray(want_s))
 
 
-def test_quantized_kernel_masks_stale_garbage():
+def test_quantized_kernel_masks_stale_garbage(orientation):
     """Positions past a row's ``pos`` are exact softmax zeros even when
     the quantized tail holds saturated garbage and the scale plane
     holds NaN (an uninitialised-HBM bit pattern a fresh fp32 plane can
@@ -345,6 +382,12 @@ def _per_layer_columns(c, planes):
     return paged_write_columns(k_new, v_new, *planes, table, pos)
 
 
+def _bytes(x):
+    """``x``'s bytes on the host (a device array can come back strided
+    the way it lay on the device: the view needs it contiguous)."""
+    return np.ascontiguousarray(x).view(np.uint8)
+
+
 def _assert_only_layer_moved(got, before, layer, want_planes):
     """Layer ``layer`` of ``got`` holds ``want_planes``; every other
     layer's bytes are those of ``before``."""
@@ -354,8 +397,7 @@ def _assert_only_layer_moved(got, before, layer, want_planes):
     for g, b in zip(jax.tree.leaves(got), jax.tree.leaves(before)):
         others = np.arange(_L) != layer
         np.testing.assert_array_equal(
-            np.asarray(g)[others].view(np.uint8),
-            np.asarray(b)[others].view(np.uint8))
+            _bytes(g)[others], _bytes(b)[others])
 
 
 @pytest.mark.parametrize("layer", range(_L))
@@ -387,6 +429,53 @@ def test_stacked_kernels_match_per_layer_calls(layout, kind, layer):
             kind=c["kind"]))(c["cache"], jnp.int32(layer))
     _assert_only_layer_moved(cache, c["cache"], layer,
                              _per_layer_columns(c, planes))
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_write_columns_clamp_at_the_horizon(orientation, layout, kind):
+    """The T-column write against the XLA one-hot write, plane by
+    plane, the operand either way round: lanes inside the horizon land
+    the XLA write's bytes, and a row whose last lane overruns the
+    horizon (XLA drops it) clamps it onto column ``S - 1``, which then
+    holds that lane — nothing else of the cache moves."""
+    layer, t = 1, 3
+    c = _stacked_case(layout, kind, pos=[5, 0, _S - 2])
+    pos, table = c["pos"], c["table"]
+    got = jax.jit(lambda cache, layer: stacked_write_columns(
+        *c["cols"], cache, layer, pos, table=table, kind=c["kind"]))(
+            c["cache"], jnp.int32(layer))
+    news = list(c["cols"])                          # [b, h, T, d] each
+    if c["kind"]:
+        (kq, ksc), (vq, vsc) = (quantize_kv_rows(n, c["kind"])
+                                for n in news)
+        news = [kq, ksc, vq, vsc]
+    xla_write = (
+        (lambda plane, new: decode_attention_mod.cache_write_columns_xla(
+            plane, new, pos)) if table is None else
+        (lambda plane, new: decode_attention_mod.paged_write_columns_xla(
+            plane, new, table, pos)))
+    want = [xla_write(plane, new)
+            for plane, new in zip(_layer_of(c["cache"], layer), news)]
+    rows = lambda plane: np.asarray(
+        plane if table is None
+        else decode_attention_mod.paged_gather_xla(plane, table),
+        np.float32)                                 # [b, h, S(, d)]
+    for g, w, new in zip(_layer_of(got, layer), want, news):
+        g, w, new = rows(g), rows(w), np.asarray(new, np.float32)
+        np.testing.assert_array_equal(g[:2], w[:2])
+        np.testing.assert_array_equal(g[2, :, :_S - 1], w[2, :, :_S - 1])
+        np.testing.assert_array_equal(g[2, :, _S - 1], new[2, :, t - 1])
+    # every other layer, and the pages in no row's table, keep their bytes
+    for g, b in zip(jax.tree.leaves(got), jax.tree.leaves(c["cache"])):
+        g, b = _bytes(g), _bytes(b)
+        others = np.arange(_L) != layer
+        np.testing.assert_array_equal(g[others], b[others])
+        if table is not None:
+            free = np.setdiff1d(np.arange(g.shape[2]), np.asarray(table))
+            assert free.size
+            np.testing.assert_array_equal(g[layer][:, free],
+                                          b[layer][:, free])
 
 
 # ---------------------------------------------------------------------------
@@ -478,28 +567,31 @@ def _assert_read_matches(c, *lives, layer=1):
         out, cache, alive = run(jnp.asarray(live))
         np.testing.assert_array_equal(out[alive], full[0][alive])
         for g, w in zip(jax.tree.leaves(cache), jax.tree.leaves(full[1])):
-            np.testing.assert_array_equal(np.asarray(g).view(np.uint8),
-                                          np.asarray(w).view(np.uint8))
+            np.testing.assert_array_equal(_bytes(g), _bytes(w))
 
 
 @pytest.mark.parametrize("live", list(_LIVE))
 @pytest.mark.parametrize("kind", ["bf16", "int8", "fp8"])
 @pytest.mark.parametrize("layout", ["contiguous", "paged"])
-def test_read_at_chunk_edges_and_with_dead_rows(layout, kind, live):
-    """All six variants with rows at every chunk edge, and the rows'
-    liveness: dead rows first, last, adjacent, all but one."""
+def test_read_at_chunk_edges_and_with_dead_rows(orientation, layout, kind,
+                                                live):
+    """All six variants, the operand either way round, with rows at
+    every chunk edge, and the rows' liveness: dead rows first, last,
+    adjacent, all but one."""
     _assert_read_matches(_read_case(layout, kind), _LIVE[live])
 
 
 @pytest.mark.parametrize("kind", ["bf16", "int8", "fp8"])
 @pytest.mark.parametrize("layout", ["contiguous", "paged"])
-def test_read_with_heads_split_over_grid_steps(monkeypatch, layout, kind):
-    """A VMEM budget that holds two of the four heads: the grid gains
-    a head-group dimension and a dead row pins its last group."""
+def test_read_with_heads_split_over_grid_steps(monkeypatch, orientation,
+                                               layout, kind):
+    """A VMEM budget that holds two of the four heads, counted in the
+    bytes of the orientation in use: the grid gains a head-group
+    dimension and a dead row pins its last group."""
     c = _read_case(layout, kind)
     kv = jax.tree.leaves(c["cache"])[0]
     fits = lambda: decode_attention_mod._heads_per_step(
-        _RH, _RD, c["bk"], kv.dtype, bool(c["kind"]))
+        _RH, _RD, c["bk"], kv.dtype, bool(c["kind"]), orientation)
     for budget in (1 << n for n in range(10, 24)):
         monkeypatch.setattr(decode_attention_mod, "_KV_VMEM_BUDGET", budget)
         if fits() >= 2:

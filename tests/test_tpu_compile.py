@@ -14,6 +14,7 @@ this one file.
 """
 
 import importlib
+import math
 import re
 
 import jax
@@ -77,27 +78,48 @@ def _read_grids(jaxpr):
     return found
 
 
+def _nbytes(shape, dtype):
+    return math.prod(shape) * jnp.dtype(dtype).itemsize
+
+
+def _yields(text, shape):
+    """The instructions of the compiled ``text`` whose result is an
+    array of ``shape`` — either way round its last two dims — and that
+    are not a parameter, an element of a tuple or a bitcast."""
+    turned = tuple(shape[:-2]) + (shape[-1], shape[-2])
+    dims = "|".join(",".join(map(str, sh)) for sh in (shape, turned))
+    return [ln.strip()[:160] for ln in text.splitlines()
+            if re.search(rf"^\s*(ROOT )?%\S+ = \w+\[({dims})\]", ln)
+            and not re.search(r" (parameter|get-tuple-element|bitcast)\(",
+                              ln)]
+
+
 @pytest.mark.parametrize("kind", [None, "int8", "fp8"])
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged", "paged16"])
 def test_stacked_decode_kernels_compile_for_v5e(topo, mosaic, layout, kind):
     """GPT-2's head shapes (16 heads of 64) at a horizon of 1024 and
     the serving cells' 40 slots: the layer-indexed write and read
     kernels, and the T-column write, on a stacked cache / page pool in
     each storage; the read's grid is rows x head groups x chunks, all
-    16 heads in one group."""
-    layers, b, h, s_max, d, page = 2, 40, 16, 1024, 64, 128
+    16 heads in one group. A horizon of 1024 and pages of 128 lie with
+    their positions on the lanes in every storage, the kernels take
+    them so, and the donated cache is never copied; pages of 16 lie
+    with the PAGES on the lanes, the kernels take them row-major, and
+    the compiler relays the pool on the way in and out."""
+    layers, b, h, s_max, d = 2, 40, 16, 1024, 64
+    page = {"contiguous": None, "paged": 128, "paged16": 16}[layout]
     one = SingleDeviceSharding(topo.devices[0])
     arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
-    rows, horizon = (b * s_max // page, page) if layout == "paged" \
-        else (b, s_max)
+    rows, horizon = (b * s_max // page, page) if page else (b, s_max)
     shape = (layers, 2, rows, h, horizon, d)
-    cache = arr(shape, jnp.bfloat16)
+    storage = da.kv_storage_dtype(kind) if kind else jnp.bfloat16
+    cache = arr(shape, storage)
     if kind:
-        cache = {"kv": arr(shape, da.kv_storage_dtype(kind)),
-                 "scale": arr(shape[:-1], jnp.float32)}
-    table = arr((b, s_max // page), jnp.int32) \
-        if layout == "paged" else None
+        cache = {"kv": cache, "scale": arr(shape[:-1], jnp.float32)}
+    table = arr((b, s_max // page), jnp.int32) if page else None
     row = arr((b, h, d), jnp.bfloat16)
+    lanes = da._positions_on_lanes(d, horizon, storage)
+    assert lanes == (layout != "paged16")
 
     def step(cache, layer, q, k_new, v_new, cols, pos, live, table):
         out, cache = da.stacked_decode_attention(
@@ -110,23 +132,34 @@ def test_stacked_decode_kernels_compile_for_v5e(topo, mosaic, layout, kind):
         cache, arr((), jnp.int32), row, row, row,
         arr((b, h, 3, d), jnp.bfloat16), arr((b,), jnp.int32),
         arr((b,), jnp.bool_), table)
-    bk = page if layout == "paged" else da.decode_block_k(
-        s_max, jax.tree.leaves(cache)[0].dtype, quantized=bool(kind))
+    bk = page or da.decode_block_k(s_max, storage, quantized=bool(kind))
     assert _read_grids(traced.jaxpr.jaxpr) == [(b, 1, s_max // bk)]
-    text = traced.lower().compile().as_text()
+    compiled = traced.lower().compile()
+    text = compiled.as_text()
     calls = lambda name: re.findall(
         rf"^\s*%{name}[.\d]* = .* custom-call\(", text, re.M)
     assert len(calls("decode_attn_write")) == 4
     assert len(calls("decode_attn_read")) == 1
+    made = _yields(text, shape)
+    copies = [ln for ln in made if "decode_attn_write" not in ln]
+    if lanes:
+        # the kernels' operand is a bitcast of the cache as it lies
+        assert not copies, copies[:3]
+        assert compiled.memory_analysis().temp_size_in_bytes < _nbytes(
+            shape, storage) // 4
+    else:
+        assert any(" copy(" in ln for ln in copies), made[:3]
 
 
 def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
-    """The engine's step program, compiled for the chip: the layer loop
-    and the step loop around it hold the cache in place — no
-    instruction anywhere yields an array of one layer's cache, and the
-    only whole-cache copies are the device-layout pair at the entry
-    computation's two ends (the resident cache's default layout puts
-    the positions on the lanes, Mosaic's operand is row-major)."""
+    """The engine's step program, compiled for the chip, holds the
+    cache in place from its parameter to its result: no instruction
+    anywhere yields an array of one layer's cache, none but the aliased
+    write kernel (once per layer-loop body) yields the whole cache —
+    no ``copy``, no ``transpose``: at head size 64 the resident cache
+    lies with its positions on the lanes and the kernels take it so —
+    and the program's temporaries are a fraction of the cache. The
+    cache's parameter and result keep the device's default layout."""
 
     class PlanEngine(Engine):
         """Programs built and never run: nothing can be placed on a
@@ -137,11 +170,14 @@ def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
             self.init_program = self._init
             self._init = lambda params: (None, None)
 
-    cfg = standalone_gpt_config(vocab_size=96, seq_len=256,
+    # a cache of 48 MiB: one small enough for the chip's fast memory is
+    # moved there and back, which is no relayout and no model of a
+    # deployment's
+    cfg = standalone_gpt_config(vocab_size=96, seq_len=1024,
                                 hidden_size=256, num_heads=4,
                                 num_layers=3, compute_dtype=jnp.bfloat16)
     assert cfg.head_dim == 64
-    ecfg = EngineConfig(slots=8, max_prompt_len=64, max_seq_len=256,
+    ecfg = EngineConfig(slots=16, max_prompt_len=64, max_seq_len=1024,
                         decode_chunk=2)
     mesh = mx.build_mesh(tp=1, devices=list(topo.devices)[:1])
     params = jax.tree.map(
@@ -151,6 +187,8 @@ def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
         gpt.param_specs(cfg))
     eng = PlanEngine(cfg, params, mesh, ecfg)
     cache, state = jax.eval_shape(eng.init_program, params)
+    assert da._positions_on_lanes(cfg.head_dim, ecfg.max_seq_len,
+                                  cache.dtype)
     traced = eng._step_variants[ecfg.decode_chunk].trace(
         params, cache, state,
         jax.ShapeDtypeStruct((ecfg.slots, cfg.vocab_size), jnp.bool_))
@@ -158,18 +196,18 @@ def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
     # heads x the chunks of the horizon
     assert _read_grids(traced.jaxpr.jaxpr) == [
         (ecfg.slots, 1, -(-ecfg.max_seq_len // eng.read_chunk))]
-    text = traced.lower().compile().as_text()
+    compiled = traced.lower().compile()
+    text = compiled.as_text()
+    assert not _yields(text, cache.shape[1:]), _yields(
+        text, cache.shape[1:])[:3]
+    made = _yields(text, cache.shape)
+    assert made and all("decode_attn_write" in ln for ln in made), made[:3]
+    assert compiled.memory_analysis().temp_size_in_bytes < _nbytes(
+        cache.shape, cache.dtype) // 4
+    # positions on the lanes, as the device lays a minor dim of 64 out
+    # by itself: nothing asked for it
     whole = ",".join(map(str, cache.shape))
-    layer = ",".join(map(str, cache.shape[1:]))
-    yields = lambda dims: [
-        ln.strip()[:160] for ln in text.splitlines()
-        if re.search(rf"^\s*(ROOT )?%\S+ = \w+\[{dims}\]", ln)
-        and not re.search(r" (parameter|get-tuple-element|bitcast)\(", ln)]
-    assert not yields(layer), yields(layer)[:3]
-    entry = text[text.index("\nENTRY "):]
-    made = yields(whole)
-    copies = [ln for ln in made if " copy(" in ln]
-    assert len(copies) <= 2 and all(ln in entry for ln in copies), copies
-    # beside them: the aliased write kernel, once per layer-loop body
-    assert all(" copy(" in ln or "decode_attn_write" in ln
-               for ln in made), made[:3]
+    lies = rf"bf16\[{whole}\]\{{4,5,3,2,1,0[:}}]"
+    assert re.search(lies + r".* parameter\(", text)
+    assert re.search(
+        rf"entry_computation_layout=.*{lies}.*->.*{lies}", text)
