@@ -55,6 +55,7 @@ from apex_tpu.kernels.decode_attention import (
     stacked_write_columns as _stacked_write_columns,
 )
 from apex_tpu.kernels.blockwise_attention import blockwise_attention
+from apex_tpu.models import latent
 from apex_tpu.mesh.topology import AXIS_CP, AXIS_DP, AXIS_EP, AXIS_PP, AXIS_TP
 # sampling lives in serving so generate and the continuous-batching
 # engine share one implementation (serving/__init__ loads its
@@ -231,6 +232,15 @@ class GPTConfig:
     layernorm_epsilon: float = 1e-5
     init_std: float = 0.02
     axis: str = AXIS_TP
+    #: a :class:`apex_tpu.models.latent.LatentConfig` switches the
+    #: layer's mixer from fused-QKV multi-head attention to latent
+    #: attention over a learned sparse selection, with RMSNorm, rotary
+    #: positions, SiLU-gated feed-forwards (dense first, routed after),
+    #: bias-free linears and an untied head over the vocabulary rows
+    #: held (``models/latent.py``; serving path only: :func:`init`,
+    #: :func:`init_cache`, :func:`decode_step`/:func:`decode_steps`,
+    #: :func:`prefill_paged`).
+    latent: Optional[Any] = None
 
     @property
     def ffn(self) -> int:
@@ -305,6 +315,8 @@ def _layer_init(cfg: GPTConfig, key):
 
 def init(cfg: GPTConfig, key) -> Any:
     """Global (unsharded) parameter pytree; shard with :func:`param_specs`."""
+    if cfg.latent is not None:
+        return latent.init(cfg, key)
     k_emb, k_pos, k_layers = jax.random.split(key, 3)
     emb_init = init_method_normal(cfg.init_std)
     layers = jax.vmap(lambda k: _layer_init(cfg, k))(
@@ -330,6 +342,8 @@ def param_specs(cfg: GPTConfig, *, pipeline: bool = False) -> Any:
     ``pipeline=True`` shards the stacked layer dim over the ``pp`` axis
     (each stage owns its contiguous slice of the — possibly interleave-
     permuted, see :func:`interleave_layers` — layer stack)."""
+    if cfg.latent is not None:
+        return latent.param_specs(cfg)
     t = cfg.axis
     lay = {
         "ln1": {"scale": P(None), "bias": P(None)},
@@ -628,11 +642,21 @@ def _moe_cfg(cfg: GPTConfig) -> moe_mod.MoEConfig:
         dispatch=cfg.moe_dispatch)
 
 
-def _ffn(cfg: GPTConfig, p, x, lora=None):
-    """The feed-forward half of a layer on the second LayerNorm's
-    output ``x [..., hidden]`` → ``(y, aux)``: the dense MLP (``aux``
-    0), or with ``num_experts`` the expert FFN over the tokens
-    flattened to ``[n, hidden]`` (``aux`` its load-balance term)."""
+def _ffn(cfg: GPTConfig, p, x, lora=None, live=None):
+    """The feed-forward half of a layer on the second norm's output
+    ``x [..., hidden]`` → ``(y, aux)``: the dense MLP (``aux`` 0), or
+    with ``num_experts`` the expert FFN over the tokens flattened to
+    ``[n, hidden]`` (``aux`` its load-balance term). Under
+    ``cfg.latent``: SwiGLU where the layer holds ``"ffn"``, else the
+    dropless routed layer, ``aux`` its int32 routing counts ``[4]``
+    over the tokens ``live`` marks (zeros for SwiGLU)."""
+    if cfg.latent is not None:
+        if "ffn" in p:
+            return moe_mod.swiglu(x, p["ffn"]), jnp.zeros((4,), jnp.int32)
+        y, counts = moe_mod.routed_ffn(
+            cfg.latent.routed, p["moe"], x.reshape(-1, x.shape[-1]),
+            live=live)
+        return y.reshape(x.shape), counts
     if not cfg.num_experts:
         return _mlp(cfg, p["mlp"], x, lora=lora), jnp.float32(0.0)
     if cfg.sequence_parallel:
@@ -645,40 +669,65 @@ def _ffn(cfg: GPTConfig, p, x, lora=None):
     return y.reshape(x.shape), aux
 
 
-def _layer(cfg: GPTConfig, p, x, attend, *, lora=None):
-    """THE transformer layer, for every entry point: LayerNorm → fused
-    QKV → ``attend`` → output projection → residual → LayerNorm →
-    feed-forward → residual, on ``x [b, hidden]`` (one decoded token)
-    or ``[b, s, hidden]``. Returns ``(x, aux, carried)``.
+def _norm(cfg: GPTConfig, x, p):
+    """A layer's normalisation with its parameters ``p``: LayerNorm
+    (``scale``, ``bias``), or RMSNorm (``scale``) under ``cfg.latent``."""
+    if cfg.latent is not None:
+        return latent.rms_norm(x, p["scale"], cfg.latent.rms_eps)
+    return _layer_norm(cfg, x, p["scale"], p["bias"])
 
-    ``attend(q, k, v)`` is what differs between entry points: it takes
-    the projected ``[..., h_local]`` slabs and returns the
-    pre-projection context in the same layout, plus whatever its caller
-    carries out of the layer (cold prefill's per-head ``(k, v)``, the
-    updated cache, the tail's K/V) — the reshapes to heads are its own.
+
+def _mix(cfg: GPTConfig, p, y, attend, lora=None):
+    """The mixer half of a layer on the first norm's output ``y``:
+    projections → ``attend`` → output projection. Returns ``(attn,
+    carried)``. The fused-QKV mixer hands ``attend`` its ``(q, k, v)``
+    slabs; the latent mixer's projections belong to its ``attend``
+    (:func:`latent.cache_attend` makes queries, the cache row and the
+    index key of the same stream), which takes ``y`` itself."""
+    if cfg.latent is not None:
+        ctx, carried = attend(y)
+        with jax.named_scope("apex.mla.proj"):
+            return ctx @ p["attn"]["o"], carried
+    sp = cfg.sequence_parallel
+    lq = None if lora is None else (lora[0]["qkv"],) + lora[1:]
+    q, k, v = _qkv_project(cfg, p["attn"]["qkv"], y,
+                           sequence_parallel=sp, lora=lq)
+    ctx, carried = attend(q, k, v)
+    attn = row_parallel_linear(
+        ctx, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
+        axis=cfg.axis, sequence_parallel=sp, sequence_dim=1)
+    if lora is not None:
+        page, ids, scale = lora
+        attn = attn + _lora_delta(ctx, page["proj"]["a"],
+                                  page["proj"]["b"], ids, scale,
+                                  axis=cfg.axis)
+    return attn, carried
+
+
+def _layer(cfg: GPTConfig, p, x, attend, *, lora=None, live=None):
+    """THE transformer layer, for every entry point and both mixers:
+    norm → mixer (:func:`_mix`: projections → ``attend`` → output
+    projection) → residual → norm → feed-forward (:func:`_ffn`) →
+    residual, on ``x [b, hidden]`` (one decoded token) or ``[b, s,
+    hidden]``. Returns ``(x, aux, carried)``.
+
+    ``attend`` is what differs between entry points: with the
+    fused-QKV mixer ``attend(q, k, v)`` takes the projected ``[...,
+    h_local]`` slabs and returns the pre-projection context in the same
+    layout, plus whatever its caller carries out of the layer (cold
+    prefill's per-head ``(k, v)``, the updated cache, the tail's K/V) —
+    the reshapes to heads are its own; under ``cfg.latent``
+    ``attend(y)`` takes the normed stream ``[b, T, hidden]``.
     ``lora`` is the per-layer ``(page, ids, scale)`` adapter bundle
     (serving only; training never threads it): the four dense seams
     gain their per-row low-rank deltas. ``aux`` is the MoE load-balance
-    term, 0 for the dense MLP."""
-    sp = cfg.sequence_parallel
+    term, 0 for the dense MLP; under ``cfg.latent`` the routed layer's
+    int32 counts over the tokens ``live [b, T]`` marks."""
     with jax.named_scope("apex.attn"):
-        y = _layer_norm(cfg, x, p["ln1"]["scale"], p["ln1"]["bias"])
-        lq = None if lora is None else (lora[0]["qkv"],) + lora[1:]
-        q, k, v = _qkv_project(cfg, p["attn"]["qkv"], y,
-                               sequence_parallel=sp, lora=lq)
-        ctx, carried = attend(q, k, v)
-        attn = row_parallel_linear(
-            ctx, p["attn"]["proj"]["kernel"], p["attn"]["proj"]["bias"],
-            axis=cfg.axis, sequence_parallel=sp, sequence_dim=1)
-        if lora is not None:
-            page, ids, scale = lora
-            attn = attn + _lora_delta(ctx, page["proj"]["a"],
-                                      page["proj"]["b"], ids, scale,
-                                      axis=cfg.axis)
+        attn, carried = _mix(cfg, p, _norm(cfg, x, p["ln1"]), attend, lora)
         x = x + attn
     with jax.named_scope("apex.mlp"):
-        y = _layer_norm(cfg, x, p["ln2"]["scale"], p["ln2"]["bias"])
-        y, aux = _ffn(cfg, p, y, lora=lora)
+        y, aux = _ffn(cfg, p, _norm(cfg, x, p["ln2"]), lora=lora, live=live)
         return x + y, aux, carried
 
 
@@ -761,10 +810,19 @@ def _scan_blocks(cfg: GPTConfig, h, layers):
     return h, aux
 
 
+def _no_latent(cfg: GPTConfig, what: str) -> None:
+    if cfg.latent is not None:
+        raise NotImplementedError(
+            f"{what} has no latent-mixer form yet: the mixer is served "
+            f"through the cache only (init_cache, decode_step(s), "
+            f"prefill_paged); training it is ROADMAP R1")
+
+
 def hidden_states_and_aux(cfg: GPTConfig, params, tokens):
     """tokens [b, s] (global ids, dp-local batch) → (final-LN hidden
     [b, s(_local under SP), hidden] in compute dtype, summed MoE aux
     loss — 0 for dense models)."""
+    _no_latent(cfg, "the training forward")
     h, aux = _scan_blocks(cfg, _embed(cfg, params, tokens),
                           params["layers"])
     # final LN runs inside the SP region (Megatron: its grads are
@@ -955,6 +1013,8 @@ def _cast_layer(cfg: GPTConfig, layer_p):
     VJP (``psum_scatter``) IS the ZeRO gradient reduce-scatter. The
     gather runs in param dtype so the grad reduction stays fp32
     (apex DDP's ``allreduce_always_fp32`` semantics (U))."""
+    if cfg.latent is not None:
+        return latent.cast_layer(cfg, layer_p)
     if cfg.fsdp and lax.axis_size(AXIS_DP) > 1:
         layer_p = jax.tree.map(
             lambda x, d: x if d < 0 else lax.all_gather(
@@ -1285,7 +1345,13 @@ def init_cache(cfg: GPTConfig, params, batch: int,
     :func:`cache_specs` for the matching PartitionSpecs). All layers
     are ONE array: :func:`decode_step` carries it whole through its
     layer scan and the decode kernels address it by layer index, so a
-    decoded token moves its own column's windows and nothing else."""
+    decoded token moves its own column's windows and nothing else.
+
+    Under ``cfg.latent`` a layer's state is two planes of different
+    width, ``{"ckv", "ki"}`` (:func:`latent.init_cache`), in the same
+    rank-6 layout."""
+    if cfg.latent is not None:
+        return latent.init_cache(cfg, batch, max_len or cfg.seq_len)
     qkv_k = params["layers"]["attn"]["qkv"]["kernel"]  # [L, h, 3, hl]
     l_local = qkv_k.shape[0]
     heads_local = qkv_k.shape[-1] // cfg.head_dim
@@ -1314,6 +1380,8 @@ def cache_specs(cfg: GPTConfig):
     """PartitionSpecs matching :func:`init_cache`'s structure (heads are
     the tp-sharded dim; the quantized scale plane shards the same
     way) — the serving engine's cache/pool in/out specs."""
+    if cfg.latent is not None:
+        return latent.cache_specs(cfg)
     data = P(None, None, None, cfg.axis, None, None)
     if _kv_cache_dtype(cfg) == "compute":
         return data
@@ -1530,7 +1598,10 @@ def _embed_at(cfg: GPTConfig, params, tokens, pos):
     hidden]``, column ``t`` at position ``pos + t``). ``pos`` is where
     each row starts: a static int (every row at the same known
     position: a slice of the position table), a traced scalar, or a
-    per-row ``[b]`` vector."""
+    per-row ``[b]`` vector. The latent mixer has no position table:
+    its positions are rotary, applied where it projects."""
+    if cfg.latent is not None:
+        return latent.embed(cfg, params, tokens)
     one = tokens.ndim == 1
     table = params["embedding"]["word"]["table"].astype(cfg.compute_dtype)
     emb = vocab_parallel_embedding(
@@ -1556,38 +1627,72 @@ def _embed_at(cfg: GPTConfig, params, tokens, pos):
         cfg.compute_dtype)
 
 
+def _layer_stacks(cfg: GPTConfig, params, cache):
+    """``(stacked layer parameters, their layer indices)`` in layer
+    order, one pair at a time: one homogeneous stack, or under
+    ``cfg.latent`` two (dense feed-forwards first, routed after), each
+    scanned."""
+    if cfg.latent is None:
+        yield params["layers"], _layer_indices(cache)
+        return
+    for stack, first in latent.layer_stacks(cfg, params):
+        n = jax.tree.leaves(stack)[0].shape[0]
+        yield stack, first + jnp.arange(n, dtype=jnp.int32)
+
+
 def _scan_cached_layers(cfg: GPTConfig, params, x, cache, pos, table,
                         lora, live=None):
-    """``x`` through every layer against the cache
-    (:func:`_cache_attend`) → ``(x, cache)``. The cache rides the
+    """``x`` through every layer against the cache → ``(x, cache)``,
+    the layer's ``attend`` being :func:`_cache_attend` or, under
+    ``cfg.latent``, :func:`latent.cache_attend`. The cache rides the
     scan's CARRY, whole: the kernels address it by layer index and
     write in place, so no layer's cache is sliced out or stacked back.
     Only the stacked parameters (and the LoRA pool, an ``xs`` leaf that
     may be None) are sliced per layer, and that slicing carries the
-    scope ``apex.decode.layers`` alone."""
+    scope ``apex.decode.layers`` alone.
+
+    Under ``cfg.latent`` ``x [b, hidden]`` is one token a row at
+    ``pos`` (scalar or ``[b]``) and ``x [b, T, hidden]`` columns
+    ``pos[b] .. pos[b] + T - 1``; ``live [b]`` or ``[b, T]`` marks the
+    real tokens, whose routing the cache's ``counts`` add up."""
     pool, ids, scale = lora if lora is not None else (None, None, None)
+    lat = cfg.latent is not None
+    one = lat and x.ndim == 2
+    if lat:
+        x, pos, live = latent.columns(x, pos, live)
 
     def body(carry, inp):
         x, cache = carry
         layer_p, layer, page = inp
-        x, _, cache = _layer(
-            cfg, _cast_layer(cfg, layer_p), x,
-            lambda q, k, v: _cache_attend(cfg, q, k, v, cache, layer, pos,
-                                          table, live),
-            lora=None if page is None else (page, ids, scale))
+        layer_p = _cast_layer(cfg, layer_p)
+        if lat:
+            attend = lambda y: latent.cache_attend(
+                cfg, layer_p, y, cache, layer, pos, table)
+        else:
+            attend = lambda q, k, v: _cache_attend(
+                cfg, q, k, v, cache, layer, pos, table, live)
+        x, aux, cache = _layer(
+            cfg, layer_p, x, attend,
+            lora=None if page is None else (page, ids, scale), live=live)
+        if lat:
+            cache = {**cache, "counts": cache["counts"] + aux}
         return (x, cache), None
 
-    xs = (params["layers"], _layer_indices(cache), pool)
-    with jax.named_scope("apex.decode.layers"):
-        (x, cache), _ = lax.scan(body, (x, cache), xs)
-    return x, cache
+    for stack, layers in _layer_stacks(cfg, params, cache):
+        with jax.named_scope("apex.decode.layers"):
+            (x, cache), _ = lax.scan(body, (x, cache),
+                                     (stack, layers, pool))
+    return (x[:, 0] if one else x), cache
 
 
 @jax.named_scope("apex.lm_head")
 def _lm_head(cfg: GPTConfig, params, h):
     """Tied-embedding LM head for a single position: ``h [b, hidden]``
     (pre-final-LN) → full-vocab fp32 logits ``[b, vocab]`` — shared by
-    incremental decode and bulk prefill so the two can never diverge."""
+    incremental decode and bulk prefill so the two can never diverge.
+    (The latent mixer's head is untied: :func:`latent.lm_head`.)"""
+    if cfg.latent is not None:
+        return latent.lm_head(cfg, params, h)
     h = _layer_norm(cfg, h, params["final_ln"]["scale"],
                     params["final_ln"]["bias"])
     h = copy_to_tensor_model_parallel_region(h, cfg.axis)
@@ -1984,6 +2089,7 @@ def _prefill_states(cfg: GPTConfig, params, prompt, max_len: int,
     ``[l, 2, b, hl, max_len, d]``, pre-final-LN hidden ``[b, p_len,
     hid]``)."""
     b, p_len = prompt.shape
+    _no_latent(cfg, "cold prefill without the cache")
     if p_len > max_len:
         raise ValueError(f"prompt {p_len} exceeds cache max_len {max_len}")
     h = _embed(cfg, params, prompt.astype(jnp.int32))
@@ -2105,6 +2211,7 @@ def prefill_extend(cfg: GPTConfig, params, prefix_kv, tail, last, *,
     bucket, tail bucket), which is what keeps the serving engine's
     prefix admissions trace-stable."""
     b, tb = tail.shape
+    _no_latent(cfg, "prefill_extend over a dense prefix block")
     cfg = _decode_entry_cfg(cfg, prefix_len + 1)
     if prefix_len + tb > cfg.seq_len:
         raise ValueError(
@@ -2157,6 +2264,46 @@ def prefill_extend(cfg: GPTConfig, params, prefix_kv, tail, last, *,
     last = jnp.asarray(last, jnp.int32)
     h_last = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
     return tail_kv, _lm_head(cfg, params, h_last)
+
+
+def prefill_paged(cfg: GPTConfig, params, cache, tail, start, last,
+                  table=None, *, head: bool = True):
+    """Prefill THROUGH the cache: one forward over the right-padded
+    tokens ``tail [b, T]`` of rows that already hold ``start [b]``
+    positions in ``cache`` (0 for a cold row), writing columns
+    ``start[b] .. start[b] + T - 1`` of each row (through its block
+    table ``table [b, max_pages]`` when the cache is paged) and
+    attending everything up to each column. Real tokens end at
+    tail-local ``last [b]``. Returns ``(cache, logits [b, vocab])``,
+    row ``i``'s logits predicting position ``start[i] + last[i] + 1``
+    (``None`` with ``head=False``: a chunk of a longer prompt).
+
+    ``start`` is DATA, so one compiled program per ``(b, T)`` serves
+    every prefix length: a question over a shared document (the
+    document's pages mapped read-only into the row's table), a
+    follow-up turn, and a long prompt taken a chunk at a time are the
+    same program. Pad columns write masked garbage past the row's real
+    tokens, which decode overwrites as it advances (the
+    :func:`prefill_at` argument). Both mixers take it — it is the layer
+    scan of :func:`decode_step` over T columns — but only the latent
+    mixer's engine admits through it today: the fused-QKV engine still
+    admits by :func:`prefill_many` / :func:`prefill_extend` (ROADMAP
+    D11 retires them for this)."""
+    b, t = tail.shape
+    cfg = _decode_entry_cfg(cfg, 1)
+    start = jnp.asarray(start, jnp.int32)
+    last = jnp.asarray(last, jnp.int32)
+    # the routed layer counts real tokens only; the fused-QKV core's
+    # ``live`` is per row (the kernels' read), and every row is live
+    live = None if cfg.latent is None else (
+        jnp.arange(t, dtype=jnp.int32)[None] <= last[:, None])
+    x, cache = _scan_cached_layers(
+        cfg, params, _embed_at(cfg, params, tail.astype(jnp.int32), start),
+        cache, start, table, None, live)
+    if not head:
+        return cache, None
+    h_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+    return cache, _lm_head(cfg, params, h_last)
 
 
 @jax.named_scope("apex.prefill.cache_insert")

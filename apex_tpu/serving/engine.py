@@ -55,6 +55,7 @@ from jax.sharding import PartitionSpec as P
 
 from apex_tpu.models import gpt
 from apex_tpu.serving import hostswap, sampling
+from apex_tpu.serving import latent_engine
 from apex_tpu.serving.pages import SINK, PageAllocator, PagesExhausted
 from apex_tpu.telemetry.recompile import expected_compiles
 from apex_tpu.serving.resilience import (
@@ -467,6 +468,50 @@ class StepHandle:
         return self._out
 
 
+def _draw_first(logits0, keys, seeded, req_idx, p_lens, temp, top_k,
+                top_p, masks):
+    """The first token of every admitted row, for every admission
+    program: ``(keys, first, first_lp)``. Unseeded rows fold the
+    monotonic request counter into the zero base key ON DEVICE (no
+    host-side compile to trip a recompile guard); seeded rows keep
+    their host key bit-for-bit. The k-row ``draw_slots`` call vmaps per
+    row over a ``[1, vocab]`` lane — each row IS the solo-generate
+    first draw (same gumbel shape, same fold index ``p_len - 1``)."""
+    base = jnp.zeros((2,), jnp.uint32)
+    folded = jax.vmap(lambda i: jax.random.fold_in(base, i))(req_idx)
+    keys = jnp.where(seeded[:, None], keys, folded)
+    first = sampling.draw_slots(logits0, keys, p_lens - 1, temp, top_k,
+                                top_p, masks=masks)
+    first_lp = jnp.take_along_axis(
+        jax.nn.log_softmax(logits0, axis=-1), first[:, None], axis=1)[:, 0]
+    return keys, first, first_lp
+
+
+def _admitted_state(state, slots, first, p_lens, max_tokens, temp, top_k,
+                    top_p, keys, eos, hist0=None):
+    """The per-slot state after an admission wrote rows ``slots``:
+    ``(new_state, hit_eos, done0)``. ``hist0`` (speculation) seeds the
+    drafter's ring: the prompt tail (packed host-side — the host knows
+    the full prompt) plus the first token just drawn."""
+    hit_eos = (eos >= 0) & (first == eos)
+    done0 = hit_eos | (max_tokens <= 1)
+    new_state = {
+        "tok": state["tok"].at[slots].set(first),
+        "pos": state["pos"].at[slots].set(p_lens),
+        "remaining": state["remaining"].at[slots].set(max_tokens - 1),
+        "done": state["done"].at[slots].set(done0),
+        "temp": state["temp"].at[slots].set(temp),
+        "top_k": state["top_k"].at[slots].set(top_k),
+        "top_p": state["top_p"].at[slots].set(top_p),
+        "key": state["key"].at[slots].set(keys),
+        "eos": state["eos"].at[slots].set(eos),
+    }
+    if hist0 is not None:
+        new_state["hist"] = state["hist"].at[slots].set(
+            jnp.concatenate([hist0, first[:, None]], axis=1))
+    return new_state, hit_eos, done0
+
+
 class Engine:
     """Compiled slot engine over ``mesh`` (tp sharding like the rest of
     the decode path; dp/pp axes must be 1 — decode state is replicated).
@@ -511,6 +556,11 @@ class Engine:
                 "than sequential steps, so MoE expert capacity breaks "
                 "spec == plain bit-parity (see gpt.decode_verify)")
         gpt._check_stop_tokens(cfg, None, ecfg.pad_token_id)
+        #: the model's mixer attends a two-plane latent cache: admission
+        #: prefills through the block table (serving/latent_engine.py)
+        self._latent = cfg.latent is not None
+        if self._latent:
+            latent_engine.check(cfg, ecfg, mesh, self._spec_ladder)
         for axis in ("dp", "pp", "cp", "ep"):
             if axis in mesh.shape and mesh.shape[axis] != 1:
                 raise ValueError(
@@ -534,7 +584,7 @@ class Engine:
                     "dense seam to delta — see gpt.init_lora_pool)")
         self._lora_scale = (ecfg.adapter_alpha / ecfg.adapter_rank
                             if self._lora else 0.0)
-        self._buckets = self._resolve_buckets(ecfg)
+        self._buckets = self._resolve_buckets(ecfg, tails=self._latent)
         self._batch_sizes = self._resolve_batch_sizes(ecfg)
         if ecfg.prefix_pool_slots > 0 and cfg.num_experts:
             raise ValueError(
@@ -544,8 +594,13 @@ class Engine:
                 "drops different tokens than the cold full-prompt "
                 "prefill and prefix-hit streams would silently "
                 "diverge (see gpt.prefill_extend)")
-        self._prefix_splits, self._extend_variants = \
-            self._resolve_prefix_variants(ecfg, self._buckets)
+        # (the dropless routed layer routes row by row: a tail-only or
+        # chunked forward computes what the whole prompt's would, so
+        # the latent mixer takes both; its prefixes have any whole
+        # number of pages, not a bucket's length)
+        self._prefix_splits, self._extend_variants = (
+            ((), ()) if self._latent else
+            self._resolve_prefix_variants(ecfg, self._buckets))
         # -- paged KV cache geometry (all config-derived constants:
         # tables are data, never shapes — PAGE-TABLE-STATIC) ------------
         if ecfg.page_size < 0 or ecfg.num_pages < 0:
@@ -611,7 +666,10 @@ class Engine:
         if ecfg.prefill_chunk < 0:
             raise ValueError(
                 f"prefill_chunk {ecfg.prefill_chunk} must be >= 0")
-        self._chunk_size = ecfg.prefill_chunk
+        self._chunk_size = 0 if self._latent else ecfg.prefill_chunk
+        #: latent mixer: tokens a fill dispatch takes of a long prompt
+        #: or of a prefix being registered
+        self._fill_chunk = ecfg.prefill_chunk or self._buckets[-1]
         if self._chunk_size:
             if cfg.num_experts:
                 raise ValueError(
@@ -736,10 +794,24 @@ class Engine:
                 self.adapters = self._adapter_init(params)
 
     @staticmethod
-    def _resolve_buckets(ecfg: EngineConfig) -> Tuple[int, ...]:
+    def _resolve_buckets(ecfg: EngineConfig,
+                         tails: bool = False) -> Tuple[int, ...]:
+        """The padded widths admission compiles for. ``tails``: they
+        are widths of what a row still has to prefill, a longer prompt
+        goes in by chunks, and the ladder need not reach
+        ``max_prompt_len``."""
         buckets = ecfg.prompt_buckets
         if buckets is None:
             return default_prompt_buckets(ecfg.max_prompt_len)
+        if tails:
+            buckets = tuple(int(b) for b in buckets)
+            if not buckets or list(buckets) != sorted(set(buckets)) \
+                    or buckets[0] < 1 \
+                    or buckets[-1] > ecfg.max_prompt_len:
+                raise ValueError(
+                    f"prompt_buckets must be strictly increasing within "
+                    f"[1, max_prompt_len], got {buckets}")
+            return buckets
         buckets = tuple(int(b) for b in buckets)
         if not buckets or list(buckets) != sorted(set(buckets)):
             raise ValueError(
@@ -1000,24 +1072,10 @@ class Engine:
                 blocks, logits0 = gpt.prefill_many(
                     cfg, params, prompts, p_lens - 1, max_len=bucket,
                     lora=lora)
-                # unseeded rows fold the monotonic request counter into
-                # the zero base key ON DEVICE (no host-side compile to
-                # trip a recompile guard); seeded rows keep their host
-                # key bit-for-bit
                 with jax.named_scope("apex.sample"):
-                    base = jnp.zeros((2,), jnp.uint32)
-                    folded = jax.vmap(
-                        lambda i: jax.random.fold_in(base, i))(req_idx)
-                    keys = jnp.where(seeded[:, None], keys, folded)
-                    # the k-row draw_slots call vmaps per row over a
-                    # [1, vocab] lane — each row IS the solo-generate
-                    # first draw (same gumbel shape, same fold index)
-                    first = sampling.draw_slots(
-                        logits0, keys, p_lens - 1, temp, top_k, top_p,
-                        masks=masks)
-                    first_lp = jnp.take_along_axis(
-                        jax.nn.log_softmax(logits0, axis=-1),
-                        first[:, None], axis=1)[:, 0]
+                    keys, first, first_lp = _draw_first(
+                        logits0, keys, seeded, req_idx, p_lens, temp,
+                        top_k, top_p, masks)
                 if paged:
                     # the paged scatter: row i's bucket columns land
                     # in its own allocated pages (pad columns reach
@@ -1028,27 +1086,9 @@ class Engine:
                         page_size=p_sz)
                 else:
                     cache = gpt.cache_insert_slots(cache, blocks, slots)
-                hit_eos = (eos >= 0) & (first == eos)
-                done0 = hit_eos | (max_tokens <= 1)
-                new_state = {
-                    "tok": state["tok"].at[slots].set(first),
-                    "pos": state["pos"].at[slots].set(p_lens),
-                    "remaining": state["remaining"].at[slots].set(
-                        max_tokens - 1),
-                    "done": state["done"].at[slots].set(done0),
-                    "temp": state["temp"].at[slots].set(temp),
-                    "top_k": state["top_k"].at[slots].set(top_k),
-                    "top_p": state["top_p"].at[slots].set(top_p),
-                    "key": state["key"].at[slots].set(keys),
-                    "eos": state["eos"].at[slots].set(eos),
-                }
-                if spec:
-                    # seed the drafter's ring: the prompt tail (packed
-                    # host-side — the host knows the full prompt) plus
-                    # the first token drawn just above
-                    new_state["hist"] = state["hist"].at[slots].set(
-                        jnp.concatenate([hist0, first[:, None]],
-                                        axis=1))
+                new_state, hit_eos, done0 = _admitted_state(
+                    state, slots, first, p_lens, max_tokens, temp, top_k,
+                    top_p, keys, eos, hist0 if spec else None)
                 return cache, new_state, first, first_lp, hit_eos, done0
 
             return admit_local
@@ -1118,7 +1158,10 @@ class Engine:
         # adapter pool + per-row adapter ids)
         n_admit_args = 12 + int(paged) + int(spec)
         self._admits: Dict[Tuple[int, int], Any] = {}
-        for bucket in self._buckets:
+        self._fills: Dict[int, Any] = {}
+        if self._latent:
+            latent_engine.build(self)
+        for bucket in () if self._latent else self._buckets:
             fn = make_admit(bucket)
             for k in self._batch_sizes:
                 self._admits[(bucket, k)] = sm(
@@ -1241,19 +1284,12 @@ class Engine:
                                    req_idx, seeded, masks, *extra):
                 pages = extra[0] if paged else None
                 hist0 = extra[-1] if spec else None
-                base = jnp.zeros((2,), jnp.uint32)
-                folded = jax.vmap(
-                    lambda i: jax.random.fold_in(base, i))(req_idx)
-                keys = jnp.where(seeded[:, None], keys, folded)
                 # the fold position is p_len - 1, exactly the cold
                 # admission's — same logits (prefill_extend parity),
                 # same fold, same first draw
-                first = sampling.draw_slots(
-                    logits0, keys, p_lens - 1, temp, top_k, top_p,
-                    masks=masks)
-                first_lp = jnp.take_along_axis(
-                    jax.nn.log_softmax(logits0, axis=-1),
-                    first[:, None], axis=1)[:, 0]
+                keys, first, first_lp = _draw_first(
+                    logits0, keys, seeded, req_idx, p_lens, temp, top_k,
+                    top_p, masks)
                 blk = gpt.quantize_cache_block(cfg, scratch)
                 if paged:
                     cache = gpt.cache_insert_pages(
@@ -1261,24 +1297,9 @@ class Engine:
                         page_size=p_sz)
                 else:
                     cache = gpt.cache_insert_slot(cache, blk, slots[0])
-                hit_eos = (eos >= 0) & (first == eos)
-                done0 = hit_eos | (max_tokens <= 1)
-                new_state = {
-                    "tok": state["tok"].at[slots].set(first),
-                    "pos": state["pos"].at[slots].set(p_lens),
-                    "remaining": state["remaining"].at[slots].set(
-                        max_tokens - 1),
-                    "done": state["done"].at[slots].set(done0),
-                    "temp": state["temp"].at[slots].set(temp),
-                    "top_k": state["top_k"].at[slots].set(top_k),
-                    "top_p": state["top_p"].at[slots].set(top_p),
-                    "key": state["key"].at[slots].set(keys),
-                    "eos": state["eos"].at[slots].set(eos),
-                }
-                if spec:
-                    new_state["hist"] = state["hist"].at[slots].set(
-                        jnp.concatenate([hist0, first[:, None]],
-                                        axis=1))
+                new_state, hit_eos, done0 = _admitted_state(
+                    state, slots, first, p_lens, max_tokens, temp, top_k,
+                    top_p, keys, eos, hist0 if spec else None)
                 return (cache, new_state, first, first_lp, hit_eos,
                         done0)
 
@@ -1381,17 +1402,10 @@ class Engine:
                 tail_kv, logits0 = gpt.prefill_extend(
                     cfg, params, block, tails, t_lens - 1,
                     prefix_len=ps, lora=lora)
-                base = jnp.zeros((2,), jnp.uint32)
-                folded = jax.vmap(
-                    lambda i: jax.random.fold_in(base, i))(req_idx)
-                keys = jnp.where(seeded[:, None], keys, folded)
                 p_lens = ps + t_lens
-                first = sampling.draw_slots(
-                    logits0, keys, p_lens - 1, temp, top_k, top_p,
-                    masks=masks)
-                first_lp = jnp.take_along_axis(
-                    jax.nn.log_softmax(logits0, axis=-1),
-                    first[:, None], axis=1)[:, 0]
+                keys, first, first_lp = _draw_first(
+                    logits0, keys, seeded, req_idx, p_lens, temp, top_k,
+                    top_p, masks)
                 if paged:
                     # copy-on-write: the prefix pages are SHARED (the
                     # host mapped them into this slot's table row and
@@ -1420,24 +1434,9 @@ class Engine:
                     cache = gpt.cache_insert_slot(
                         cache, gpt.quantize_cache_block(cfg, tail_kv),
                         slots[0], pos=ps)
-                hit_eos = (eos >= 0) & (first == eos)
-                done0 = hit_eos | (max_tokens <= 1)
-                new_state = {
-                    "tok": state["tok"].at[slots].set(first),
-                    "pos": state["pos"].at[slots].set(p_lens),
-                    "remaining": state["remaining"].at[slots].set(
-                        max_tokens - 1),
-                    "done": state["done"].at[slots].set(done0),
-                    "temp": state["temp"].at[slots].set(temp),
-                    "top_k": state["top_k"].at[slots].set(top_k),
-                    "top_p": state["top_p"].at[slots].set(top_p),
-                    "key": state["key"].at[slots].set(keys),
-                    "eos": state["eos"].at[slots].set(eos),
-                }
-                if spec:
-                    new_state["hist"] = state["hist"].at[slots].set(
-                        jnp.concatenate([hist0, first[:, None]],
-                                        axis=1))
+                new_state, hit_eos, done0 = _admitted_state(
+                    state, slots, first, p_lens, max_tokens, temp, top_k,
+                    top_p, keys, eos, hist0 if spec else None)
                 return (cache, new_state, first, first_lp, hit_eos,
                         done0)
 
@@ -1487,8 +1486,10 @@ class Engine:
     @property
     def prefix_pool_enabled(self) -> bool:
         """True when ``EngineConfig.prefix_pool_slots > 0`` resolved to
-        at least one usable split point."""
-        return bool(self._prefix_splits)
+        at least one usable split point (under the latent mixer: to
+        any pool at all)."""
+        return bool(self._prefix_splits) or (
+            self._latent and self.engine_cfg.prefix_pool_slots > 0)
 
     @property
     def prefix_splits(self) -> Tuple[int, ...]:
@@ -1804,7 +1805,14 @@ class Engine:
         or the template is shorter than the smallest split bucket.
         Call AFTER :meth:`warmup` (which resets the pool); the insert
         rides a program warmup already compiled, so a recompile guard
-        stays armed through registration."""
+        stays armed through registration.
+
+        Under the latent mixer the prefix is filled straight into
+        pinned cache pages, a chunk a dispatch, and may have any whole
+        number of pages (:func:`latent_engine.register_prefix`)."""
+        if self._latent:
+            self._check_poisoned()
+            return latent_engine.register_prefix(self, tokens)
         if not self._prefix_splits:
             raise ValueError(
                 "prefix pool disabled (EngineConfig.prefix_pool_slots "
@@ -1886,6 +1894,8 @@ class Engine:
         lookups; no device work."""
         if not self._prefix_index:
             return None
+        if self._latent:
+            return latent_engine.match_prefix(self, prompt)
         t = tuple(int(x) for x in prompt)
         for split in sorted(self._prefix_splits, reverse=True):
             if split >= len(t):
@@ -2232,6 +2242,8 @@ class Engine:
             raise InjectedFault(
                 f"injected device error at admit: {spec.describe()}",
                 point="admit", spec=spec)
+        if self._latent:
+            return latent_engine.admit_many(self, items, AdmitResult)
         validated = [self._validate_admission(a) for a in items]
         slots_used = [a.slot for a in items]
         if len(set(slots_used)) != len(slots_used):
@@ -2823,7 +2835,9 @@ class Engine:
         self.cache, self.state = self._init(self._params)
         if self._chunk_size:
             self._chunk_scratch = self._chunk_scratch_init(self._params)
-        if self._paged and self._prefix_pages:
+        if self._latent:
+            latent_engine.refill_prefixes(self)
+        elif self._paged and self._prefix_pages:
             for page in sorted(self._prefix_pages):
                 pb = len(self._prefix_tokens[page])
                 self.cache = self._pool_pageins[pb](
@@ -2878,7 +2892,10 @@ class Engine:
         # shapes are what compile, and id 0 is the base row anyway
         wlora = lambda k: ((self.adapters, np.zeros((k,), np.int32))
                            if self._lora else ())
-        for (bucket, k), fn in sorted(self._admits.items()):
+        if self._latent:
+            latent_engine.warmup(self)
+        for (bucket, k), fn in (() if self._latent
+                                else sorted(self._admits.items())):
             # dummy args exercise shapes only: k pad-token prompts of
             # length 1, budget 1 (done at admission), no sampling
             self.cache, self.state, first, _, _, _ = fn(
@@ -3003,6 +3020,10 @@ class Engine:
             self._tables_dev = None
             self._slot_pages.clear()
             self._prefix_pages.clear()
+        if self._latent:
+            self._prefix_index.clear()
+            self._prefix_tokens.clear()
+            self._prefix_used = 0
         if self._prefix_splits:
             # warmup wrote junk into pool page 0 — reset the pool AND
             # the host registry, so templates register on clean pages
@@ -3039,6 +3060,8 @@ class Engine:
         by :meth:`compiled_cache_sizes` and the recompile sentinel so
         the two can never disagree on what is tracked."""
         items = []
+        if self._latent:
+            return latent_engine.program_items(self)
         if self._prefix_splits:
             items.append(("pool_init", self._pool_init))
             for pb, fn in sorted(self._pool_inserts.items()):

@@ -99,6 +99,7 @@ from apex_tpu.serving.engine import (
     Engine,
     StepHandle,
 )
+from apex_tpu.serving import latent_engine
 from apex_tpu.serving.pages import PagesExhausted
 from apex_tpu.serving.request import (
     FINISH_EOS,
@@ -907,6 +908,8 @@ class Scheduler:
         #: (re-)admission, so fault replay rides the same (page, split)
         #: and stays bit-identical
         self._prefix_hits: Dict[str, Tuple[int, int]] = {}
+        #: latent mixer: the device's routing totals at the last tick
+        self._routing_seen: Dict[str, int] = {}
         self._prefix_hit_count = 0
         self._prefix_miss_count = 0
         #: the in-flight chunked-prefill admission (one at a time —
@@ -1319,6 +1322,8 @@ class Scheduler:
                 self._collect_oldest()
         self._steps += 1
         with self._phase("sched.publish"):
+            if self.spans is not None:
+                self._count_routing()
             self._publish()
 
     def _publish(self) -> None:
@@ -1934,6 +1939,36 @@ class Scheduler:
         count("decode.chunks_grid",
               eng.engine_cfg.slots * (last // bk + 1))
 
+    def _count_keys(self, positions: List[int]) -> None:
+        """Under the latent mixer, what the sparse selection had to do
+        for query tokens at ``positions`` (the host's view): cache
+        positions the indexer scores (``position + 1`` a layer) and
+        positions attended (at most ``index_topk`` of them). A decode
+        chunk counts every step as if its row lived to the chunk's
+        end."""
+        lc = self.engine.cfg.latent
+        if lc is None or not positions:
+            return
+        layers = self.engine.cfg.num_layers
+        last = self.engine.engine_cfg.max_seq_len - 1
+        seen = [min(p, last) + 1 for p in positions]
+        self.spans.count("dsa.keys_scored", layers * sum(seen))
+        self.spans.count("dsa.keys_attended", layers * sum(
+            min(n, lc.index_topk) for n in seen))
+
+    def _count_routing(self) -> None:
+        """Under the latent mixer, the routed layers' totals as the
+        device added them up, read once a tick (a wait for the newest
+        dispatched program, so only with a span recorder)."""
+        if self.engine.cfg.latent is None:
+            return
+        now = latent_engine.routing_counts(self.engine)
+        for key, n in now.items():
+            delta = n - self._routing_seen.get(key, 0)
+            if delta > 0:
+                self.spans.count("moe." + key, delta)
+        self._routing_seen = now
+
     def _exclusion_cause(self) -> Optional[str]:
         """THE per-slot exclusion conditions, as a cause: a
         constrained request is active (its vocab mask advances per
@@ -2029,6 +2064,13 @@ class Scheduler:
             step_kw["spec"] = self._use_spec()
         if self.spans is not None:
             self._count_decode_chunks()
+            if self.engine.cfg.latent is not None:
+                cols = self._inflight_cols()
+                self._count_keys([
+                    len(act.request.prompt) + len(act.tokens)
+                    + cols.get(slot, 0) + c - 1
+                    for slot, act in self.active.items()
+                    for c in range(self.engine.engine_cfg.decode_chunk)])
         try:
             # the host-side cost of getting the chunk onto the device —
             # the half of the old engine.step section the pipeline
@@ -3400,11 +3442,18 @@ class Scheduler:
                 # what the admission programs were given, against what
                 # they ran at: rows x bucket is the padded batch
                 hits = self._prefix_hits
+                shared = [hits.get(r.request_id, (0, 0))[1] for r in reqs]
                 self._count_prefill(
-                    sum(len(r.prompt) - hits.get(r.request_id, (0, 0))[1]
-                        for r in reqs),
+                    sum(len(r.prompt) for r in reqs) - sum(shared),
                     sum(res.bucket for res in results),
                     len(reqs), n_groups)
+                if self.engine.prefix_pool_enabled:
+                    self.spans.count("prefix.tokens_shared", sum(shared))
+                    self.spans.count(
+                        "prefix.tokens_prefilled",
+                        sum(len(r.prompt) for r in reqs) - sum(shared))
+                self._count_keys([p for r, n in zip(reqs, shared)
+                                  for p in range(n, len(r.prompt))])
             tele = self.telemetry
             if tele is not None:
                 tele.admit_dispatches.inc(n_groups)

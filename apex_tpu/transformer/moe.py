@@ -220,3 +220,133 @@ def moe_ffn(cfg: MoEConfig, params: dict, x):
     p = jnp.mean(probs, axis=0)
     aux = E * jnp.sum(f * p)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# the dropless routed layer: sigmoid scores, group-limited top-k, a share
+# of the experts held here (DeepSeek-V3's layer; serving path). The
+# capacity-factor layer above stays for the trainer.
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class RoutedConfig:
+    """One dropless routed layer as ONE of the chips that share it runs
+    it: the router scores all ``num_experts`` and picks ``top_k`` of
+    them for every token, and this chip computes the part of the result
+    that its own experts ``experts_held = (first, count)`` give, plus
+    the shared expert. Nothing stands in for the absent chips. No token
+    is dropped and no capacity exists: the (token, expert) pairs held
+    here are sorted by expert and multiplied group by group."""
+
+    num_experts: int
+    experts_held: tuple            # (first, count)
+    top_k: int
+    n_group: int
+    topk_group: int
+    routed_scale: float
+
+    def __post_init__(self):
+        first, count = self.experts_held
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(
+                f"experts_held={self.experts_held} outside the "
+                f"{self.num_experts} routed experts")
+        if self.num_experts % self.n_group \
+                or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(
+                f"n_group={self.n_group} must divide num_experts="
+                f"{self.num_experts} and hold topk_group="
+                f"{self.topk_group}")
+        if self.top_k > self.topk_group * (self.num_experts
+                                           // self.n_group):
+            raise ValueError(
+                f"top_k={self.top_k} experts do not fit in "
+                f"{self.topk_group} groups")
+
+
+def routed_select(rcfg: RoutedConfig, router, x):
+    """``x [n, hidden]`` -> ``(experts [n, top_k] int32, weights [n,
+    top_k] float32)`` over ALL the experts. Scores are the sigmoid of
+    the float32 router product; the choice is made on score + bias (the
+    bias corrects the load and never weights the result): a group's
+    score is the sum of its two largest, the best ``topk_group`` groups
+    stay, the best ``top_k`` experts inside them win; the winners'
+    scores are renormalised to sum to 1 and scaled."""
+    e, g = rcfg.num_experts, rcfg.n_group
+    s = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router["kernel"].astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    choice = s + router["bias"].astype(jnp.float32)
+    g_score = jnp.sum(lax.top_k(choice.reshape(-1, g, e // g), 2)[0], -1)
+    kept = lax.top_k(g_score, rcfg.topk_group)[1]
+    g_mask = jnp.any(kept[:, :, None] == jnp.arange(g)[None, None], 1)
+    choice = jnp.where(jnp.repeat(g_mask, e // g, -1), choice, -jnp.inf)
+    experts = lax.top_k(choice, rcfg.top_k)[1].astype(jnp.int32)
+    w = jnp.take_along_axis(s, experts, -1)
+    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20) * rcfg.routed_scale
+    return experts, w
+
+
+def swiglu(x, p):
+    """``(silu(x gate) * (x up)) down``."""
+    h = jax.nn.silu(x @ p["gate"]) * (x @ p["up"])
+    return h @ p["down"]
+
+
+def grouped_swiglu(x, experts, sizes):
+    """``x [m, hidden]`` whose rows are sorted by expert, ``sizes [E]``
+    rows for each of the ``E`` experts held (rows past their sum are
+    nobody's and come out as garbage the caller drops) through each
+    row's own SwiGLU: three grouped products, no padding to a
+    capacity."""
+    from apex_tpu.kernels.grouped_matmul import grouped_matmul as dot
+
+    h = jax.nn.silu(dot(x, experts["gate"], sizes)) \
+        * dot(x, experts["up"], sizes)
+    return dot(h, experts["down"], sizes)
+
+
+def routed_ffn(rcfg: RoutedConfig, p, x, live=None):
+    """The layer on ``x [n, hidden]`` -> ``(y [n, hidden], counts)``.
+    ``p``: ``router {kernel [hidden, E_all], bias [E_all]}``, ``experts
+    {gate, up [E_held, hidden, ffn], down [E_held, ffn, hidden]}``,
+    ``shared {gate, up, down}``. ``live [n] bool`` marks the rows that
+    are real tokens (padding is still computed: shapes are static; it
+    is only kept out of the counts). ``counts`` is int32 ``[4]``: pairs
+    routed (``live rows x top_k``), pairs held here, held experts that
+    at least one live row chose, and held experts offered (their
+    number, in a forward with a live row)."""
+    n, k = x.shape[0], rcfg.top_k
+    first, held = rcfg.experts_held
+    with jax.named_scope("apex.moe.route"):
+        experts, w = routed_select(rcfg, p["router"], x)
+        # the pairs, flat and sorted by expert; the experts of other
+        # chips sort behind the last one held and form no group
+        local = experts.reshape(-1) - first
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(key[:, None] == jnp.arange(held)[None], 0,
+                        dtype=jnp.int32)
+        rows = order // k
+    with jax.named_scope("apex.moe.experts"):
+        out = grouped_swiglu(jnp.take(x, rows, axis=0), p["experts"],
+                             sizes)
+        gate = jnp.where(jnp.take(mine, order),
+                         jnp.take(w.reshape(-1), order), 0.0)
+        # garbage rows (nobody's) may hold anything, NaN included
+        out = jnp.where(gate[:, None] != 0, out.astype(jnp.float32)
+                        * gate[:, None], 0.0)
+        # back to token order (the sort undone) and the k parts summed
+        y = jnp.sum(jnp.take(out, jnp.argsort(order), axis=0
+                             ).reshape(n, k, -1), 1)
+    with jax.named_scope("apex.moe.shared"):
+        y = y.astype(x.dtype) + swiglu(x, p["shared"])
+    lv = jnp.ones((n,), bool) if live is None else live.reshape(-1)
+    mine_live = mine.reshape(n, k) & lv[:, None]
+    hit = jnp.any((local.reshape(n, k)[..., None] == jnp.arange(held))
+                  & mine_live[..., None], (0, 1))
+    counts = jnp.stack([jnp.sum(lv) * k, jnp.sum(mine_live), jnp.sum(hit),
+                        jnp.any(lv) * held]).astype(jnp.int32)
+    return y, counts
