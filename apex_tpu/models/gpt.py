@@ -1803,7 +1803,7 @@ def decode_steps(cfg: GPTConfig, params, cache, state, n: int, *,
             if draw_fn is None:
                 nxt = _sampling.draw_slots(
                     logits, st["key"], st["pos"], st["temp"],
-                    st["top_k"], st["top_p"], masks=masks)
+                    st["top_k"], st["top_p"], masks=masks, live=live)
             else:
                 nxt = draw_fn(logits, st["pos"])
             lp = jnp.take_along_axis(
@@ -2001,7 +2001,7 @@ def decode_steps_spec(cfg: GPTConfig, params, cache, state, n: int, *,
             if draw_fn is None:
                 nxt = _sampling.draw_slots(
                     lg, st["key"], tj, st["temp"], st["top_k"],
-                    st["top_p"], masks=masks)
+                    st["top_p"], masks=masks, live=live0)
             else:
                 nxt = draw_fn(lg, tj)
             if j > 0:

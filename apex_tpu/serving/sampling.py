@@ -16,6 +16,13 @@ Python-int/float parameters (free when disabled); the traced variant
 inside :func:`draw_slots` takes them as device scalars so per-request
 values never trigger a recompile, and is value-equal to the static form
 for enabled and disabled settings alike.
+
+:func:`draw_slots` does only what some live row asks for: one
+``lax.switch`` inside the compiled program, on a level reduced from the
+per-slot parameters, so a step whose rows are all greedy runs no sort,
+softmax, cumsum, ``fold_in`` or gumbel draw (its docstring has the
+three levels). The level is data: one compiled program serves every
+mix of requests.
 """
 
 from __future__ import annotations
@@ -117,37 +124,81 @@ def draw(logits, t, *, temperature: float = 0.0, top_k: int = 0,
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
+def asks(temperature, top_k, top_p, vocab: int):
+    """What rows ask of the draw, as ``(drawn, filtered)``: sampled at
+    all (``temperature > 0``), and sampled through a filter that can
+    drop a position (``0 < top_k < vocab`` or ``0 < top_p < 1``).
+    Elementwise over device vectors (:func:`draw_slots`' levels) or
+    over one request's Python scalars (the scheduler's
+    ``sample.dispatches_*`` counts): one definition for both."""
+    drawn = temperature > 0
+    return drawn, drawn & (((top_k > 0) & (top_k < vocab))
+                           | ((top_p > 0.0) & (top_p < 1.0)))
+
+
 @jax.named_scope("apex.sample")
-def draw_slots(logits, keys, t, temperature, top_k, top_p, masks=None):
+def draw_slots(logits, keys, t, temperature, top_k, top_p, masks=None,
+               live=None):
     """Per-slot batched draw: ``logits [B, vocab]``; ``keys [B, 2]``
     (raw PRNG key data); ``t``/``temperature``/``top_k``/``top_p`` all
     ``[B]`` device vectors. ``masks`` (optional bool ``[B, vocab]``) is
     the per-slot constrained-decoding vocab mask — False positions are
-    dropped to the dtype minimum before either branch, so an all-True
-    row is bit-identical to the maskless path (the engine always passes
-    masks; unconstrained slots ride all-True rows). Returns ``[B]
-    int32``.
+    dropped to the dtype minimum before any draw, so an all-True row is
+    bit-identical to the maskless path (the engine always passes masks;
+    unconstrained slots ride all-True rows). ``live`` (optional bool
+    ``[B]``, default every row) says whose token the caller will use:
+    a row that is not live still gets a token, of no meaning. Returns
+    ``[B] int32``.
 
-    Slot ``b``'s token is bit-identical to
+    Live slot ``b``'s token is bit-identical to
     ``draw(logits[b:b+1], t[b], temperature=.., key=keys[b])[0]`` — the
     vmapped inner function sees a ``[1, vocab]`` row, so even the
-    categorical's gumbel noise has the solo-generate shape, and greedy
-    slots (``temperature <= 0``) take the argmax branch by ``where``
-    (their sampled lane divides by a safe 1.0 and is discarded)."""
+    categorical's gumbel noise has the solo-generate shape.
 
-    def one(lg, key, tt, temp, kk, pp, mask=None):
-        if mask is not None:
-            lg = jnp.where(mask, lg, jnp.finfo(lg.dtype).min)
-        safe = jnp.where(temp > 0, temp, jnp.float32(1.0))
-        scaled = _filter_logits_traced(lg / safe, kk, pp)
-        sampled = jax.random.categorical(
-            jax.random.fold_in(key, tt), scaled, axis=-1)
-        greedy = jnp.argmax(lg, axis=-1)
-        return jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
+    How much of the draw runs is chosen inside the compiled program,
+    by ``lax.switch`` on what the live rows' parameters ask for:
 
-    if masks is None:
-        return jax.vmap(one)(
-            logits[:, None], keys, t, temperature, top_k, top_p)[:, 0]
-    return jax.vmap(one)(
-        logits[:, None], keys, t, temperature, top_k, top_p,
-        masks[:, None])[:, 0]
+    0. no live row has ``temperature > 0``: argmax of the masked
+       logits — no sort, softmax, cumsum, ``fold_in`` or gumbel draw;
+    1. some live row is sampled, none with a filter on (``0 < top_k <
+       vocab`` or ``0 < top_p < 1``): the categorical draw over
+       ``logits / temperature`` without :func:`_filter_logits_traced`,
+       which is the identity for such rows (its threshold is the row's
+       minimum);
+    2. otherwise the filter and the draw for every row. Greedy rows of
+       levels 1 and 2 take their argmax by ``where`` (their sampled
+       lane divides by a safe 1.0 and is discarded): one filtered row
+       makes the whole batch pay the sort.
+
+    Each level returns for every live row what level 2 would, so the
+    choice never shows in a stream. The level is reduced from vectors
+    that are replicated over ``tp`` (the engine's per-slot state), so
+    every device of a mesh takes the same branch."""
+    vocab = logits.shape[-1]
+    if masks is not None:
+        logits = jnp.where(masks, logits, jnp.finfo(logits.dtype).min)
+    drawn, filtered = asks(temperature, top_k, top_p, vocab)
+    if live is not None:
+        drawn, filtered = drawn & live, filtered & live
+    level = (jnp.any(drawn).astype(jnp.int32)
+             + jnp.any(filtered).astype(jnp.int32))
+
+    def greedy(lg, *_):
+        return jnp.argmax(lg, axis=-1).astype(jnp.int32)
+
+    def sampled(filtering):
+        def one(lg, key, tt, temp, kk, pp):
+            safe = jnp.where(temp > 0, temp, jnp.float32(1.0))
+            scaled = lg / safe
+            if filtering:
+                scaled = _filter_logits_traced(scaled, kk, pp)
+            token = jax.random.categorical(
+                jax.random.fold_in(key, tt), scaled, axis=-1)
+            return jnp.where(temp > 0, token,
+                             jnp.argmax(lg, axis=-1)).astype(jnp.int32)
+
+        return lambda lg, *rest: jax.vmap(one)(lg[:, None], *rest)[:, 0]
+
+    return jax.lax.switch(
+        level, (greedy, sampled(False), sampled(True)),
+        logits, keys, t, temperature, top_k, top_p)

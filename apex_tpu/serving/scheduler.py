@@ -100,6 +100,7 @@ from apex_tpu.serving.engine import (
     StepHandle,
 )
 from apex_tpu.serving import latent_engine
+from apex_tpu.serving import sampling
 from apex_tpu.serving.pages import PagesExhausted
 from apex_tpu.serving.request import (
     FINISH_EOS,
@@ -1939,6 +1940,24 @@ class Scheduler:
         count("decode.chunks_grid",
               eng.engine_cfg.slots * (last // bk + 1))
 
+    def _count_sampler(self) -> None:
+        """The draw's level into the recorder, at a dispatch, as the
+        host sees it: one dispatch, whether any active request is
+        sampled (``sampling.draw_slots`` level 1 or 2), and whether any
+        is sampled through a filter (level 2: the vocabulary sort runs
+        for every slot). A chunk whose requests are all greedy counts
+        0 and 0."""
+        vocab = self.engine.cfg.vocab_size
+        asked = [sampling.asks(p.temperature, p.top_k, p.top_p, vocab)
+                 for p in (act.request.sampling
+                           for act in self.active.values())]
+        count = self.spans.count
+        count("sample.dispatches", 1)
+        count("sample.dispatches_drawn",
+              int(any(drawn for drawn, _ in asked)))
+        count("sample.dispatches_sorted",
+              int(any(filtered for _, filtered in asked)))
+
     def _count_keys(self, positions: List[int]) -> None:
         """Under the latent mixer, what the sparse selection had to do
         for query tokens at ``positions`` (the host's view): cache
@@ -2064,6 +2083,7 @@ class Scheduler:
             step_kw["spec"] = self._use_spec()
         if self.spans is not None:
             self._count_decode_chunks()
+            self._count_sampler()
             if self.engine.cfg.latent is not None:
                 cols = self._inflight_cols()
                 self._count_keys([

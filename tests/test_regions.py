@@ -21,7 +21,8 @@ from apex_tpu import mesh as mx
 from apex_tpu.amp import ScalerConfig
 from apex_tpu.models import gpt, training
 from apex_tpu.optimizers import fused_adam
-from apex_tpu.serving import Engine, EngineConfig, Request, Scheduler
+from apex_tpu.serving import (
+    Engine, EngineConfig, Request, SamplingParams, Scheduler)
 from apex_tpu.telemetry import SpanRecorder
 from apex_tpu.transformer.testing import standalone_gpt_config
 
@@ -312,11 +313,13 @@ def test_prefill_counts_sum_to_what_was_admitted(span_rows, tiny_engine):
 
 
 def test_the_count_vocabulary_is_what_a_served_script_records(span_rows):
-    """Every count a run records is one of the six names docs/API.md
-    lists, and a run that admits and decodes records all six."""
+    """Every count a run records is one of the nine names docs/API.md
+    lists, and a run that admits and decodes records all nine."""
     assert {e[2] for e in span_rows[0] if e[0] == 2} == {
         "prefill.tokens_real", "prefill.tokens_padded", "prefill.rows",
-        "prefill.dispatches", "decode.chunks_needed", "decode.chunks_grid"}
+        "prefill.dispatches", "decode.chunks_needed", "decode.chunks_grid",
+        "sample.dispatches", "sample.dispatches_drawn",
+        "sample.dispatches_sorted"}
 
 
 def test_decode_chunk_counts_of_the_served_script(span_rows, tiny_engine):
@@ -357,6 +360,64 @@ def test_decode_chunks_needed_equals_the_hand_count(devices8):
               if e[0] == 2 and e[2].startswith("decode.")]
     assert counts == [("decode.chunks_needed", 2), ("decode.chunks_grid", 6),
                       ("decode.chunks_needed", 3), ("decode.chunks_grid", 6)]
+
+
+def test_sampler_counts_of_the_served_script(span_rows):
+    """One triple of counts a decode dispatch; the served script is
+    greedy, so no dispatch draws and none sorts — counted as 0, not
+    left out, so that the share exists and reads 0."""
+    rows = span_rows[0]
+    by = {n: [e[3] for e in rows if e[2] == "sample." + n]
+          for n in ("dispatches", "dispatches_drawn", "dispatches_sorted")}
+    dispatches = [e for e in rows if e[0] == 1 and e[2] == "engine.dispatch"]
+    assert by["dispatches"] == [1] * len(dispatches) and dispatches
+    assert by["dispatches_drawn"] == [0] * len(dispatches)
+    assert by["dispatches_sorted"] == [0] * len(dispatches)
+
+
+def test_sampler_counts_equal_the_hand_count(devices8):
+    """Three requests, two tokens a chunk, the first token drawn at
+    admission. Greedy ``g`` (9 tokens) decodes in dispatches 1-4.
+    Sampled, unfiltered ``s`` (3 tokens; ``top_k`` at the vocabulary's
+    width filters nothing) joins for dispatch 2 alone: drawn, not
+    sorted. Nucleus ``n`` (5 tokens) joins for dispatches 3 and 4:
+    drawn and sorted. Greedy ``h`` (3 tokens) decodes alone in
+    dispatch 5: 0 and 0 again."""
+    cfg = standalone_gpt_config(vocab_size=96, seq_len=64)
+    mesh = mx.build_mesh(tp=1, devices=devices8[:1])
+    spans = SpanRecorder()
+    with Engine(cfg, gpt.init(cfg, jax.random.PRNGKey(0)), mesh,
+                EngineConfig(slots=2, max_prompt_len=8, max_seq_len=24,
+                             decode_chunk=2)) as eng:
+        sched = Scheduler(eng, clock=_Clock(), spans=spans)
+
+        def dispatches():
+            return sum(1 for e in spans.events()
+                       if e[2] == "sample.dispatches")
+
+        def step_until(n):
+            while dispatches() < n:
+                sched.step()
+            assert dispatches() == n
+
+        sched.submit(Request("g", [1, 2, 3], max_tokens=9))
+        step_until(1)
+        sched.submit(Request("s", [4, 5], max_tokens=3,
+                             sampling=SamplingParams(
+                                 temperature=0.7, top_k=96, seed=1)))
+        step_until(2)
+        sched.submit(Request("n", [6, 7, 8], max_tokens=5,
+                             sampling=SamplingParams(
+                                 temperature=0.8, top_p=0.9, seed=2)))
+        step_until(4)
+        sched.submit(Request("h", [9], max_tokens=3))
+        sched.run_until_idle()
+        assert set(sched.completions) == {"g", "s", "n", "h"}
+    by = {n: [e[3] for e in spans.events() if e[2] == "sample." + n]
+          for n in ("dispatches", "dispatches_drawn", "dispatches_sorted")}
+    assert by["dispatches"] == [1, 1, 1, 1, 1]
+    assert by["dispatches_drawn"] == [0, 1, 1, 1, 0]
+    assert by["dispatches_sorted"] == [0, 0, 1, 1, 0]
 
 
 def test_without_a_recorder_nothing_is_annotated(tiny_engine, monkeypatch):

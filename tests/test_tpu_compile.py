@@ -151,29 +151,23 @@ def test_stacked_decode_kernels_compile_for_v5e(topo, mosaic, layout, kind):
         assert any(" copy(" in ln for ln in copies), made[:3]
 
 
-def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
-    """The engine's step program, compiled for the chip, holds the
-    cache in place from its parameter to its result: no instruction
-    anywhere yields an array of one layer's cache, none but the aliased
-    write kernel (once per layer-loop body) yields the whole cache —
-    no ``copy``, no ``transpose``: at head size 64 the resident cache
-    lies with its positions on the lanes and the kernels take it so —
-    and the program's temporaries are a fraction of the cache. The
-    cache's parameter and result keep the device's default layout."""
+class PlanEngine(Engine):
+    """Programs built and never run: nothing can be placed on a
+    described device."""
 
-    class PlanEngine(Engine):
-        """Programs built and never run: nothing can be placed on a
-        described device."""
+    def _build(self):
+        super()._build()
+        self.init_program = self._init
+        self._init = lambda params: (None, None)
 
-        def _build(self):
-            super()._build()
-            self.init_program = self._init
-            self._init = lambda params: (None, None)
 
+def _plan_engine(topo, vocab_size):
+    """``(engine, params, cache, state)`` of a small GPT-2-shaped
+    engine on one described chip, everything but the engine as shapes."""
     # a cache of 48 MiB: one small enough for the chip's fast memory is
     # moved there and back, which is no relayout and no model of a
     # deployment's
-    cfg = standalone_gpt_config(vocab_size=96, seq_len=1024,
+    cfg = standalone_gpt_config(vocab_size=vocab_size, seq_len=1024,
                                 hidden_size=256, num_heads=4,
                                 num_layers=3, compute_dtype=jnp.bfloat16)
     assert cfg.head_dim == 64
@@ -187,6 +181,20 @@ def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
         gpt.param_specs(cfg))
     eng = PlanEngine(cfg, params, mesh, ecfg)
     cache, state = jax.eval_shape(eng.init_program, params)
+    return eng, params, cache, state
+
+
+def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
+    """The engine's step program, compiled for the chip, holds the
+    cache in place from its parameter to its result: no instruction
+    anywhere yields an array of one layer's cache, none but the aliased
+    write kernel (once per layer-loop body) yields the whole cache —
+    no ``copy``, no ``transpose``: at head size 64 the resident cache
+    lies with its positions on the lanes and the kernels take it so —
+    and the program's temporaries are a fraction of the cache. The
+    cache's parameter and result keep the device's default layout."""
+    eng, params, cache, state = _plan_engine(topo, vocab_size=96)
+    cfg, ecfg = eng.cfg, eng.engine_cfg
     assert da._positions_on_lanes(cfg.head_dim, ecfg.max_seq_len,
                                   cache.dtype)
     traced = eng._step_variants[ecfg.decode_chunk].trace(
@@ -211,6 +219,74 @@ def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
     assert re.search(lies + r".* parameter\(", text)
     assert re.search(
         rf"entry_computation_layout=.*{lies}.*->.*{lies}", text)
+
+
+def _computations(text):
+    """``({name: lines}, entry)`` of a compiled program's text."""
+    comps, entry, cur = {}, None, None
+    for ln in text.splitlines():
+        head = re.match(r"^(ENTRY )?%(\S+) \(.*\{\s*$", ln)
+        if head:
+            cur = head.group(2)
+            comps[cur] = []
+            entry = cur if head.group(1) else entry
+        elif ln.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(ln)
+    return comps, entry
+
+
+def _always_run(comps, entry):
+    """The computations the entry reaches — loop bodies, fusions,
+    calls — without passing through a ``conditional``."""
+    seen, todo = set(), [entry]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for ln in comps[name]:
+            if " conditional(" not in ln:
+                todo += [n for n in re.findall(r"%([\w.\-]+)",
+                                               ln.split(" = ", 1)[-1])
+                         if n in comps]
+    return seen
+
+
+def test_engine_programs_sort_only_under_a_conditional(topo, mosaic):
+    """The sampler's vocabulary sort, in the step program and in an
+    admission program compiled for the chip, lies in a branch of a
+    ``conditional`` (``sampling.draw_slots``' level 2) and nowhere
+    the program always runs: not in the scan's ``while`` body, not in
+    the entry. The vocabulary is wide enough that a branch is no
+    candidate for a ``select`` of both sides."""
+    eng, params, cache, state = _plan_engine(topo, vocab_size=2048)
+    cfg, ecfg = eng.cfg, eng.engine_cfg
+    arr = jax.ShapeDtypeStruct
+    i32, f32 = jnp.int32, jnp.float32
+    bucket, k = sorted(eng._admits)[0]
+    programs = {
+        "step": eng._step_variants[ecfg.decode_chunk].lower(
+            params, cache, state,
+            arr((ecfg.slots, cfg.vocab_size), jnp.bool_)),
+        "admit": eng._admits[(bucket, k)].lower(
+            params, cache, state, arr((k,), i32), arr((k, bucket), i32),
+            arr((k,), i32), arr((k,), i32), arr((k,), f32),
+            arr((k,), i32), arr((k,), f32), arr((k, 2), jnp.uint32),
+            arr((k,), i32), arr((k,), i32), arr((k,), jnp.bool_),
+            arr((k, cfg.vocab_size), jnp.bool_))}
+    for name, lowered in programs.items():
+        comps, entry = _computations(lowered.compile().as_text())
+        sorting = {c for c, lines in comps.items()
+                   if any(" sort(" in ln for ln in lines)}
+        assert sorting, name
+        always = _always_run(comps, entry)
+        assert any(" conditional(" in ln for c in always
+                   for ln in comps[c]), name
+        assert not sorting & always, (name, sorted(sorting & always))
+        if name == "step":
+            assert any(" while(" in ln for ln in comps[entry])
 
 
 def test_latent_step_and_admission_compile_for_v5e(topo, mosaic):
@@ -246,12 +322,6 @@ def test_latent_step_and_admission_compile_for_v5e(topo, mosaic):
             s.shape, s.dtype, sharding=NamedSharding(mesh, sp)),
         jax.eval_shape(lambda: gpt.init(cfg, jax.random.PRNGKey(0))),
         gpt.param_specs(cfg))
-
-    class PlanEngine(Engine):
-        def _build(self):
-            super()._build()
-            self.init_program = self._init
-            self._init = lambda params: (None, None)
 
     eng = PlanEngine(cfg, params, mesh, ecfg)
     cache, state = jax.eval_shape(eng.init_program, params)
