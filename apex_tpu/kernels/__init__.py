@@ -20,6 +20,7 @@ from apex_tpu.kernels.decode_attention import (
     decode_attention,
     decode_attention_quantized,
     kv_storage_dtype,
+    live_rows,
     paged_attention,
     paged_attention_quantized,
     paged_gather_xla,
@@ -62,6 +63,7 @@ __all__ = [
     "decode_attention",
     "decode_attention_quantized",
     "kv_storage_dtype",
+    "live_rows",
     "paged_attention",
     "paged_attention_quantized",
     "paged_gather_xla",

@@ -20,8 +20,8 @@ composed by :func:`decode_attention`:
   whole tiles, so each grid step reads the one tile-aligned window
   that holds ``pos[b]`` in the K and the V plane of that layer,
   replaces position ``pos[b] % window`` and writes the window back;
-  the rest of the cache — every other layer included — is never
-  touched;
+  the rest of the cache — every other layer, and the rows the caller
+  marks dead (:func:`live_rows`) — is never touched;
 - **split-K read**: flash-decode attention — the cache horizon is swept
   in ``block_k`` chunks with a running online-softmax ``(out, lse)``
   merge (the same ``m/l/acc`` update as the training flash kernel),
@@ -29,15 +29,19 @@ composed by :func:`decode_attention`:
   vector-``pos`` semantics exactly: garbage cache entries past a row's
   position contribute exact softmax zeros. K and V chunks are blocks
   of plane 0 and plane 1 of the same stacked operand at the prefetched
-  layer. The grid is ``(b, h // hb, chunks)``: ``hb`` heads of one
+  layer. The grid is ``(rows, h // hb, chunks)``: ``hb`` heads of one
   batch row a grid step (as many as a VMEM budget holds; all of them
   at GPT-2's 16 x 64), their scores and statistics dense ``(hb, bk)``
   tiles. It fetches only what a step attends: the index maps clamp the
   chunk to the row's fill (``min(j, pos[b] // block_k)``), so a grid
   step past the row's last chunk names the block already resident and
-  the pipeline copies nothing, and a row the caller marks dead (a done
-  slot) names the block the row before it left, computes nothing and
-  comes out as zeros.
+  the pipeline copies nothing.
+
+Both grids walk only the rows the caller marks live: their first axis
+is a dynamic bound, the live count, over the scalar-prefetched list
+:func:`live_rows` builds (live rows first), so a done or empty slot is
+neither written nor read and its output is the zeros aliased to the
+read's output.
 
 **Which way the operand lies** (:func:`_positions_on_lanes`). The
 device does not keep ``[.., S, 64]`` row-major between programs: a
@@ -164,7 +168,10 @@ def _fit_block_k(want: int, sk: int, align: int) -> int:
 def _write_kernel(*refs, n_scalar, windows, page):
     """Land one column per cache operand. Each operand's block is the
     K and the V plane's tile-aligned window of ``w`` positions around
-    ``pos`` (``windows[k] == (w, lanes)``). Row-major storage ``[2, 1,
+    the position of grid row ``i``'s row (:func:`_row_of`; at ``-1``,
+    the one step of a grid with no live row, nothing lands and the
+    window goes back as it was) (``windows[k] == (w, lanes)``).
+    Row-major storage ``[2, 1,
     h, w, d]`` (the window down the sublanes) and scale planes ``[2,
     1, h, w]`` take an incoming block one position wide, which
     broadcasts over the window. Storage with the positions on the
@@ -176,9 +183,9 @@ def _write_kernel(*refs, n_scalar, windows, page):
     news = refs[n_scalar:n_scalar + n]
     olds = refs[n_scalar + n:n_scalar + 2 * n]
     outs = refs[n_scalar + 2 * n:]
-    pos = refs[1][pl.program_id(0)]
+    _, pos = _row_of(pl.program_id(0), refs[1], refs[2])
     if page:
-        pos = lax.rem(pos, page)
+        pos = lax.rem(pos, page)        # -1 stays -1: no cell is hit
     for new_ref, old_ref, out_ref, (w, lanes) in zip(news, olds, outs,
                                                      windows):
         if lanes:
@@ -199,23 +206,77 @@ def _layer_scalar(layer):
     return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
-def _write_column_planes(news, planes, layer, pos, table=None):
+def live_rows(live):
+    """The decode kernels' row list for one step, ``[b + 1] int32``:
+    the rows that ``live [b] bool`` marks, in order, then the others,
+    in order, then ``n``, how many are live. Grid row ``i < n`` of
+    both kernels works on row ``rows[i]``; the grids stop at ``n``
+    (one step where ``n`` is 0), so a dead row is neither written nor
+    read. The live set does not change between layers: a caller that
+    runs the kernels for every layer of a step builds this once.
+
+    Masked ``[b, b]`` reductions, not a sort or a gather: each of those
+    is a device operation of its own, and a sort of ``b`` keys costs
+    more than the whole list."""
+    live = jnp.asarray(live, jnp.bool_)
+    idx = jnp.arange(live.shape[0], dtype=jnp.int32)
+    n = jnp.sum(live, dtype=jnp.int32)
+    live_before = jnp.sum(live[None] & (idx[None] < idx[:, None]), axis=1,
+                          dtype=jnp.int32)
+    # a live row's place is the live rows before it; a dead row's, n
+    # plus the dead rows before it
+    place = jnp.where(live, live_before, n + idx - live_before)
+    rows = jnp.sum(jnp.where(place[None] == idx[:, None], idx[None], 0),
+                   axis=1, dtype=jnp.int32)
+    return jnp.concatenate([rows, n[None]])
+
+
+def _every_row(b: int):
+    """:func:`live_rows` of a step whose ``b`` rows are all live."""
+    return jnp.arange(b + 1, dtype=jnp.int32)
+
+
+def _row_of(i, pos, rows):
+    """``(row, position)`` of grid row ``i``: row ``rows[i]`` at its
+    ``pos`` while ``i`` is under the live count ``rows[-1]``; past it —
+    only the one step of a grid with no live row — at ``-1``, which no
+    cell and no chunk is at or before. Works on prefetched scalar refs
+    and, for the tests' walk of the grid, on host arrays."""
+    row = rows[i]
+    return row, jnp.where(i < rows[rows.shape[0] - 1], pos[row], -1)
+
+
+def _grid_rows(rows, b: int):
+    """The kernels' row-axis extent: ``b`` when every row is live
+    (``rows`` None, the list :func:`_every_row`), else the live count
+    as a dynamic grid bound, at least 1 (a grid with no live row keeps
+    one step, which writes a window back as it was and reads
+    nothing)."""
+    if rows is None:
+        return _every_row(b), b
+    return rows, jnp.maximum(rows[b], 1)
+
+
+def _write_column_planes(news, planes, layer, pos, table=None, live=None):
     """Write ``news[k] [2, b, h(, d)]`` (the K and the V row) into
     position ``pos[b]`` of layer ``layer`` of ``planes[k]`` — stacked
     contiguous caches ``[L, 2, b, h, S(, d)]``, or, with ``table [b,
     max_pages]``, page pools ``[L, 2, num_pages, h, P(, d)]`` where the
     cell is ``(table[b, pos // P], pos % P)``. ``planes`` is ``[kv]``
-    or the quantized ``[kv, scale]``. One grid step per batch row;
-    every operand is aliased input→output so only the windows holding
-    the written cells move, whatever ``L`` is. ``0 <= pos[b]`` must lie
-    inside the row's horizon, and rows must target distinct windows —
-    except inside a shared garbage/sink page, where the pipelined
-    read-modify-write of one window by two rows keeps only one row's
-    cell (the sink holds garbage by contract)."""
+    or the quantized ``[kv, scale]``. One grid step per row that
+    ``live`` (a :func:`live_rows` list; None: every row) names; a dead
+    row's window is neither read nor written. Every operand is aliased
+    input→output, so only the windows holding the written cells move,
+    whatever ``L`` is. ``0 <= pos[b]`` must lie inside the row's
+    horizon, and rows must target distinct windows — except inside a
+    shared garbage/sink page, where the pipelined read-modify-write of
+    one window by two rows keeps only one row's cell (the sink holds
+    garbage by contract)."""
     b = news[0].shape[1]
     p_sz = planes[0].shape[4]
     paged = table is not None
     mp = table.shape[1] if paged else 0
+    rows, grid_b = _grid_rows(live, b)
     new_ops, new_specs, plane_ops, plane_specs, windows = [], [], [], [], []
     for new, plane in zip(news, planes):
         h = plane.shape[3]
@@ -237,11 +298,12 @@ def _write_column_planes(news, planes, layer, pos, table=None):
             block, at = (w,), 0
             new, new_block = jnp.expand_dims(new, 3), (2, 1, h, 1)
 
-        def where(i, layer_ref, pos_ref, *tbl_ref, w=w, at=at,
+        def where(i, layer_ref, pos_ref, rows_ref, *tbl_ref, w=w, at=at,
                   n=len(block)):
-            row, col = i, pos_ref[i]
+            row, col = _row_of(i, pos_ref, rows_ref)
+            col = jnp.maximum(col, 0)
             if paged:
-                row = tbl_ref[0][i * mp + lax.div(col, p_sz)]
+                row = tbl_ref[0][row * mp + lax.div(col, p_sz)]
                 col = lax.rem(col, p_sz)
             tail = [0] * n
             tail[at] = lax.div(col, w)
@@ -250,17 +312,18 @@ def _write_column_planes(news, planes, layer, pos, table=None):
         new_ops.append(new.astype(plane.dtype))
         new_specs.append(pl.BlockSpec(
             new_block,
-            lambda i, *_, n=len(new_block): (0, i) + (0,) * (n - 2)))
+            lambda i, layer_ref, pos_ref, rows_ref, *_, n=len(new_block):
+            (0, rows_ref[i]) + (0,) * (n - 2)))
         plane_ops.append(plane)
         plane_specs.append(pl.BlockSpec((None, 2, 1, h) + block, where))
         windows.append((w, lanes))
-    scalars = [_layer_scalar(layer), jnp.asarray(pos, jnp.int32)]
+    scalars = [_layer_scalar(layer), jnp.asarray(pos, jnp.int32), rows]
     if paged:
         scalars.append(jnp.asarray(table, jnp.int32).reshape(-1))
     n_scalar, n = len(scalars), len(planes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_scalar,
-        grid=(b,),
+        grid=(grid_b,),
         in_specs=new_specs + plane_specs,
         out_specs=plane_specs,
     )
@@ -322,7 +385,9 @@ def stacked_write_columns(k_new, v_new, cache, layer, pos, *, table=None,
     quantized into one storage column plus one fp32 scale cell. The
     cache is aliased input→output, so only the touched windows of that
     one layer move (the speculative verify forward's cache landing, T =
-    draft k + 1). Returns the cache.
+    draft k + 1). Every row is written: only the one-column step of
+    :func:`stacked_decode_attention` takes a ``live`` list and skips
+    the dead rows. Returns the cache.
 
     Columns past the horizon are CLAMPED onto the row's last column
     ``S - 1``: a row whose tail lanes overrun the cache end (a
@@ -464,64 +529,38 @@ def _heads_per_step(h: int, d: int, bk: int, dtype, quant: bool,
     return max(n for n in range(1, h + 1) if h % n == 0 and n <= fit)
 
 
-def _fetch_table(pos, live, bk: int, groups: int, chunks: int):
-    """Which blocks each grid row of the read names, ``[2, b] int32``:
-    ``row`` and ``pin``. A live row sweeps its own chunks (``row[i] ==
-    i``, ``pin[i] == -1``). A dead row names ONE block through all its
-    grid steps — head group ``pin // chunks``, chunk ``pin % chunks``
-    of row ``row[i]`` — and it is the block already resident: the last
-    one the live row before it fetched, or, for dead rows that lead
-    the batch, the first one the first live row will fetch. So a dead
-    row moves no bytes. ``live`` None: every row is live."""
-    b = pos.shape[0]
-    idx = jnp.arange(b, dtype=jnp.int32)
-    if live is None:
-        return jnp.stack([idx, jnp.full_like(idx, -1)])
-    # masked [b, b] reductions, not cummax / argmax / take: this runs
-    # once per layer call inside the model's scan, where each of those
-    # is a device operation of its own
-    rows = jnp.where(live, idx, -1)[None]
-    prev = jnp.max(jnp.where(idx[None] <= idx[:, None], rows, -1),
-                   axis=1)                         # live row at or before
-    first = jnp.min(jnp.where(live, idx, b)) % b       # 0 where none is
-    row = jnp.where(prev >= 0, prev, first)
-    left = (groups - 1) * chunks + jnp.max(
-        jnp.where(idx[None] == row[:, None], pos[None] // bk, 0), axis=1)
-    pin = jnp.where(live, -1, jnp.where(prev >= 0, left, 0))
-    return jnp.stack([row, pin])
-
-
-def _block_index(g, j, pos, row, pin, bk: int, chunks: int):
+def _block_index(i, g, j, pos, rows, bk: int):
     """``(row, head group, chunk)`` of the K / V block that grid step
-    ``(i, g, j)`` names, from row ``i``'s ``pos`` and its
-    :func:`_fetch_table` entries. The chunk is clamped to the row's
-    fill: steps past its last chunk name the block already resident,
-    and the pipeline skips the copy."""
-    live = pin < 0
-    c = jnp.where(live, jnp.minimum(j, pos // bk), pin % chunks)
-    return row, jnp.where(live, g, pin // chunks), c
+    ``(i, g, j)`` names: row ``rows[i]`` (:func:`_row_of`) at its chunk
+    ``j`` clamped to its fill, so steps past its last chunk name the
+    block already resident and the pipeline skips the copy. The one
+    step of a grid with no live row names chunk 0 of ``rows[0]``."""
+    row, p = _row_of(i, pos, rows)
+    return row, g, jnp.minimum(j, jnp.maximum(p, 0) // bk)
 
 
-def _attn_kernel(*refs, n_scalar, quant, lanes, scale, bk, smax):
-    """Grid ``(b, h // hb, chunks)``: ``hb`` heads of one batch row
-    swept over the row's horizon in ``bk``-position chunks, scores and
-    statistics as dense ``(hb, bk)`` / ``(hb, lanes)`` tiles. A head's
-    K and V chunk are ``(d, bk)`` blocks where the operand has the
-    positions on the lanes (``lanes``), ``(bk, d)`` where it is
-    row-major: the same two dots, each contracting the other way.
-    ``quant`` adds the two fp32 scale-block refs of the int8/fp8
-    layout. A dead row comes with ``pos == -1``: no chunk is at or
-    before it, so it does no arithmetic and writes zeros."""
-    pos_ref = refs[1]
+def _attn_kernel(*refs, n_scalar, quant, lanes, scale, bk, smax, zeros):
+    """Grid ``(rows, h // hb, chunks)``: ``hb`` heads of one batch row
+    (:func:`_row_of`) swept over the row's horizon in ``bk``-position
+    chunks, scores and statistics as dense ``(hb, bk)`` / ``(hb,
+    lanes)`` tiles. A head's K and V chunk are ``(d, bk)`` blocks where
+    the operand has the positions on the lanes (``lanes``), ``(bk, d)``
+    where it is row-major: the same two dots, each contracting the
+    other way. ``quant`` adds the two fp32 scale-block refs of the
+    int8/fp8 layout; ``zeros`` (0 or 1) counts the output's aliased
+    zeros, which come before them all, left in HBM and never touched. A row at ``pos == -1`` (a grid
+    with no live row) has no chunk at or before it, so it does no
+    arithmetic and writes zeros."""
+    blocks = refs[n_scalar + zeros:]
     if quant:
         (q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref,
-         l_ref) = refs[n_scalar:]
+         l_ref) = blocks
     else:
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs[n_scalar:]
+        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = blocks
     hb, d = acc_ref.shape
     j = pl.program_id(2)        # split-K chunk of the horizon
     nk = pl.num_programs(2)
-    pos = pos_ref[pl.program_id(0)]
+    _, pos = _row_of(pl.program_id(0), refs[1], refs[2])
 
     @pl.when(j == 0)
     def _init():
@@ -611,10 +650,11 @@ def _run_attn(q, planes, layer, pos, scale, *, bk, table=None, live=None):
     and plane 1 of the one ``kv`` operand, read where and as it lies
     (:func:`_positions_on_lanes`: ``(d, bk)`` blocks of ``swapaxes(kv,
     4, 5)``, or ``(bk, d)`` blocks of ``kv``), ``hb`` heads at a time
-    (:func:`_heads_per_step`). Only the chunks a live
-    row attends are fetched (:func:`_block_index`); rows that ``live
-    [b] bool`` marks dead fetch and compute nothing and come out as
-    zeros.
+    (:func:`_heads_per_step`). The grid's first axis runs over the
+    rows that ``live`` (a :func:`live_rows` list; None: every row)
+    names, and only the chunks a row attends are fetched
+    (:func:`_block_index`); a dead row is not in the grid at all, and
+    its output is the zeros aliased to the output, untouched.
 
     One layer's fp32 scale planes (a sixteenth of its int8/fp8 bytes
     at head size 64) are sliced out for the read, heads on the
@@ -633,28 +673,32 @@ def _run_attn(q, planes, layer, pos, scale, *, bk, table=None, live=None):
     hb = _heads_per_step(h, d, bk, kv.dtype, quant, lanes)
     groups = h // hb
 
-    def block(i, g, j, pos_ref, fetch_ref, tbl_ref):
+    rows, grid_b = _grid_rows(live, b)
+
+    def block(i, g, j, pos_ref, rows_ref, tbl_ref):
         # (leading, head-group, position-chunk) block index
-        row, g, c = _block_index(g, j, pos_ref[i], fetch_ref[0, i],
-                                 fetch_ref[1, i], bk, chunks)
+        row, g, c = _block_index(i, g, j, pos_ref, rows_ref, bk)
         if paged:
             return tbl_ref[0][row * mp + c], g, 0
         return row, g, c
 
     def data_map(plane):
-        def index(i, g, j, layer_ref, pos_ref, fetch_ref, *tbl_ref):
-            lead, g, c = block(i, g, j, pos_ref, fetch_ref, tbl_ref)
+        def index(i, g, j, layer_ref, pos_ref, rows_ref, *tbl_ref):
+            lead, g, c = block(i, g, j, pos_ref, rows_ref, tbl_ref)
             return (layer_ref[0], plane, lead, g) + (
                 (0, c) if lanes else (c, 0))
         return index
 
     def scale_map(plane):
-        def index(i, g, j, layer_ref, pos_ref, fetch_ref, *tbl_ref):
-            lead, g, c = block(i, g, j, pos_ref, fetch_ref, tbl_ref)
+        def index(i, g, j, layer_ref, pos_ref, rows_ref, *tbl_ref):
+            lead, g, c = block(i, g, j, pos_ref, rows_ref, tbl_ref)
             return plane, lead, g, 0, c
         return index
 
-    row_spec = pl.BlockSpec((1, 1, hb, d), lambda i, g, j, *_: (i, g, 0, 0))
+    row_spec = pl.BlockSpec(
+        (1, 1, hb, d),
+        lambda i, g, j, layer_ref, pos_ref, rows_ref, *_:
+        (rows_ref[i], g, 0, 0))
     operands, specs = [], []
     if quant:
         sc = lax.dynamic_index_in_dim(
@@ -669,16 +713,19 @@ def _run_attn(q, planes, layer, pos, scale, *, bk, table=None, live=None):
             operands.append(scale_rows)
             specs.append(pl.BlockSpec((None, 1, 1, hb, bk),
                                       scale_map(plane)))
-    if live is not None:
-        pos = jnp.where(live, pos, -1)
-    scalars = [_layer_scalar(layer), pos,
-               _fetch_table(pos, live, bk, groups, chunks)]
+    out_shape = jax.ShapeDtypeStruct((b, groups, hb, d), q.dtype)
+    # the rows the grid does not name keep these zeros: an output block
+    # no step names is never written back
+    zeros = ([] if live is None else
+             [jnp.zeros(out_shape.shape, out_shape.dtype)])
+    scalars = [_layer_scalar(layer), pos, rows]
     if paged:
         scalars.append(jnp.asarray(table, jnp.int32).reshape(-1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b, groups, chunks),
-        in_specs=[row_spec] + specs,
+        grid=(grid_b, groups, chunks),
+        in_specs=([pl.BlockSpec(memory_space=pl.ANY)] * len(zeros)
+                  + [row_spec] + specs),
         out_specs=row_spec,
         scratch_shapes=[
             pltpu.VMEM((hb, d), jnp.float32),
@@ -689,12 +736,14 @@ def _run_attn(q, planes, layer, pos, scale, *, bk, table=None, live=None):
     out = pl.pallas_call(
         functools.partial(_attn_kernel, n_scalar=len(scalars),
                           quant=quant, lanes=lanes, scale=scale, bk=bk,
-                          smax=smax),
+                          smax=smax, zeros=len(zeros)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, groups, hb, d), q.dtype),
+        out_shape=out_shape,
+        # operand order: (*scalars, *zeros, q, *operands)
+        input_output_aliases={len(scalars): 0} if zeros else {},
         name="decode_attn_read",
         interpret=use_interpret(),
-    )(*scalars, q.reshape(b, groups, hb, d), *operands)
+    )(*scalars, *zeros, q.reshape(b, groups, hb, d), *operands)
     return out.reshape(b, h, d)
 
 
@@ -719,15 +768,19 @@ def stacked_decode_attention(q, k_new, v_new, cache, layer, pos, *,
     either; ``layer`` an int32 scalar (traced or not); ``pos`` ``[b]
     int32`` each row's write/attend position (``0 <= pos[i] < S``;
     ``gpt.decode_step`` guarantees this by freezing done slots);
-    ``live`` optional ``[b] bool``, False for a row whose output the
-    caller discards (a done slot): its column is still written at its
-    frozen ``pos``, but the read fetches none of its history, does no
-    arithmetic for it and returns zeros in its row. Returns ``(out [b,
-    h, d], cache)``.
+    ``live`` optional, :func:`live_rows` of the ``[b] bool`` mask that
+    is False for a row whose output the caller discards (a done or an
+    empty slot): both kernels' grids run over the live rows alone, so
+    a dead row's column is NOT written — its cache keeps the bytes it
+    had, every layer — the read fetches none of its history, does no
+    arithmetic for it and returns zeros in its row, and a step with no
+    live row leaves the cache byte for byte as it was. ``live`` None:
+    every row is live. Returns ``(out [b, h, d], cache)``.
 
-    The cache holds the new column at ``(layer, pos)``: the write
-    kernel aliases the whole stacked cache input→output and moves only
-    the ``b`` windows it touches, so a caller that owns the buffer (a
+    The cache holds the new column at ``(layer, pos)`` of every live
+    row: the write kernel aliases the whole stacked cache input→output
+    and moves only the windows it touches, so a caller that owns the
+    buffer (a
     scan carry) keeps it in place; a caller that still holds the input
     pays XLA's copy of all of it. ``out`` attends over positions
     ``0..pos[i]`` inclusive, bit-exactly masked like the XLA path: rows
@@ -760,15 +813,17 @@ def stacked_decode_attention(q, k_new, v_new, cache, layer, pos, *,
             f"cache shape {kv.shape} inconsistent with q {q.shape}")
     if pos.shape != (b,):
         raise ValueError(f"pos must be [{b}], got {pos.shape}")
-    if live is not None and live.shape != (b,):
-        raise ValueError(f"live must be [{b}], got {live.shape}")
+    if live is not None and live.shape != (b + 1,):
+        raise ValueError(f"live must be live_rows of a [{b}] mask, "
+                         f"[{b + 1}], got {live.shape}")
     s = float(scale) if scale is not None else 1.0 / d ** 0.5
     q, was16 = widen_f16(q)
     cache16 = kv.dtype == jnp.float16
     planes[0] = kv = widen_f16(kv)[0]
     pos = jnp.asarray(pos, jnp.int32)
     planes = list(_write_column_planes(
-        _stack_news(k_new, v_new, kind), planes, layer, pos, table))
+        _stack_news(k_new, v_new, kind), planes, layer, pos, table,
+        live))
     if table is not None:
         bk = kv.shape[4]
     else:

@@ -48,6 +48,7 @@ from apex_tpu.kernels.decode_attention import (
     cache_write_columns_xla as _cache_write_columns_xla,
     decode_block_k as _decode_block_k,
     kv_storage_dtype as _kv_storage_dtype,
+    live_rows as _live_rows,
     paged_gather_xla as _paged_gather_xla,
     paged_write_columns_xla as _paged_write_columns_xla,
     quantize_kv_rows as _quantize_kv_rows_impl,
@@ -1482,18 +1483,21 @@ def _cache_attend(cfg: GPTConfig, q, k_new, v_new, cache, layer, pos,
     window write a lane, quantized on the way under a quantized
     storage) and no layer is sliced out or stacked back. One column is
     then read by the split-K sweep with its online (out, lse) merge,
-    scales folded in per chunk, and rows that ``live [b] bool`` marks
-    dead read nothing and come out as zeros. T columns keep the
-    materialised read over the layer sliced out of the carry: T is tiny
-    (draft k + 1) and a T-row split-K sweep is future work
-    (docs/DESIGN.md). The XLA fallback — the CPU-testable backbone,
-    same semantics for live rows (it computes every row and ignores
-    ``live``) — slices the layer out (:func:`_cache_planes`), quantizes
-    the incoming rows once (the quantizer the kernels and prefill use),
-    writes every plane by one-hot select (a batched
-    ``dynamic_update_slice`` at per-row offsets is not expressible —
-    the full-cache rewrite the kernel exists to remove), puts the layer
-    back (:func:`_stack_planes`), and reads the row-contiguous view
+    scales folded in per chunk; ``live`` (the step's
+    :func:`apex_tpu.kernels.live_rows` list, built once outside the
+    layer scan) confines both kernels to the live rows: a dead row's
+    column is not written and its row reads nothing and comes out as
+    zeros. T columns keep the materialised read over the layer sliced
+    out of the carry: T is tiny (draft k + 1) and a T-row split-K sweep
+    is future work (docs/DESIGN.md). The XLA fallback — the
+    CPU-testable backbone, same semantics for live rows (it writes and
+    computes every row and ignores ``live``) — slices the layer out
+    (:func:`_cache_planes`), quantizes the incoming rows once (the
+    quantizer the kernels and prefill use), writes every plane by
+    one-hot select (a batched ``dynamic_update_slice`` at per-row
+    offsets is not expressible — the full-cache rewrite the kernel
+    exists to remove), puts the layer back (:func:`_stack_planes`),
+    and reads the row-contiguous view
     (gathered through ``table`` when paged, dequantized when
     quantized): a paged row reads the same bytes through the same
     einsum as a contiguous one, which is what the paged == contiguous
@@ -1654,12 +1658,15 @@ def _scan_cached_layers(cfg: GPTConfig, params, x, cache, pos, table,
     Under ``cfg.latent`` ``x [b, hidden]`` is one token a row at
     ``pos`` (scalar or ``[b]``) and ``x [b, T, hidden]`` columns
     ``pos[b] .. pos[b] + T - 1``; ``live [b]`` or ``[b, T]`` marks the
-    real tokens, whose routing the cache's ``counts`` add up."""
+    real tokens, whose routing the cache's ``counts`` add up; without
+    ``cfg.latent`` a ``[b]`` mask becomes the decode kernels' row list
+    here, once for every layer."""
     pool, ids, scale = lora if lora is not None else (None, None, None)
     lat = cfg.latent is not None
     one = lat and x.ndim == 2
     if lat:
         x, pos, live = latent.columns(x, pos, live)
+    rows = None if lat or live is None else _live_rows(live)
 
     def body(carry, inp):
         x, cache = carry
@@ -1670,7 +1677,7 @@ def _scan_cached_layers(cfg: GPTConfig, params, x, cache, pos, table,
                 cfg, layer_p, y, cache, layer, pos, table)
         else:
             attend = lambda q, k, v: _cache_attend(
-                cfg, q, k, v, cache, layer, pos, table, live)
+                cfg, q, k, v, cache, layer, pos, table, rows)
         x, aux, cache = _layer(
             cfg, layer_p, x, attend,
             lora=None if page is None else (page, ids, scale), live=live)
@@ -1729,10 +1736,10 @@ def decode_step(cfg: GPTConfig, params, cache, token, pos, table=None,
     all-zero row) leaves base rows numerically exact.
 
     ``live`` (optional ``[b] bool``) marks the rows whose logits the
-    caller will use. A dead row's K/V column is still written at its
-    ``pos``, but the decode kernels read none of its history and its
-    logits are those of a zero attention context: discard them. The
-    XLA fallback computes every row regardless.
+    caller will use. The decode kernels neither write a dead row's K/V
+    column nor read its history, and its logits are those of a zero
+    attention context: discard them. The XLA fallback writes and
+    computes every row regardless.
 
     The sequence shardings are stripped (:func:`_decode_entry_cfg`):
     decode has no sequence dim, and the SP gather/scatter would misread

@@ -1940,6 +1940,25 @@ class Scheduler:
         count("decode.chunks_grid",
               eng.engine_cfg.slots * (last // bk + 1))
 
+    def _count_decode_rows(self, ncols: int) -> None:
+        """Decode-kernel row counts into the recorder, at the dispatch
+        of a chunk of ``ncols`` columns, before it joins the in-flight
+        ones: the row steps in which the active slots are live, as the
+        host sees it (``min(remaining budget, ncols)`` each, the budget
+        being max tokens less the tokens emitted or in flight; an early
+        eos makes it an upper bound), and the row steps of a grid over
+        every slot (slots x ``ncols``). Their ratio is the share of the
+        slots' row steps that the decode kernels' grids, which run over
+        the live rows alone, still walk."""
+        cols = self._inflight_cols()
+        count = self.spans.count
+        count("decode.row_steps_live", sum(
+            min(max(act.request.max_tokens - len(act.tokens)
+                    - cols.get(slot, 0), 0), ncols)
+            for slot, act in self.active.items()))
+        count("decode.row_steps_grid",
+              self.engine.engine_cfg.slots * ncols)
+
     def _count_sampler(self) -> None:
         """The draw's level into the recorder, at a dispatch, as the
         host sees it: one dispatch, whether any active request is
@@ -2103,6 +2122,8 @@ class Scheduler:
                                     sorted(self.active.items())])
             return False
         t0 = timed.start
+        if self.spans is not None:
+            self._count_decode_rows(handle.ncols)
         # snapshot the live slots: by the time this chunk is fetched,
         # some may have been released (finish seen in an earlier chunk,
         # deadline retire) and their columns must be dropped

@@ -536,15 +536,42 @@ def _xla_step(c, layer):
     return np.einsum("bhs,bhsd->bhd", p, v)
 
 
+def _cell(c, row, layer):
+    """Index of row ``row``'s cell at its ``pos`` in every plane of
+    layer ``layer``: ``(layer, both planes, leading row or page, every
+    head, position)``."""
+    p = int(c["pos"][row])
+    if c["table"] is None:
+        return layer, slice(None), row, slice(None), p
+    page = jax.tree.leaves(c["cache"])[0].shape[4]
+    return (layer, slice(None), int(c["table"][row, p // page]),
+            slice(None), p % page)
+
+
+def _keep_dead_cells(c, cache, alive, layer):
+    """The planes of ``cache`` with each dead row's cell as it was in
+    ``c["cache"]``, on the host: what a step under ``alive`` leaves
+    where a step that writes every row left ``cache``."""
+    planes = [np.array(p) for p in jax.tree.leaves(cache)]
+    for p, old in zip(planes, jax.tree.leaves(c["cache"])):
+        old = np.asarray(old)
+        for i in np.flatnonzero(~alive):
+            p[_cell(c, i, layer)] = old[_cell(c, i, layer)]
+    return planes
+
+
 def _assert_read_matches(c, *lives, layer=1):
     """Under each liveness of ``lives`` (None: ``live`` not given), live
     rows match the XLA reference at the file's tolerances and dead rows
-    are exact zeros; under a mask, live rows and the cache are bit for
-    bit what a step with every row live gives (a dead row's column is
-    still written)."""
+    are exact zeros; under a mask, live rows are bit for bit what a
+    step with every row live gives, and so is the cache, except that a
+    dead row's cell keeps the bytes it had: its column is not
+    written."""
     step = jax.jit(lambda cache, layer, live=None: stacked_decode_attention(
         c["q"], c["k_new"], c["v_new"], cache, layer, c["pos"],
-        table=c["table"], kind=c["kind"], live=live, block_k=_RBK))
+        table=c["table"], kind=c["kind"], block_k=_RBK,
+        live=None if live is None else
+        decode_attention_mod.live_rows(live)))
     want = _xla_step(c, layer)
     tol = _QTOL[c["kind"]] if c["kind"] else _TOL[jnp.bfloat16]
 
@@ -566,7 +593,8 @@ def _assert_read_matches(c, *lives, layer=1):
         full = full or run(jnp.ones(_RB, bool))
         out, cache, alive = run(jnp.asarray(live))
         np.testing.assert_array_equal(out[alive], full[0][alive])
-        for g, w in zip(jax.tree.leaves(cache), jax.tree.leaves(full[1])):
+        for g, w in zip(jax.tree.leaves(cache),
+                        _keep_dead_cells(c, full[1], alive, layer)):
             np.testing.assert_array_equal(_bytes(g), _bytes(w))
 
 
@@ -600,38 +628,93 @@ def test_read_with_heads_split_over_grid_steps(monkeypatch, orientation,
     _assert_read_matches(c, _LIVE["dead_adjacent"], _LIVE["dead_first"])
 
 
+#: the write's dead rows: leading, trailing, between live ones, all
+_DEAD = {
+    "dead_lead": [False, False, True, True, True],
+    "dead_trail": [True, True, True, False, False],
+    "interleaved": [False, True, False, True, False],
+    "all_dead": [False] * _RB,
+}
+
+
+@pytest.mark.parametrize("live", list(_DEAD))
+@pytest.mark.parametrize("kind", ["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_write_lands_only_the_live_rows(orientation, layout, kind, live):
+    """The step's write under a live-row list, against the module's XLA
+    write of every row: each live row's cell holds what the XLA write
+    lands there, each dead row's window keeps its old bytes, and no
+    other layer moves; with no live row the cache comes back byte for
+    byte as it went in."""
+    layer = 1
+    c = _read_case(layout, kind)
+    alive = np.asarray(_DEAD[live])
+    _, got = jax.jit(lambda cache, layer, live: stacked_decode_attention(
+        c["q"], c["k_new"], c["v_new"], cache, layer, c["pos"],
+        table=c["table"], kind=c["kind"], block_k=_RBK,
+        live=decode_attention_mod.live_rows(live)))(
+            c["cache"], jnp.int32(layer), jnp.asarray(alive))
+    pos, table = c["pos"], c["table"]
+    news = [c["k_new"][:, :, None], c["v_new"][:, :, None]]
+    if c["kind"]:
+        (kq, ksc), (vq, vsc) = (quantize_kv_rows(n, c["kind"])
+                                for n in news)
+        news = [kq, ksc, vq, vsc]
+    write = (
+        (lambda plane, new: decode_attention_mod.cache_write_columns_xla(
+            plane, new, pos)) if table is None else
+        (lambda plane, new: decode_attention_mod.paged_write_columns_xla(
+            plane, new, table, pos)))
+    written = [np.asarray(write(plane, new)) for plane, new in
+               zip(_layer_of(c["cache"], layer), news)]
+    want = [np.array(p) for p in jax.tree.leaves(c["cache"])]
+    # (k, v) or (k, k_scale, v, v_scale) back into [kv] or [kv, scale]
+    for k, plane in enumerate(want):
+        plane[layer] = np.stack(written[k::len(want)])
+    want = _keep_dead_cells(c, want, alive, layer)
+    for g, w in zip(jax.tree.leaves(got), want):
+        np.testing.assert_array_equal(_bytes(g), _bytes(w))
+    if not alive.any():
+        for g, b in zip(jax.tree.leaves(got), jax.tree.leaves(c["cache"])):
+            np.testing.assert_array_equal(_bytes(g), _bytes(b))
+
+
 @pytest.mark.parametrize("groups", [1, 2])
 @pytest.mark.parametrize("live", list(_LIVE) + ["none"])
 def test_grid_walk_fetches_only_the_chunks_live_rows_need(live, groups):
-    """The index maps alone, walked over the grid in Python: the
-    blocks change ``groups * sum(pos // bk + 1 over live rows)`` times
-    (a step that names the block already resident copies nothing), a
-    live row's blocks are its own chunks in order, and a dead row
-    names the block the row before it left."""
+    """The row list and the index maps alone, walked over the grid in
+    Python: the grid's rows are the live rows in order (one step where
+    none is), the blocks change ``groups * sum(pos // bk + 1 over live
+    rows)`` times (a step that names the block already resident copies
+    nothing), and a live row's blocks are its own chunks in order."""
     bk, chunks = 16, 4
     pos = np.asarray([0, 15, 16, 63, 35], np.int32)
     alive = (np.zeros(5, bool) if live == "none" else
              np.ones(5, bool) if _LIVE[live] is None else
              np.asarray(_LIVE[live]))
-    given = None if live != "none" and _LIVE[live] is None else \
-        jnp.asarray(alive)
-    pos_k = np.where(alive, pos, -1)
-    row, pin = np.asarray(decode_attention_mod._fetch_table(
-        jnp.asarray(pos_k), given, bk, groups, chunks))
+    rows = np.asarray(
+        decode_attention_mod._every_row(5)
+        if live != "none" and _LIVE[live] is None
+        else decode_attention_mod.live_rows(jnp.asarray(alive)))
+    n = int(alive.sum())
+    assert rows[-1] == n
+    np.testing.assert_array_equal(rows[:n], np.flatnonzero(alive))
+    np.testing.assert_array_equal(rows[n:-1], np.flatnonzero(~alive))
     walk = []
-    for i in range(len(pos)):
+    for i in range(max(n, 1)):
         for g in range(groups):
             for j in range(chunks):
                 block = tuple(int(x) for x in
                               decode_attention_mod._block_index(
-                                  g, j, int(pos_k[i]), int(row[i]),
-                                  int(pin[i]), bk, chunks))
-                if alive[i]:
-                    assert block == (i, g, min(j, pos[i] // bk))
+                                  i, g, j, pos, rows, bk))
+                if i < n:
+                    assert block == (rows[i], g,
+                                     min(j, pos[rows[i]] // bk))
                 if not walk or walk[-1] != block:
                     walk.append(block)
     needed = groups * int(sum(p // bk + 1 for p in pos[alive]))
-    assert len(walk) == max(needed, 1)
+    # with no live row, the grid's one step names chunk 0 of each group
+    assert len(walk) == (needed if n else groups)
     assert len(set(walk)) == len(walk)      # nothing is fetched twice
 
 

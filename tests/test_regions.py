@@ -313,11 +313,12 @@ def test_prefill_counts_sum_to_what_was_admitted(span_rows, tiny_engine):
 
 
 def test_the_count_vocabulary_is_what_a_served_script_records(span_rows):
-    """Every count a run records is one of the nine names docs/API.md
-    lists, and a run that admits and decodes records all nine."""
+    """Every count a run records is one of the eleven names docs/API.md
+    lists, and a run that admits and decodes records all eleven."""
     assert {e[2] for e in span_rows[0] if e[0] == 2} == {
         "prefill.tokens_real", "prefill.tokens_padded", "prefill.rows",
         "prefill.dispatches", "decode.chunks_needed", "decode.chunks_grid",
+        "decode.row_steps_live", "decode.row_steps_grid",
         "sample.dispatches", "sample.dispatches_drawn",
         "sample.dispatches_sorted"}
 
@@ -357,9 +358,35 @@ def test_decode_chunks_needed_equals_the_hand_count(devices8):
         sched.run_until_idle()
         assert set(sched.completions) == {"a", "b"}
     counts = [(e[2], e[3]) for e in spans.events()
-              if e[0] == 2 and e[2].startswith("decode.")]
+              if e[0] == 2 and e[2].startswith("decode.chunks_")]
     assert counts == [("decode.chunks_needed", 2), ("decode.chunks_grid", 6),
                       ("decode.chunks_needed", 3), ("decode.chunks_grid", 6)]
+
+
+def test_decode_row_steps_equal_the_hand_count(devices8):
+    """Three requests on two slots, two steps a chunk, the first token
+    drawn at admission, each chunk fetched before the next dispatch.
+    Dispatch 1: ``a`` (4 tokens) has 3 of budget left and is live both
+    steps, ``b`` (2) one. Dispatch 2: ``b`` has ended and ``c`` (5)
+    taken its slot: ``a`` 1, ``c`` 2. Dispatch 3: ``a`` has ended, ``c``
+    2. A chunk's grid over every slot is 2 x 2 = 4."""
+    cfg = standalone_gpt_config(vocab_size=96, seq_len=64)
+    mesh = mx.build_mesh(tp=1, devices=devices8[:1])
+    spans = SpanRecorder()
+    with Engine(cfg, gpt.init(cfg, jax.random.PRNGKey(0)), mesh,
+                EngineConfig(slots=2, max_prompt_len=8, max_seq_len=24,
+                             decode_chunk=2)) as eng:
+        sched = Scheduler(eng, clock=_Clock(), spans=spans)
+        for rid, n in (("a", 4), ("b", 2), ("c", 5)):
+            sched.submit(Request(rid, [1, 2, 3], max_tokens=n))
+        sched.run_until_idle()
+        assert set(sched.completions) == {"a", "b", "c"}
+    rows = [(e[2], e[3]) for e in spans.events()
+            if e[0] == 2 and e[2].startswith("decode.row_steps_")]
+    live = [n for name, n in rows if name == "decode.row_steps_live"]
+    grid = [n for name, n in rows if name == "decode.row_steps_grid"]
+    assert grid == [4] * len(live)
+    assert live == [3, 3, 2], live
 
 
 def test_sampler_counts_of_the_served_script(span_rows):

@@ -65,16 +65,18 @@ def mosaic(monkeypatch):
     compilation_cache.reset_cache()
 
 
-def _read_grids(jaxpr):
-    """The grid of every ``decode_attn_read`` kernel in ``jaxpr``,
-    loops and calls included."""
+def _grids(jaxpr, kernel="decode_attn_read"):
+    """The grid of every ``kernel`` in ``jaxpr``, loops and calls
+    included; a dynamic bound (the decode kernels' count of live rows)
+    reads None."""
     found = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call" and \
-                "decode_attn_read" in str(eqn.params.get("name")):
-            found.append(tuple(eqn.params["grid_mapping"].grid))
+                kernel in str(eqn.params.get("name")):
+            found.append(tuple(d if isinstance(d, int) else None
+                               for d in eqn.params["grid_mapping"].grid))
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _read_grids(sub)
+            found += _grids(sub, kernel)
     return found
 
 
@@ -100,12 +102,14 @@ def test_stacked_decode_kernels_compile_for_v5e(topo, mosaic, layout, kind):
     """GPT-2's head shapes (16 heads of 64) at a horizon of 1024 and
     the serving cells' 40 slots: the layer-indexed write and read
     kernels, and the T-column write, on a stacked cache / page pool in
-    each storage; the read's grid is rows x head groups x chunks, all
-    16 heads in one group. A horizon of 1024 and pages of 128 lie with
-    their positions on the lanes in every storage, the kernels take
-    them so, and the donated cache is never copied; pages of 16 lie
-    with the PAGES on the lanes, the kernels take them row-major, and
-    the compiler relays the pool on the way in and out."""
+    each storage; the read's grid is live rows x head groups x chunks,
+    all 16 heads in one group, and the step's write grid is the live
+    rows (a dynamic bound) where the T-column write's is every row. A
+    horizon of 1024 and pages of 128 lie with their positions on the
+    lanes in every storage, the kernels take them so, and the donated
+    cache is never copied; pages of 16 lie with the PAGES on the lanes,
+    the kernels take them row-major, and the compiler relays the pool
+    on the way in and out."""
     layers, b, h, s_max, d = 2, 40, 16, 1024, 64
     page = {"contiguous": None, "paged": 128, "paged16": 16}[layout]
     one = SingleDeviceSharding(topo.devices[0])
@@ -123,8 +127,8 @@ def test_stacked_decode_kernels_compile_for_v5e(topo, mosaic, layout, kind):
 
     def step(cache, layer, q, k_new, v_new, cols, pos, live, table):
         out, cache = da.stacked_decode_attention(
-            q, k_new, v_new, cache, layer, pos, table=table, live=live,
-            kind=kind)
+            q, k_new, v_new, cache, layer, pos, table=table,
+            live=da.live_rows(live), kind=kind)
         return out, da.stacked_write_columns(
             cols, cols, cache, layer, pos, table=table, kind=kind)
 
@@ -133,7 +137,9 @@ def test_stacked_decode_kernels_compile_for_v5e(topo, mosaic, layout, kind):
         arr((b, h, 3, d), jnp.bfloat16), arr((b,), jnp.int32),
         arr((b,), jnp.bool_), table)
     bk = page or da.decode_block_k(s_max, storage, quantized=bool(kind))
-    assert _read_grids(traced.jaxpr.jaxpr) == [(b, 1, s_max // bk)]
+    assert _grids(traced.jaxpr.jaxpr) == [(None, 1, s_max // bk)]
+    assert _grids(traced.jaxpr.jaxpr, "decode_attn_write") == [
+        (None,)] + [(b,)] * 3
     compiled = traced.lower().compile()
     text = compiled.as_text()
     calls = lambda name: re.findall(
@@ -200,10 +206,10 @@ def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
     traced = eng._step_variants[ecfg.decode_chunk].trace(
         params, cache, state,
         jax.ShapeDtypeStruct((ecfg.slots, cfg.vocab_size), jnp.bool_))
-    # one read kernel in the program: slots x one group of all four
-    # heads x the chunks of the horizon
-    assert _read_grids(traced.jaxpr.jaxpr) == [
-        (ecfg.slots, 1, -(-ecfg.max_seq_len // eng.read_chunk))]
+    # one read kernel in the program: the live slots x one group of all
+    # four heads x the chunks of the horizon
+    assert _grids(traced.jaxpr.jaxpr) == [
+        (None, 1, -(-ecfg.max_seq_len // eng.read_chunk))]
     compiled = traced.lower().compile()
     text = compiled.as_text()
     assert not _yields(text, cache.shape[1:]), _yields(
@@ -219,6 +225,70 @@ def test_engine_step_program_has_no_cache_copy_in_its_loops(topo, mosaic):
     assert re.search(lies + r".* parameter\(", text)
     assert re.search(
         rf"entry_computation_layout=.*{lies}.*->.*{lies}", text)
+
+
+def _scan_bodies(jaxpr, length):
+    """The body of every ``scan`` of ``length`` steps in ``jaxpr``,
+    nested ones included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and eqn.params["length"] == length:
+            found.append(eqn.params["jaxpr"].jaxpr)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _scan_bodies(sub, length)
+    return found
+
+
+def _ops_over(jaxpr, shape):
+    """Operations anywhere in ``jaxpr`` that take an operand of
+    ``shape``."""
+    found = [eqn.primitive.name for eqn in jaxpr.eqns
+             if any(getattr(v.aval, "shape", None) == shape
+                    for v in eqn.invars)]
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _ops_over(sub, shape)
+    return found
+
+
+def test_chat_step_program_walks_the_live_rows(topo, mosaic):
+    """The chat cell's deployment at its real size (GPT-2 medium, 40
+    slots, chunks of 8 steps, a horizon of 1024), its step program
+    compiled for the described v5e: both decode kernels' row axes are
+    the live count, a dynamic bound; the row list is built once a
+    decode step, outside the layer scan, whose body holds no operation
+    over a ``[slots, slots]`` operand; and the plan is the 5.75 GiB of
+    the parent's program (AOT, PR 32) to 1 %."""
+    from benchmark.harness import recipe
+    from benchmark.jobs import serve_base
+
+    cfg, ecfg = serve_base.engine_setup(recipe.load_cell("gpt2m_chat"))
+    assert (ecfg.slots, ecfg.decode_chunk, ecfg.max_seq_len) == (
+        40, 8, 1024)
+    mesh = mx.build_mesh(tp=1, devices=list(topo.devices)[:1])
+    params = jax.tree.map(
+        lambda s, sp: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, sp)),
+        jax.eval_shape(lambda: gpt.init(cfg, jax.random.PRNGKey(0))),
+        gpt.param_specs(cfg))
+    eng = PlanEngine(cfg, params, mesh, ecfg)
+    cache, state = jax.eval_shape(eng.init_program, params)
+    traced = eng._step_variants[ecfg.decode_chunk].trace(
+        params, cache, state,
+        jax.ShapeDtypeStruct((ecfg.slots, cfg.vocab_size), jnp.bool_))
+    jaxpr = traced.jaxpr.jaxpr
+    assert _grids(jaxpr) == [(None, 1, ecfg.max_seq_len // eng.read_chunk)]
+    assert _grids(jaxpr, "decode_attn_write") == [(None,)]
+    square = (ecfg.slots, ecfg.slots)
+    (steps,) = _scan_bodies(jaxpr, ecfg.decode_chunk)
+    (layers,) = _scan_bodies(jaxpr, cfg.num_layers)
+    assert _ops_over(steps, square)
+    assert not _ops_over(layers, square), _ops_over(layers, square)
+    m = traced.lower().compile().memory_analysis()
+    plan = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes + m.generated_code_size_in_bytes
+            - m.alias_size_in_bytes)
+    assert abs(plan / 2 ** 30 - 5.75) < 0.0575, plan / 2 ** 30
 
 
 def _computations(text):
