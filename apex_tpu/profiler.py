@@ -47,6 +47,26 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
+def capture_start(logdir: str) -> float:
+    """Start of the newest capture under ``logdir`` on the profiler's
+    clock, in seconds: its events, in ``ProfileData`` and in the trace
+    files the profiler writes, lie at the wall clock less this
+    (``profile_start_time`` of the capture's "Task Environment"
+    plane)."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = max(glob.glob(os.path.join(
+        logdir, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)
+    plane = ProfileData.from_file(path).find_plane_with_name(
+        "Task Environment")
+    start, = (v for k, v in plane.stats if k == "profile_start_time")
+    return start * 1e-9
+
+
 class StepTimer:
     """Wall-clock per-step timing with device sync and derived rates.
 

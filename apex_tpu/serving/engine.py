@@ -43,9 +43,10 @@ front). Host-side policy (queueing, deadlines, metrics) lives in
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -389,6 +390,9 @@ def _threefry_key_data(seed: int) -> np.ndarray:
     return np.asarray(jax.random.PRNGKey(seed), np.uint32)
 
 
+_NO_SECTION = contextlib.nullcontext()
+
+
 class StepHandle:
     """One in-flight decode chunk: the ``[B, n]`` token/logprob/
     finished device futures a :meth:`Engine.step_async` dispatch
@@ -438,9 +442,17 @@ class StepHandle:
         """True when this handle carries a speculative chunk."""
         return self.spec_k > 0
 
-    def fetch(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    def fetch(self, section: Optional[Callable[[str], Any]] = None
+              ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Block until the chunk lands; returns ``(tokens [B, n],
-        logprobs [B, n], finished [B, n])`` as host arrays."""
+        logprobs [B, n], finished [B, n])`` as host arrays.
+
+        ``section`` (``name -> context manager``, e.g. a span
+        recorder's ``section``) times the two parts of a fetch:
+        ``engine.fetch.wait``, the copy of the tokens, which waits for
+        the device to finish the chunk, and ``engine.fetch.copy``, the
+        copies after it (logprobs, finished, and a speculative chunk's
+        valid columns)."""
         if self._out is not None:
             return self._out
         spec = self._plan.take("fetch") if self._plan is not None else None
@@ -453,11 +465,16 @@ class StepHandle:
             raise InjectedFault(
                 f"injected device error at fetch: {spec.describe()}",
                 point="fetch", spec=spec)
-        tokens = np.asarray(self._emit)
-        logprobs = np.asarray(self._logprobs)
-        finished = np.asarray(self._finished)
-        if self._valid_dev is not None:
-            self.valid = np.asarray(self._valid_dev)
+        wait, copy = ((_NO_SECTION, _NO_SECTION) if section is None else
+                      (section("engine.fetch.wait"),
+                       section("engine.fetch.copy")))
+        with wait:
+            tokens = np.asarray(self._emit)
+        with copy:
+            logprobs = np.asarray(self._logprobs)
+            finished = np.asarray(self._finished)
+            if self._valid_dev is not None:
+                self.valid = np.asarray(self._valid_dev)
         if spec is not None and spec.kind == KIND_NAN:
             # what a NaN logit batch looks like by the time the host
             # sees it: garbage token ids in the poisoned lanes

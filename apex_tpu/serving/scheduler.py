@@ -796,13 +796,16 @@ class Scheduler:
         #: test clocks produce deterministic timelines, and its
         #: sections are annotated into the profiler's trace
         #: (``apex.sched.*`` / ``apex.engine.*`` on the device trace's
-        #: clock). Without a recorder a phase costs one ``is None``.
+        #: clock), with a clock row here and at every tick's entry that
+        #: puts the recorder's other rows on that clock too. Without a
+        #: recorder a phase costs one ``is None``.
         self.telemetry = (None if registry is None
                           else _RegistryMetrics(registry, engine))
         self.spans = spans
         if spans is not None:
             spans.clock = self.clock
             spans.annotate = profiler.annotate
+            spans.anchor()
         self._registry = registry
         #: flight recorder (telemetry.flightrec.FlightRecorder) — the
         #: always-on black box: every load-bearing host decision is one
@@ -1293,6 +1296,8 @@ class Scheduler:
         self._dump_token += 1
         if self.health.state == HEALTH_FAILED:
             return
+        if self.spans is not None:
+            self.spans.anchor()
         with self._phase("sched.step"):
             self._tick()
 
@@ -2141,9 +2146,13 @@ class Scheduler:
             self._inflight.popleft()
         try:
             # the blocking wait for the chunk's value — under pipelining
-            # this shrinks toward zero while engine.dispatch stays put
+            # this shrinks toward zero while engine.dispatch stays put;
+            # with a recorder the handle splits it into the wait for the
+            # first copy and the copies after (engine.fetch.wait / .copy)
             with self._timed("engine.fetch") as timed:
-                tokens, logprobs, finished = handle.fetch()
+                tokens, logprobs, finished = (
+                    handle.fetch() if self.spans is None
+                    else handle.fetch(section=self.spans.section))
         except Exception as e:  # device error escaping the fetch
             self._recover(self.clock(), cause="fetch", detail=str(e),
                           affected=[a.request
@@ -3031,6 +3040,9 @@ class Scheduler:
                 elif e[0] == spans_mod._COUNT:
                     raw.append({"kind": "count", "t": e[1],
                                 "name": e[2], "n": e[3]})
+                elif e[0] == spans_mod._CLOCK:
+                    raw.append({"kind": "clock", "t": e[1],
+                                "t_profiler": e[3]})
                 else:
                     raw.append({"kind": "section", "t": e[1],
                                 "name": e[2], "t_end": e[3],

@@ -11,15 +11,18 @@ analogue for non-request work (the scheduler's tick and its phases,
 engine dispatch, scrape handlers): sections nest, each row names the
 section it ran inside, and with an ``annotate`` hook the same ranges
 land in the profiler's trace. ``count()`` records how much of something
-a section handled (tokens admitted, rows padded).
+a section handled (tokens admitted, rows padded). ``anchor()``, while
+that hook is set, pairs a read of the recorder's clock with one of the
+profiler's, so that every row can be put on the device trace's clock.
 
 ``to_chrome_trace()`` renders the ring as Chrome-trace JSON: one lane
 (tid) per request plus a lane for host sections, consecutive marks of a
 request becoming complete ("X") events named by the phase they opened.
-The file opens in Perfetto / chrome://tracing side by side with the
-device captures :func:`apex_tpu.profiler.trace` writes — the
-correlation the reference stack never had (scattered host timings vs an
-nsys timeline, SURVEY.md §5).
+With clock rows its timestamps are on the profiler's clock: given the
+capture's start (:func:`apex_tpu.profiler.capture_start`) they are on
+the axis of the device capture :func:`apex_tpu.profiler.trace` writes —
+the correlation the reference stack never had (scattered host timings
+vs an nsys timeline, SURVEY.md §5).
 
 Dependency-free: stdlib only (the ring helper imports numpy lazily,
 which this module never triggers).
@@ -27,8 +30,9 @@ which this module never triggers).
 
 from __future__ import annotations
 
+import bisect
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from apex_tpu.telemetry.ring import Ring
 
@@ -45,9 +49,30 @@ PHASE_ERROR = "error"
 _MARK = 0
 _SECTION = 1
 _COUNT = 2
+_CLOCK = 3
 
 #: prefix of a section's name on the profiler's side
 ANNOTATION_PREFIX = "apex."
+
+
+def on_profiler_clock(anchors: Sequence[Tuple[float, float]]
+                      ) -> Callable[[float], float]:
+    """``t -> the profiler's clock at recorder time t``, from
+    ``(recorder time, profiler time)`` anchors sorted by the first:
+    linear between the two anchors around ``t``, the nearest anchor's
+    offset before the first and after the last."""
+    xs = [a for a, _ in anchors]
+    ys = [b for _, b in anchors]
+
+    def at(t: float) -> float:
+        i = bisect.bisect_right(xs, t)
+        if i == 0 or i == len(xs) or xs[i] == xs[i - 1]:
+            k = 0 if i == 0 else i - 1
+            return t + ys[k] - xs[k]
+        x0, x1, y0, y1 = xs[i - 1], xs[i], ys[i - 1], ys[i]
+        return y0 + (t - x0) * (y1 - y0) / (x1 - x0)
+
+    return at
 
 
 class Stopwatch:
@@ -111,22 +136,29 @@ class SpanRecorder:
     prefixed ``apex.`` — the scheduler sets it to
     ``jax.profiler.TraceAnnotation``, which puts the sections on the
     profiler's clock beside the device trace (this module stays free
-    of jax). The ring keeps the most recent ``capacity`` events —
-    ``summary()`` reports how many were dropped so a truncated export
-    is never mistaken for a complete one.
+    of jax). ``profiler_clock`` is the clock the profiler stamps those
+    annotations with: TSL's ``TraceMe`` reads the wall clock, and a
+    capture's events lie at it less the capture's start. The ring keeps
+    the most recent ``capacity`` events — ``summary()`` reports how
+    many were dropped so a truncated export is never mistaken for a
+    complete one.
 
     Rows: ``(0, time, request, phase, note)`` for a mark, ``(1, start,
     name, end, parent)`` for a section (``parent`` the name of the
-    section open around it, None at top level) and ``(2, time, name,
-    n, None)`` for a count. One thread records.
+    section open around it, None at top level), ``(2, time, name,
+    n, None)`` for a count and ``(3, time, "clock", profiler_time,
+    None)`` for a clock row (:meth:`anchor`). One thread records.
     """
 
     def __init__(self, capacity: int = 65536,
-                 clock=time.perf_counter, annotate=None):
+                 clock=time.perf_counter, annotate=None,
+                 profiler_clock: Callable[[], float] = time.time):
         self._events = Ring(capacity)
         self.clock = clock
         self.annotate = annotate
+        self.profiler_clock = profiler_clock
         self._open: List[str] = []      # names of the open sections
+        self.anchor()
 
     # -- recording (hot path) ----------------------------------------------
 
@@ -159,6 +191,15 @@ class SpanRecorder:
         rows of a padded batch)."""
         self._events.append((_COUNT, self.clock(), name, n, None))
 
+    def anchor(self) -> None:
+        """While an ``annotate`` hook is set, a clock row: one read of
+        ``clock`` and one of ``profiler_clock``, back to back. Rows
+        between two such pairs map onto the profiler's clock by
+        :func:`on_profiler_clock`; without a hook nothing is read."""
+        if self.annotate is not None:
+            self._events.append((_CLOCK, self.clock(), "clock",
+                                 self.profiler_clock(), None))
+
     # -- export -------------------------------------------------------------
 
     def events(self) -> List[tuple]:
@@ -178,18 +219,27 @@ class SpanRecorder:
     def clear(self) -> None:
         self._events.clear()
 
-    def to_chrome_trace(self) -> Dict[str, Any]:
+    def to_chrome_trace(self, origin_s: float = 0.0) -> Dict[str, Any]:
         """Render as a Chrome-trace dict (``json.dump`` it to a file and
-        open in Perfetto). Request lanes are pid 1; host sections pid 2.
-        Timestamps are microseconds relative to the earliest retained
-        event (Chrome trace wants µs; the absolute epoch is whatever
-        ``clock`` counts from and carries no meaning across processes).
+        open in Perfetto). Request lanes are pid 1; host sections pid 2;
+        clock rows are not rendered. Timestamps are microseconds: with
+        clock rows, of the profiler's clock less ``origin_s`` — pass a
+        capture's start (:func:`apex_tpu.profiler.capture_start`) and
+        they are on that capture's axis, the one its own trace files
+        use; without, relative to the earliest retained event (the
+        epoch of ``clock`` carries no meaning across processes).
         """
-        evs = self._events.values()
+        rows = self._events.values()
+        evs = [e for e in rows if e[0] != _CLOCK]
         if not evs:
             return {"traceEvents": [], "displayTimeUnit": "ms"}
-        t0 = min(e[1] for e in evs)
-        us = lambda t: (t - t0) * 1e6
+        anchors = sorted((e[1], e[3]) for e in rows if e[0] == _CLOCK)
+        if anchors:
+            on = on_profiler_clock(anchors)
+            us = lambda t: (on(t) - origin_s) * 1e6
+        else:
+            t0 = min(e[1] for e in evs)
+            us = lambda t: (t - t0) * 1e6
 
         out: List[Dict[str, Any]] = [
             {"ph": "M", "pid": 1, "name": "process_name",

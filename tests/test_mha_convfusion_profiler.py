@@ -323,7 +323,7 @@ def test_sections_reach_the_profilers_trace(tmp_path):
     assert set(found) == {"apex.sched.step", "apex.engine.fetch"}
     (a, b), (c, d) = found["apex.sched.step"], found["apex.engine.fetch"]
     assert a <= c < d <= b
-    assert [(e[2], e[4]) for e in rec.events()] == [
+    assert [(e[2], e[4]) for e in rec.events() if e[0] == 1] == [
         ("engine.fetch", "sched.step"), ("sched.step", None),
         ("engine.verify", None)]
 
@@ -360,7 +360,7 @@ def test_section_rows_name_their_parent():
     with rec.section("sched.submit"):
         t[0] = 4.0
     assert (step.start, step.end) == (0.0, 3.0)
-    assert [e[1:] for e in rec.events()] == [
+    assert [e[1:] for e in rec.events() if e[0] == 1] == [
         (0.5, "engine.verify", 2.0, "sched.collect"),
         (1.0, "sched.collect", 2.0, "sched.step"),
         (2.0, "sched.publish", 3.0, "sched.step"),

@@ -11,6 +11,7 @@ import ast
 import contextlib
 import pathlib
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ from apex_tpu.amp import ScalerConfig
 from apex_tpu.models import gpt, training
 from apex_tpu.optimizers import fused_adam
 from apex_tpu.serving import (
-    Engine, EngineConfig, Request, SamplingParams, Scheduler)
+    Engine, EngineConfig, Request, SamplingParams, Scheduler, StepHandle)
 from apex_tpu.telemetry import SpanRecorder
 from apex_tpu.transformer.testing import standalone_gpt_config
 
@@ -289,6 +290,55 @@ def test_engine_sections_name_their_phase(span_rows, section, parent):
     phases = [e for e in span_rows[0] if e[0] == 1 and e[2] == parent]
     for _, t0, _, t1, _ in found:
         assert any(p[1] <= t0 and t1 <= p[3] for p in phases)
+
+
+def test_fetch_splits_into_the_wait_and_the_copies(span_rows):
+    """Every ``engine.fetch`` holds one ``engine.fetch.wait`` (the copy
+    of the tokens, which waits for the chunk) followed by one
+    ``engine.fetch.copy`` (the copies after it), both naming the fetch
+    as their parent."""
+    rows = [e for e in span_rows[0] if e[0] == 1]
+    fetches = [e for e in rows if e[2] == "engine.fetch"]
+    assert fetches
+    for f in fetches:
+        inside = sorted((e for e in rows if e[4] == "engine.fetch"
+                         and f[1] <= e[1] and e[3] <= f[3]),
+                        key=lambda e: e[1])
+        assert [e[2] for e in inside] == ["engine.fetch.wait",
+                                          "engine.fetch.copy"]
+        assert inside[0][3] <= inside[1][1]
+    assert len([e for e in rows if e[4] == "engine.fetch"]) == \
+        2 * len(fetches)
+
+
+def test_untraced_fetch_takes_no_section(tiny_engine, monkeypatch):
+    """Without a recorder the scheduler fetches a chunk as it always
+    did: no section factory reaches the handle, so nothing is timed
+    or recorded."""
+    calls = []
+    fetch = StepHandle.fetch
+
+    def spy(self, *args, **kwargs):
+        calls.append((args, kwargs))
+        return fetch(self, *args, **kwargs)
+
+    monkeypatch.setattr(StepHandle, "fetch", spy)
+    _served(tiny_engine)
+    assert calls and all(c == ((), {}) for c in calls)
+
+
+def test_a_clock_row_opens_every_tick(span_rows):
+    """One clock row when the scheduler takes the recorder, then one at
+    the entry of every tick, ahead of its ``sched.step`` section: the
+    recorder's clock beside the wall clock the profiler stamps with."""
+    rows = span_rows[0]
+    clocks = [e for e in rows if e[0] == 3]
+    steps = [e for e in rows if e[0] == 1 and e[2] == "sched.step"]
+    assert len(clocks) == len(steps) + 1
+    assert all(e[2] == "clock" and e[4] is None for e in clocks)
+    assert all(a[1] < s[1] for a, s in zip(clocks[1:], steps))
+    wall = [e[3] for e in clocks]
+    assert wall == sorted(wall) and abs(wall[-1] - time.time()) < 600
 
 
 def test_sections_are_annotated_under_the_apex_prefix(span_rows):

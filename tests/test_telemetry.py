@@ -172,6 +172,108 @@ def test_span_recorder_bounded():
     json.dumps(rec.to_chrome_trace())
 
 
+def test_clock_rows_only_while_an_annotate_hook_is_set():
+    """A clock row pairs one read of each clock, at construction and at
+    every ``anchor()`` — and nothing is read or written without a
+    hook, so a recorder the profiler does not see costs what it did."""
+    reads = []
+
+    def clock():
+        reads.append("rec")
+        return 5.0 + len(reads)
+
+    bare = SpanRecorder(clock=clock, profiler_clock=lambda: 1e9)
+    bare.anchor()
+    assert bare.events() == [] and reads == []
+    hooked = SpanRecorder(clock=clock, annotate=lambda name: None,
+                          profiler_clock=lambda: 1e9 + len(reads))
+    hooked.anchor()
+    assert hooked.events() == [(3, 6.0, "clock", 1e9 + 1, None),
+                               (3, 7.0, "clock", 1e9 + 2, None)]
+    # a hook set after construction (as the scheduler sets it) anchors
+    # from its first anchor() on
+    bare.annotate = lambda name: None
+    bare.anchor()
+    assert [e[0] for e in bare.events()] == [3]
+    assert bare.summary()["requests"] == 0
+
+
+def test_recorder_time_maps_between_anchors():
+    at = spans_mod.on_profiler_clock([(10.0, 1000.0), (11.0, 1001.002),
+                                      (11.0, 1001.002), (12.0, 1002.0)])
+    assert at(10.5) == pytest.approx(1000.501)      # interpolated
+    assert at(11.5) == pytest.approx(1001.501)
+    assert at(9.0) == pytest.approx(999.0)          # the first offset
+    assert at(13.0) == pytest.approx(1003.0)        # the last offset
+    assert at(11.0) == pytest.approx(1001.002)
+
+
+def test_chrome_trace_on_the_profilers_clock():
+    """With clock rows the export renders none of them and stamps every
+    event on the profiler's clock less ``origin_s``; without, as
+    before, from the earliest row."""
+    t = [0.0]
+    rec = SpanRecorder(clock=lambda: t[0], annotate=lambda name: _Null(),
+                       profiler_clock=lambda: 500.0 + 2 * t[0])
+    rec.mark("r0", spans_mod.PHASE_QUEUED)
+    t[0] = 1.0
+    with rec.section("sched.step"):
+        t[0] = 1.5
+    rec.anchor()                    # at 1.5: the clocks drift 2x
+    rec.mark("r0", spans_mod.PHASE_RETIRED)
+    ct = rec.to_chrome_trace(origin_s=500.0)
+    evs = ct["traceEvents"]
+    assert not any(e.get("name") == "clock" for e in evs)
+    x = {e["name"]: (e["ts"], e["dur"]) for e in evs if e["ph"] == "X"}
+    assert x["sched.step"] == (pytest.approx(2e6), pytest.approx(1e6))
+    assert x["queued"] == (pytest.approx(0.0), pytest.approx(3e6))
+    plain = SpanRecorder(clock=lambda: t[0] + 7.0)
+    plain.mark("r1", spans_mod.PHASE_QUEUED)
+    assert [e["ts"] for e in plain.to_chrome_trace()["traceEvents"]
+            if e["ph"] == "i"] == [0.0]
+
+
+class _Null:
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_chrome_trace_lines_up_with_a_capture(tmp_path):
+    """On a real capture: each section the export stamps on the
+    capture's axis starts where the profiler put its annotation, to
+    well under 50 us (the hook is entered just before the recorder
+    reads its clock)."""
+    import glob
+    import os
+    import time
+
+    from jax.profiler import ProfileData
+
+    from apex_tpu import profiler
+
+    rec = SpanRecorder(clock=time.monotonic, annotate=profiler.annotate)
+    with profiler.trace(str(tmp_path)):
+        for i in range(20):
+            rec.anchor()
+            with rec.section(f"sched.s{i}"):
+                time.sleep(0.002)
+    ct = rec.to_chrome_trace(origin_s=profiler.capture_start(str(tmp_path)))
+    mine = {e["name"]: e["ts"] for e in ct["traceEvents"] if e["ph"] == "X"}
+    path, = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    theirs = {ev.name[len("apex."):]: ev.start_ns * 1e-3
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("apex.sched.s")}
+    assert set(theirs) == set(mine) and len(mine) == 20
+    off = sorted(abs(mine[k] - theirs[k]) for k in mine)
+    assert off[len(off) // 2] < 50.0, off
+
+
 # --- recompile sentinel ----------------------------------------------------
 
 
