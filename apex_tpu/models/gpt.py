@@ -1014,13 +1014,22 @@ def _cast_layer(cfg: GPTConfig, layer_p):
     VJP (``psum_scatter``) IS the ZeRO gradient reduce-scatter. The
     gather runs in param dtype so the grad reduction stays fp32
     (apex DDP's ``allreduce_always_fp32`` semantics (U))."""
-    if cfg.latent is not None:
-        return latent.cast_layer(cfg, layer_p)
-    if cfg.fsdp and lax.axis_size(AXIS_DP) > 1:
+    if cfg.latent is None and cfg.fsdp and lax.axis_size(AXIS_DP) > 1:
         layer_p = jax.tree.map(
             lambda x, d: x if d < 0 else lax.all_gather(
                 x, AXIS_DP, axis=d, tiled=True),
             layer_p, fsdp_layer_dims(cfg))
+    return _cast_matmuls(cfg, layer_p)
+
+
+def _cast_matmuls(cfg: GPTConfig, layer_p):
+    """``layer_p`` (one layer, or a stack of them) with every leaf the
+    layer computes with in the compute dtype cast to it — the matmul
+    weights and their biases; the LayerNorm affine and an expert router
+    stay as stored. The one rule of :func:`_cast_layer` and
+    :func:`cast_weights`."""
+    if cfg.latent is not None:
+        return latent.cast_layer(cfg, layer_p)
     cast = lambda t: jax.tree.map(
         lambda x: x.astype(cfg.compute_dtype)
         if jnp.issubdtype(x.dtype, jnp.floating) else x, t)
@@ -1032,6 +1041,20 @@ def _cast_layer(cfg: GPTConfig, layer_p):
                         "experts": cast(layer_p["moe"]["experts"])}}
     return {**layer_p, "attn": cast(layer_p["attn"]),
             "mlp": cast(layer_p["mlp"])}
+
+
+def cast_weights(cfg: GPTConfig, params):
+    """``params`` (global or local, arrays or shapes) with every layer
+    stack cast by :func:`_cast_matmuls`' rule, as each cached forward
+    casts it. Every other leaf is the caller's own, the embedding
+    tables and the latent head included: a program keeps their cast
+    (held in bf16, the word table becomes XLA's cross-program prefetch
+    and re-tiles the admission programs' matmuls, which is slower and
+    reorders their sums). A serving engine holds its weights so, once,
+    where every forward would cast the stacks again; training casts its
+    fp32 master weights in :func:`_cast_layer`, every step."""
+    names = ("layers",) if cfg.latent is None else latent.STACKS
+    return {**params, **{n: _cast_matmuls(cfg, params[n]) for n in names}}
 
 
 def pipeline_loss(

@@ -271,11 +271,15 @@ def param_specs(cfg) -> Any:
     return jax.tree.map(lambda _: P(), shapes)
 
 
+#: the parameter tree's layer stacks, in layer order
+STACKS = ("dense_layers", "moe_layers")
+
+
 def layer_stacks(cfg, params):
     """``[(stacked layer parameters, index of the stack's first
     layer)]`` in layer order."""
-    return [(params["dense_layers"], 0),
-            (params["moe_layers"], cfg.latent.dense_layers)]
+    return [(params[STACKS[0]], 0),
+            (params[STACKS[1]], cfg.latent.dense_layers)]
 
 
 # ---------------------------------------------------------------------------

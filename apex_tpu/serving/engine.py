@@ -529,6 +529,38 @@ def _admitted_state(state, slots, first, p_lens, max_tokens, temp, top_k,
     return new_state, hit_eos, done0
 
 
+def _held_weights(cfg, params, mesh):
+    """``params`` as the engine holds them: every leaf whose dtype
+    :func:`gpt.cast_weights` changes is cast once, here, shard by shard
+    on ``mesh`` under ``gpt.param_specs`` — the operand every forward
+    would otherwise make again from the same bits; every other leaf is
+    the caller's own array, uncopied. An abstract leaf
+    (``ShapeDtypeStruct``) becomes one of the new dtype with its
+    sharding."""
+    leaves, tree = jax.tree.flatten(params)
+    want = tree.flatten_up_to(
+        jax.eval_shape(lambda p: gpt.cast_weights(cfg, p), params))
+    specs = tree.flatten_up_to(gpt.param_specs(cfg))
+    live = []
+    for i, (x, w) in enumerate(zip(leaves, want)):
+        if x.dtype == w.dtype:
+            continue
+        if isinstance(x, jax.ShapeDtypeStruct):
+            leaves[i] = jax.ShapeDtypeStruct(x.shape, w.dtype,
+                                             sharding=x.sharding)
+        else:
+            live.append(i)
+    if live:
+        in_specs = [specs[i] for i in live]
+        cast = jax.jit(jax.shard_map(
+            lambda xs: [x.astype(cfg.compute_dtype) for x in xs],
+            mesh=mesh, in_specs=(in_specs,), out_specs=in_specs))
+        weights = [leaves[i] for i in live]
+        for i, x in zip(live, cast(weights)):
+            leaves[i] = x
+    return tree.unflatten(leaves)
+
+
 class Engine:
     """Compiled slot engine over ``mesh`` (tp sharding like the rest of
     the decode path; dp/pp axes must be 1 — decode state is replicated).
@@ -537,6 +569,13 @@ class Engine:
     exposes host-facing ``admit`` / ``admit_many`` / ``step`` /
     ``step_async`` / ``retire``; each call fetches only the tiny
     per-slot outputs (``step_async`` defers even that).
+
+    The layer stacks are held as every forward computes with them: the
+    leaves a forward casts to ``cfg.compute_dtype`` (matmul weights and
+    biases, :func:`gpt.cast_weights`) are cast once at construction and
+    the caller's fp32 leaves are not kept; LayerNorm, embedding tables
+    and router leaves, and any leaf already in the compute dtype, are
+    the caller's own arrays. The caller's tree is not modified.
     """
 
     def __init__(self, cfg: "gpt.GPTConfig", params, mesh,
@@ -713,7 +752,6 @@ class Engine:
         self.read_chunk = ecfg.page_size or gpt.decode_read_chunk(
             cfg, ecfg.max_seq_len)
         self._mesh = mesh
-        self._params = params
         self._sentinel = None  # lazily via recompile_sentinel()
         #: monotonic admission counter — folded into the default PRNG
         #: key of unseeded requests so concurrent sampled requests never
@@ -799,16 +837,19 @@ class Engine:
             # here) are sanctioned: another live engine's armed
             # recompile guard must read them as a replica being built,
             # not as its own trace-stability breach
-            self.cache, self.state = self._init(params)
+            #: the weights every program computes with (see the class)
+            self._params = _held_weights(cfg, params, mesh)
+            self.cache, self.state = self._init(self._params)
             if self._chunk_size:
-                self._chunk_scratch = self._chunk_scratch_init(params)
+                self._chunk_scratch = self._chunk_scratch_init(
+                    self._params)
             if self._prefix_splits:
-                self.pool = self._pool_init(params)
+                self.pool = self._pool_init(self._params)
             if self._lora:
                 # the adapter pool: zeros everywhere — row 0 IS the
                 # pinned base adapter; never donated, so it survives
                 # rebuild_slots and fault replay
-                self.adapters = self._adapter_init(params)
+                self.adapters = self._adapter_init(self._params)
 
     @staticmethod
     def _resolve_buckets(ecfg: EngineConfig,

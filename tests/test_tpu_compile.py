@@ -13,7 +13,9 @@ process holds the TPU library at a time), and every such test lives in
 this one file.
 """
 
+import contextlib
 import importlib
+import json
 import math
 import re
 
@@ -47,22 +49,31 @@ def topo():
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-@pytest.fixture
-def mosaic(monkeypatch):
+@contextlib.contextmanager
+def _for_mosaic():
     """Kernels lower for Mosaic instead of the interpreter (this
     process's default backend is the CPU), and no described-device
     program enters the persistent compile cache."""
     from jax.experimental.compilation_cache import compilation_cache
 
     not_interpreted = lambda: False
-    monkeypatch.setattr(da, "use_interpret", not_interpreted)
-    monkeypatch.setattr(kernel_utils, "use_interpret", not_interpreted)
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(da, "use_interpret", not_interpreted)
+        m.setattr(kernel_utils, "use_interpret", not_interpreted)
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic():
+    with _for_mosaic():
+        yield
 
 
 def _grids(jaxpr, kernel="decode_attn_read"):
@@ -251,31 +262,54 @@ def _ops_over(jaxpr, shape):
     return found
 
 
-def test_chat_step_program_walks_the_live_rows(topo, mosaic):
-    """The chat cell's deployment at its real size (GPT-2 medium, 40
-    slots, chunks of 8 steps, a horizon of 1024), its step program
-    compiled for the described v5e: both decode kernels' row axes are
-    the live count, a dynamic bound; the row list is built once a
-    decode step, outside the layer scan, whose body holds no operation
-    over a ``[slots, slots]`` operand; and the plan is the 5.75 GiB of
-    the parent's program (AOT, PR 32) to 1 %."""
+@pytest.fixture(scope="module")
+def chat_step(topo):
+    """The chat cell's deployment at its real size (GPT-2 medium, fp32
+    parameters, bf16 compute, 40 slots, chunks of 8 steps, a horizon of
+    1024): ``(engine, the caller's parameters as shapes, the step
+    program traced with the weights the engine holds, compiled, (cache,
+    state) as shapes)`` for the described v5e, compiled once for the
+    tests that read it."""
     from benchmark.harness import recipe
     from benchmark.jobs import serve_base
 
     cfg, ecfg = serve_base.engine_setup(recipe.load_cell("gpt2m_chat"))
     assert (ecfg.slots, ecfg.decode_chunk, ecfg.max_seq_len) == (
         40, 8, 1024)
-    mesh = mx.build_mesh(tp=1, devices=list(topo.devices)[:1])
-    params = jax.tree.map(
-        lambda s, sp: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=NamedSharding(mesh, sp)),
-        jax.eval_shape(lambda: gpt.init(cfg, jax.random.PRNGKey(0))),
-        gpt.param_specs(cfg))
-    eng = PlanEngine(cfg, params, mesh, ecfg)
-    cache, state = jax.eval_shape(eng.init_program, params)
-    traced = eng._step_variants[ecfg.decode_chunk].trace(
-        params, cache, state,
-        jax.ShapeDtypeStruct((ecfg.slots, cfg.vocab_size), jnp.bool_))
+    assert jnp.dtype(cfg.param_dtype) == jnp.float32
+    with _for_mosaic():
+        mesh = mx.build_mesh(tp=1, devices=list(topo.devices)[:1])
+        params = jax.tree.map(
+            lambda s, sp: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(mesh, sp)),
+            jax.eval_shape(lambda: gpt.init(cfg, jax.random.PRNGKey(0))),
+            gpt.param_specs(cfg))
+        eng = PlanEngine(cfg, params, mesh, ecfg)
+        cache, state = jax.eval_shape(eng.init_program, eng._params)
+        traced = eng._step_variants[ecfg.decode_chunk].trace(
+            eng._params, cache, state,
+            jax.ShapeDtypeStruct((ecfg.slots, cfg.vocab_size), jnp.bool_))
+        return eng, params, traced, traced.lower().compile(), (cache, state)
+
+
+def _plan_gib(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes + m.generated_code_size_in_bytes
+            - m.alias_size_in_bytes) / 2 ** 30
+
+
+def test_chat_step_program_walks_the_live_rows(chat_step):
+    """The chat cell's step program as the engine runs it: both decode
+    kernels' row axes are the live count, a dynamic bound; the row list
+    is built once a decode step, outside the layer scan, whose body
+    holds no operation over a ``[slots, slots]`` operand; and the plan
+    is 4.62 GiB to 1 %. The program that takes the fp32 parameters and
+    casts them plans 5.75 GiB: 0.56 GiB more of arguments, and the 0.56
+    GiB bf16 copy of the layer stacks that is the bulk of its
+    temporaries (the word table's 0.1 GiB copy is in both)."""
+    eng, _, traced, compiled, _ = chat_step
+    cfg, ecfg = eng.cfg, eng.engine_cfg
     jaxpr = traced.jaxpr.jaxpr
     assert _grids(jaxpr) == [(None, 1, ecfg.max_seq_len // eng.read_chunk)]
     assert _grids(jaxpr, "decode_attn_write") == [(None,)]
@@ -284,11 +318,92 @@ def test_chat_step_program_walks_the_live_rows(topo, mosaic):
     (layers,) = _scan_bodies(jaxpr, cfg.num_layers)
     assert _ops_over(steps, square)
     assert not _ops_over(layers, square), _ops_over(layers, square)
-    m = traced.lower().compile().memory_analysis()
-    plan = (m.argument_size_in_bytes + m.output_size_in_bytes
-            + m.temp_size_in_bytes + m.generated_code_size_in_bytes
-            - m.alias_size_in_bytes)
-    assert abs(plan / 2 ** 30 - 5.75) < 0.0575, plan / 2 ** 30
+    plan = _plan_gib(compiled)
+    assert abs(plan - 4.62) < 0.0462, plan
+
+
+def test_chat_step_program_casts_no_weight(chat_step):
+    """The step program the engine runs takes every layer weight a
+    forward casts (matmul kernels and biases) as a bf16 parameter and
+    converts none of them: no instruction of the entry that reads such
+    a parameter yields another element type. The LayerNorm affine and
+    the word and position tables stay fp32 parameters."""
+    eng, params, _, compiled, _ = chat_step
+    caller = jax.tree_util.tree_flatten_with_path(params)[0]
+    held = jax.tree.leaves(eng._params)
+    comps, entry = _computations(compiled.as_text())
+    # an entry parameter's number is its leaf's place in the flattened
+    # arguments, the weights first
+    found = {int(m.group(3)): (m.group(1), m.group(2)) for m in (
+        re.match(r"\s*%(\S+) = (\w+)\[.* parameter\((\d+)\)", ln)
+        for ln in comps[entry]) if m}
+    cast = 0
+    for i, ((path, x), mine) in enumerate(zip(caller, held)):
+        name, dtype = found[i]
+        if x.dtype == mine.dtype:
+            assert dtype == "f32", (jax.tree_util.keystr(path), dtype)
+            continue
+        cast += 1
+        assert dtype == "bf16", (jax.tree_util.keystr(path), dtype)
+        for ln in comps[entry]:
+            if not re.search(rf"%{re.escape(name)}[,)]",
+                             ln.split(" = ", 1)[-1]):
+                continue
+            out = re.match(r"\s*(ROOT )?%\S+ = (\w+)\[", ln)
+            assert " convert(" not in ln and (
+                out is None or out.group(2) == "bf16"), ln[:160]
+    # attn's and mlp's two kernels and two biases each
+    assert cast == 8
+
+
+def _matmuls(text):
+    """``(tilings, bodies)`` of a compiled program: the window of every
+    matmul fusion in program order — how the compiler splits its
+    operands and contraction, and so the order of its sums — and the
+    computations that hold a matmul, names dropped, sorted."""
+    tilings = []
+    for ln in text.splitlines():
+        if '"window_config"' in ln and (
+                "kind=kOutput" in ln or " convolution(" in ln):
+            w = json.loads(re.search(r"backend_config=(\{.*\})", ln)
+                           .group(1))["window_config"]
+            tilings.append(tuple(tuple(w[k]) for k in (
+                "input_window_bounds", "kernel_window_bounds",
+                "output_window_bounds", "iteration_bounds")))
+    bodies = []
+    for m in re.finditer(r"^%\S+ \([^\n]*\) -> [^\n]* \{\n(.*?)\n\}",
+                         text, re.S | re.M):
+        if " convolution(" in m.group(1):
+            body = re.sub(r", (metadata|backend_config)=.*", "",
+                          m.group(1))
+            body = re.sub(r"%[\w.\-]+|S\(\d\)", "", body)
+            bodies.append(body)
+    return tilings, sorted(bodies)
+
+
+@pytest.mark.parametrize("program", ["step", "widest_admission"])
+def test_chat_programs_tile_their_matmuls_as_the_casting_ones(
+        chat_step, mosaic, program):
+    """The chat cell's step program and its widest admission, compiled
+    with the weights the engine holds and with the caller's fp32 tree
+    (the programs that cast in every call): every matmul fusion has the
+    same body and the same tiling, so it sums the same bf16 products in
+    the same order. Holding the word table in bf16 broke this (the
+    table became the admission's cross-program prefetch and its
+    matmuls were tiled again)."""
+    from benchmark.harness import plan
+
+    eng, params, _, held, (cache, state) = chat_step
+    name = plan.largest_engine_programs(eng)[program != "step"]
+    texts = []
+    for tree in (params, eng._params):
+        if program == "step" and tree is eng._params:
+            texts.append(held.as_text())
+            continue
+        fn, args = plan.engine_programs(eng, tree, cache, state)[name]
+        texts.append(fn.lower(*args).compile().as_text())
+    casting, holding = map(_matmuls, texts)
+    assert casting[0] and casting[1] and casting == holding
 
 
 def _computations(text):
